@@ -16,9 +16,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 NEG_INF = -1e30
+
+# KV positions streamed per grid step; a cache length must be a multiple.
+BLOCK_K = 256
 
 
 def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
@@ -64,7 +65,7 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     jax.jit,
     static_argnames=("window", "softcap", "bk", "interpret"))
 def decode_attention(q, k, v, pos, *, window: Optional[int] = None,
-                     softcap: float = 0.0, bk: int = 256,
+                     softcap: float = 0.0, bk: int = BLOCK_K,
                      interpret: bool = False):
     """q: (B, H, hd); k/v: (B, K, S, hd); pos: scalar int32.
 
@@ -82,6 +83,7 @@ def decode_attention(q, k, v, pos, *, window: Optional[int] = None,
                                softcap=softcap, bk=bk, nk=nk)
     out = pl.pallas_call(
         kernel,
+        name="decode_attention",
         grid=(B, H, nk),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -96,7 +98,7 @@ def decode_attention(q, k, v, pos, *, window: Optional[int] = None,
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
         ],
-        compiler_params=compat.pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(pos_arr, q4, k, v)

@@ -16,8 +16,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro import compat
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(x_ref, b_ref, c_ref, cum_ref, state_ref, y_ref, newstate_ref):
@@ -77,7 +76,7 @@ def mamba2_chunk(xdt, Bh, Ch, cum, state, *, interpret: bool = False):
             jax.ShapeDtypeStruct((B, H, L, P), xdt.dtype),
             jax.ShapeDtypeStruct((B, H, N, P), jnp.float32),
         ],
-        compiler_params=compat.pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(xdt, Bh, Ch, cum4, state)

@@ -20,8 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-from repro import compat
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -61,6 +60,7 @@ def node_scores(features, weights, *, bn: int = 1024, interpret: bool = False):
     w2 = weights.reshape(1, 8)
     out = pl.pallas_call(
         _kernel,
+        name="node_scores",
         grid=(N // bn,),
         in_specs=[
             pl.BlockSpec((bn, 8), lambda i: (i, 0)),
@@ -68,7 +68,7 @@ def node_scores(features, weights, *, bn: int = 1024, interpret: bool = False):
         ],
         out_specs=pl.BlockSpec((bn, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((N, 1), jnp.float32),
-        compiler_params=compat.pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(features, w2)
@@ -113,34 +113,53 @@ def select_best_batched(features, weights, *, interpret: bool = False):
 # ---------------------------------------------------------------------------
 
 
+def _fold_tile_best(s, base, first, idx_ref, val_ref):
+    """Reduce one (1, bn) score tile to its (first) max and fold it into the
+    running per-task best held in the resident (1, 1, 1) output blocks.
+
+    Every value stays a (1, 1) vector: the TPU cannot store scalars to
+    VMEM. Inside the tile the lowest index among equal maxima wins
+    (np.argmax semantics, via a 2D iota — TPU requires >= 2D); across
+    tiles the strict ``>`` keeps the earlier tile, so exact ties resolve to
+    the lowest global index. ``base`` is the tile's first global index."""
+    bn = s.shape[1]
+    tile_max = jnp.max(s, axis=1, keepdims=True)               # (1, 1)
+    ii = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    tile_arg = jnp.min(jnp.where(s == tile_max, ii, bn), axis=1,
+                       keepdims=True)                          # (1, 1)
+    gidx = (base + tile_arg).astype(jnp.int32)
+
+    @pl.when(first)
+    def _init():
+        val_ref[0] = tile_max
+        idx_ref[0] = gidx
+
+    @pl.when(jnp.logical_not(first))
+    def _fold():
+        prev = val_ref[0]
+        better = tile_max > prev
+        val_ref[0] = jnp.where(better, tile_max, prev)
+        idx_ref[0] = jnp.where(better, gidx, idx_ref[0])
+
+
 def _select_kernel(f_ref, w_ref, idx_ref, val_ref):
     """One (1, bn, 8) node tile of one task row: score it, reduce to the
     tile's (first) max, and fold into the running per-task best across the
     sequential node-tile grid axis. Emits per-task winner index + score —
     the (B, N) score matrix never leaves the chip."""
     j = pl.program_id(1)
-    f = f_ref[0]                                   # (bn, 8)
-    w = w_ref[...]                                 # (1, 8)
-    s = _eq3_tile_scores(f, w)[None, :]            # (1, bn)
-    bn = s.shape[1]
-    tile_max = jnp.max(s, axis=1)                             # (1,)
-    # first-max index via 2D iota (TPU requires >=2D), np.argmax semantics
-    ii = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    tile_arg = jnp.min(jnp.where(s == tile_max[:, None], ii, bn), axis=1)
-    gidx = (j * bn + tile_arg).astype(jnp.int32)              # (1,)
+    s = _eq3_tile_scores(f_ref[0], w_ref[...])[None, :]      # (1, bn)
+    _fold_tile_best(s, j * s.shape[1], j == 0, idx_ref, val_ref)
 
-    @pl.when(j == 0)
-    def _init():
-        val_ref[...] = tile_max[:, None]
-        idx_ref[...] = gidx[:, None]
 
-    @pl.when(j > 0)
-    def _fold():
-        prev = val_ref[0, 0]
-        # strict > keeps the lowest global index on exact ties
-        better = tile_max[0] > prev
-        val_ref[0, 0] = jnp.where(better, tile_max[0], prev)
-        idx_ref[0, 0] = jnp.where(better, gidx[0], idx_ref[0, 0])
+# Per-task outputs are (B, 1, 1): a (1, 1, 1) block's last two dims equal
+# the array's, which the TPU tiling accepts (a (1, 1) block of a (B, 1)
+# array is refused unless B == 1).
+def _winner_specs(index_map, B):
+    specs = [pl.BlockSpec((1, 1, 1), index_map)] * 2
+    shapes = [jax.ShapeDtypeStruct((B, 1, 1), jnp.int32),
+              jax.ShapeDtypeStruct((B, 1, 1), jnp.float32)]
+    return specs, shapes
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "interpret"))
@@ -162,27 +181,22 @@ def select_best_fused(features, weights, *, bn: int = 1024,
     if pad:
         features = jnp.pad(features, ((0, 0), (0, pad), (0, 0)))
     N = features.shape[1]
-    w2 = weights.reshape(1, 8)
+    out_specs, out_shape = _winner_specs(lambda i, j: (i, 0, 0), B)
     idx, val = pl.pallas_call(
         _select_kernel,
+        name="select_best_fused",
         grid=(B, N // bn),
         in_specs=[
             pl.BlockSpec((1, bn, 8), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, 8), lambda i, j: (0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, 1), jnp.int32),
-            jax.ShapeDtypeStruct((B, 1), jnp.float32),
-        ],
-        compiler_params=compat.pallas_tpu_compiler_params(
+        out_specs=out_specs,
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(features, w2)
-    return idx[:, 0], val[:, 0]
+    )(features, weights.reshape(1, 8))
+    return idx[:, 0, 0], val[:, 0, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -199,28 +213,9 @@ def _joint_select_kernel(n_pad, f_ref, w_ref, idx_ref, val_ref):
     compatible with the numpy path's reshape over (P, N)."""
     p = pl.program_id(1)
     j = pl.program_id(2)
-    f = f_ref[0, 0]                                # (bn, 8)
-    w = w_ref[...]                                 # (1, 8)
-    s = _eq3_tile_scores(f, w)[None, :]            # (1, bn)
-    bn = s.shape[1]
-    tile_max = jnp.max(s, axis=1)                             # (1,)
-    ii = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    tile_arg = jnp.min(jnp.where(s == tile_max[:, None], ii, bn), axis=1)
-    gidx = (p * n_pad + j * bn + tile_arg).astype(jnp.int32)  # (1,)
-    first = (p == 0) & (j == 0)
-
-    @pl.when(first)
-    def _init():
-        val_ref[...] = tile_max[:, None]
-        idx_ref[...] = gidx[:, None]
-
-    @pl.when(jnp.logical_not(first))
-    def _fold():
-        prev = val_ref[0, 0]
-        # strict > keeps the lowest flat (p, n) index on exact ties
-        better = tile_max[0] > prev
-        val_ref[0, 0] = jnp.where(better, tile_max[0], prev)
-        idx_ref[0, 0] = jnp.where(better, gidx[0], idx_ref[0, 0])
+    s = _eq3_tile_scores(f_ref[0, 0], w_ref[...])[None, :]   # (1, bn)
+    _fold_tile_best(s, p * n_pad + j * s.shape[1], (p == 0) & (j == 0),
+                    idx_ref, val_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "interpret"))
@@ -245,31 +240,26 @@ def select_best_joint(features, weights, *, bn: int = 1024,
     if pad:
         features = jnp.pad(features, ((0, 0), (0, 0), (0, pad), (0, 0)))
     N = features.shape[2]
-    w2 = weights.reshape(1, 8)
+    out_specs, out_shape = _winner_specs(lambda i, p, j: (i, 0, 0), B)
     idx, val = pl.pallas_call(
         functools.partial(_joint_select_kernel, N),
+        name="select_best_joint",
         grid=(B, P, N // bn),
         in_specs=[
             pl.BlockSpec((1, 1, bn, 8), lambda i, p, j: (i, p, j, 0)),
             pl.BlockSpec((1, 8), lambda i, p, j: (0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda i, p, j: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, p, j: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, 1), jnp.int32),
-            jax.ShapeDtypeStruct((B, 1), jnp.float32),
-        ],
-        compiler_params=compat.pallas_tpu_compiler_params(
+        out_specs=out_specs,
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
-    )(features, w2)
-    flat = idx[:, 0]
+    )(features, weights.reshape(1, 8))
+    flat = idx[:, 0, 0]
     # Padding rows can only win when nothing real is feasible, in which
     # case the score is NEG_INF and callers discard the indices anyway.
     return ((flat // N).astype(jnp.int32), (flat % N).astype(jnp.int32),
-            val[:, 0])
+            val[:, 0, 0])
 
 
 # ---------------------------------------------------------------------------
@@ -282,7 +272,6 @@ def _sharded_select_fn(mesh, axis: str, bn: int, interpret: bool):
     """Build (and cache) the shard_map'd fused select for one mesh: each
     device scores its node shard with the fused kernel, then a cross-shard
     argmax combine picks the global winner (lowest global index on ties)."""
-    from repro import compat
 
     def local_select(f_local, w):
         # f_local: (B, N/d, 8) on this device
@@ -298,11 +287,11 @@ def _sharded_select_fn(mesh, axis: str, bn: int, interpret: bool):
 
     from jax.sharding import PartitionSpec as P
 
-    return jax.jit(compat.shard_map(
+    return jax.jit(jax.shard_map(
         local_select, mesh=mesh,
         in_specs=(P(None, axis, None), P(None)),
         out_specs=(P(None), P(None)),
-        check_rep=False))
+        check_vma=False))
 
 
 def select_best_sharded(features, weights, mesh=None, axis: str = "nodes",

@@ -1,13 +1,12 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels run in interpret mode; on TPU they
-compile to Mosaic. ``use_pallas()`` gates whether model code routes through
-kernels or the pure-jnp reference path (the default on CPU, where interpret
-mode is slow).
+On a TPU the kernels compile to Mosaic; on any other backend they run in
+interpret mode (the CPU tests). ``use_pallas()`` is true exactly on a TPU:
+model code routes through the kernels there and through the pure-jnp
+reference path elsewhere, where interpret mode is slow.
 """
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 import jax
@@ -24,9 +23,7 @@ def _interpret() -> bool:
 
 
 def use_pallas() -> bool:
-    if os.environ.get("REPRO_USE_PALLAS"):
-        return os.environ["REPRO_USE_PALLAS"] not in ("0", "false")
-    return jax.default_backend() == "tpu"
+    return not _interpret()
 
 
 def flash_attention(q, k, v, *, causal=True, window: Optional[int] = None,

@@ -138,7 +138,7 @@ def full_pass(cfg, shape, multi_pod: bool):
     mesh = make_production_mesh(multi_pod=multi_pod)
     fn, args, in_sh = build(cfg, shape, mesh)
     t0 = time.time()
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = jax.jit(fn, in_shardings=in_sh).lower(*args)
         compiled = lowered.compile()
     t1 = time.time()
@@ -172,7 +172,7 @@ def _acct_metrics(cfg, shape, mesh):
     """(flops, bytes, coll_bytes, counts) for one unrolled lowering."""
     fn, args, in_sh = build(cfg, shape, mesh)
     with modes.unroll_scans():
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jax.jit(fn, in_shardings=in_sh).lower(*args)
             compiled = lowered.compile()
     flops, byts = _cost_items(compiled)

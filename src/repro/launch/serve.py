@@ -14,6 +14,7 @@ import numpy as np
 from repro.configs.registry import get_config, list_archs, reduced_config
 from repro.core import costmodel, energy
 from repro.core.router import GreenRouter, PodSpec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer
 from repro.obs import console_logger
 from repro.runtime.serving import Request, ServingEngine
@@ -31,6 +32,8 @@ DEFAULT_PODS = [
 
 
 def main(argv=None):
+    """Serve the synthetic request mix; returns the :class:`ServingEngine`
+    (its ``completions`` and ``report()``) for in-process callers."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=list_archs(), default="qwen3-1.7b")
     ap.add_argument("--mode", choices=["performance", "balanced", "green"],
@@ -45,6 +48,7 @@ def main(argv=None):
     ap.add_argument("--batch-size", type=int, default=4)
     ap.add_argument("--full-config", action="store_true")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch) if args.full_config else reduced_config(args.arch)
     params = transformer.init_params(cfg, jax.random.PRNGKey(0))
@@ -60,6 +64,7 @@ def main(argv=None):
     terms = energy.roofline(flops, hbm, 0.0, chips=256)
     router.seed_profile({p.name: terms for p in DEFAULT_PODS})
 
+    # ServingEngine rounds the cache up to the decode kernel's block
     engine = ServingEngine(cfg, params, router,
                            max_len=args.prompt_len + args.max_new + 8,
                            batch_size=args.batch_size)
@@ -79,7 +84,7 @@ def main(argv=None):
         log.info("  %-12s tasks=%4d carbon=%.4f mgCO2 I=%.0f",
                  region, acc["tasks"], acc["carbon_g"] * 1e3,
                  acc["intensity"])
-    return rep
+    return engine
 
 
 if __name__ == "__main__":

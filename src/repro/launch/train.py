@@ -16,6 +16,7 @@ from repro.checkpoint import store
 from repro.configs.registry import get_config, list_archs, reduced_config
 from repro.core.carbon import CarbonMonitor
 from repro.data.pipeline import DataConfig, make_batches
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer
 from repro.obs import console_logger
 from repro.optim import adamw
@@ -40,6 +41,7 @@ def main(argv=None):
     ap.add_argument("--carbon-intensity", type=float, default=380.0)
     ap.add_argument("--log-every", type=int, default=5)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch) if args.full_config else reduced_config(args.arch)
     log.info("arch=%s layers=%d d_model=%d params~%.1fM",

@@ -287,9 +287,11 @@ def attn_decode(cfg: ModelConfig, p, x, cache: Tuple, pos, *, window=None,
     from repro.sharding.constraints import _current_mesh
 
     # Pallas decode-attention kernel (single-device serving path).
+    from repro.kernels import decode_attention as _dec
     from repro.kernels import ops as _ops
 
-    if (_ops.use_pallas() and _current_mesh() is None and Smax % 256 == 0
+    if (_ops.use_pallas() and _current_mesh() is None
+            and Smax % _dec.BLOCK_K == 0
             and H % K == 0 and not cfg.mrope_sections):
         out = _ops.decode_attention(
             q[:, 0], ck.transpose(0, 2, 1, 3), cv.transpose(0, 2, 1, 3), pos,

@@ -31,8 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-
 from repro.configs.base import ModelConfig
 from repro.models.common import ParamSpec, activation
 from repro.models import mlp as mlp_mod
@@ -187,7 +185,7 @@ def _moe_forward_shardmap(cfg: ModelConfig, p, x, mesh, dp, dp_size, msize):
         P("model", None, None),           # w_down
     )
     out_specs = (P(dp_spec, None), P())
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         local_fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False,
     )(x.reshape(T, D), p["router"], p["w_gate"], p["w_up"], p["w_down"])

@@ -19,6 +19,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.core import costmodel, energy
 from repro.core.router import GreenRouter
+from repro.kernels.decode_attention import BLOCK_K
 from repro.runtime import steps
 
 
@@ -55,9 +56,12 @@ class ServingEngine:
         self.cfg = cfg
         self.params = params
         self.router = router
-        self.max_len = max_len
+        # The KV cache rounds up to the decode kernel's block so decode can
+        # take the Pallas path; slots past the decode position are masked,
+        # so the extra length never changes a token.
+        self.max_len = -(-max_len // BLOCK_K) * BLOCK_K
         self.batch_size = batch_size
-        self._prefill = jax.jit(steps.prefill_step(cfg, max_len))
+        self._prefill = jax.jit(steps.prefill_step(cfg, self.max_len))
         self._decode = jax.jit(steps.decode_fn(cfg))
         self.queue: List[Request] = []
         self.completions: List[Completion] = []

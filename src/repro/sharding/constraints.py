@@ -6,7 +6,7 @@ attention dots at full global batch, a 16x FLOP overcount). Model code
 calls ``constrain(x, "batch", "seq", "heads", ...)`` with *logical* axis
 names; mapping respects the active mesh, divisibility, and axis reuse.
 
-No-op outside a mesh context (CPU unit tests).
+No-op outside a ``jax.set_mesh`` context (CPU unit tests).
 """
 from __future__ import annotations
 
@@ -29,21 +29,13 @@ def mesh_axis_size(name: str) -> int:
 
 
 def _current_mesh():
-    try:
-        from jax._src import mesh as mesh_lib
-
-        m = mesh_lib.thread_resources.env.physical_mesh
-        if m is not None and m.devices.size > 1:
-            return m
-    except Exception:  # noqa: BLE001
-        pass
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and not m.empty and m.size > 1:
-            return m
-    except Exception:  # noqa: BLE001
-        pass
-    return None
+    """The mesh set by ``jax.set_mesh`` (None without one, or on a single
+    device). Callers enter meshes with ``jax.set_mesh``; the legacy
+    ``with mesh:`` context is not visible here."""
+    m = jax.sharding.get_abstract_mesh()
+    if m.empty or m.size <= 1:
+        return None
+    return m
 
 
 def constrain(x, *axes: Optional[str]):

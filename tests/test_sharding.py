@@ -54,7 +54,7 @@ mesh = make_test_mesh(data=4, model=2)
 shape = InputShape("tiny_train", seq_len=32, global_batch=8, kind="train")
 
 fn, args, in_sh = build(cfg, shape, mesh)
-with mesh:
+with jax.set_mesh(mesh):
     lowered = jax.jit(fn, in_shardings=in_sh).lower(*args)
     compiled = lowered.compile()
 
@@ -66,7 +66,7 @@ tokens = jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0, cfg.vocab_size)
 batch = {"tokens": tokens, "labels": jnp.roll(tokens, -1, 1)}
 
 step = steps.train_step(cfg, adamw.AdamWConfig())
-with mesh:
+with jax.set_mesh(mesh):
     p_sh, o_sh, b_sh = in_sh
     params_d = jax.device_put(params, p_sh)
     opt_d = jax.device_put(opt, o_sh)
@@ -115,7 +115,7 @@ for arch in ("qwen3-1.7b", "zamba2-2.7b", "qwen2-moe-a2.7b"):
     mesh = make_test_mesh(data=2, model=2, pod=2)
     shape = InputShape("tiny_decode", seq_len=64, global_batch=4, kind="decode")
     fn, args, in_sh = build(cfg, shape, mesh)
-    with mesh:
+    with jax.set_mesh(mesh):
         compiled = jax.jit(fn, in_shardings=in_sh).lower(*args).compile()
     ok[arch] = True
 print("RESULT::" + json.dumps(ok))

@@ -67,6 +67,32 @@ def test_serving_green_routing_and_accounting():
     assert rep["per_region"]["pod-high"]["tasks"] == 0
 
 
+def test_serving_cache_rounds_to_decode_block_without_changing_tokens():
+    """The KV cache rounds up to the decode kernel's block (so decode can
+    take the Pallas kernel on a TPU); slots past the decode position are
+    masked, so the tokens equal those of a cache of the requested length."""
+    from repro.kernels.decode_attention import BLOCK_K
+
+    cfg, eng = _engine("green")                    # requests max_len=32
+    assert eng.max_len == BLOCK_K
+    prompts = np.random.default_rng(1).integers(
+        0, cfg.vocab_size, size=(2, 12)).astype(np.int32)
+    for i, p in enumerate(prompts):
+        eng.submit(Request(uid=i, prompt=p, max_new_tokens=4))
+    got = [c.tokens for c in sorted(eng.run_all(), key=lambda c: c.uid)]
+
+    cache, logits = jax.jit(steps.prefill_step(cfg, 32))(
+        eng.params, {"tokens": jnp.asarray(prompts)})
+    decode = jax.jit(steps.decode_fn(cfg))
+    tok = steps.greedy_sample(logits)[:, None]
+    want = [tok[:, 0]]
+    for t in range(3):
+        logits, cache = decode(eng.params, cache, tok, jnp.int32(12 + t))
+        tok = steps.greedy_sample(logits)[:, None]
+        want.append(tok[:, 0])
+    assert got == np.stack(want, axis=1).tolist()
+
+
 def test_green_pod_availability_changes_carbon():
     """Same workload with the green pod saturated (load filter, Algorithm 1
     line 3) must emit more carbon — and the ratio must follow the grid
@@ -100,3 +126,23 @@ def test_greedy_decode_deterministic():
     eng2.submit(Request(uid=0, prompt=prompt, max_new_tokens=4))
     b = eng2.run_all()[0].tokens
     assert a == b
+
+
+def test_compile_cache_placement(monkeypatch, tmp_path):
+    """$JAX_COMPILATION_CACHE_DIR wins and nothing overrides it; otherwise
+    the cache goes to one fixed path inside the checkout."""
+    from pathlib import Path
+
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = compile_cache.enable_compile_cache()
+        assert path == str(Path(__file__).resolve().parents[1] / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
