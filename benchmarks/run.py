@@ -12,7 +12,7 @@ from __future__ import annotations
 
 def main() -> None:
     from benchmarks import (fig2_tradeoff, fig3_weight_sweep, fleet_scale,
-                            obs_overhead, overhead, partition_scale, roofline,
+                            obs_overhead, overhead, partition_scale,
                             sim_serving, table2_carbon_footprint,
                             table4_multi_model, table5_node_distribution,
                             temporal_shifting, tenancy_saturation)
@@ -106,11 +106,6 @@ def main() -> None:
                  f"_{acc_row['batch']}b",
                  acc_row["enabled_per_task_ms"] * 1e3,
                  f"overhead_x={acc_row['overhead_x']:.2f}"))
-
-    for r in roofline.load():
-        rows.append((f"roofline_{r['arch']}_{r['shape']}",
-                     r["step_time_s"] * 1e6,
-                     f"bottleneck={r['bottleneck']}"))
 
     print("name,us_per_call,derived")
     for name, us, derived in rows:

@@ -24,7 +24,6 @@ from __future__ import annotations
 
 import warnings
 from dataclasses import dataclass
-from time import perf_counter
 from typing import Dict, List, Mapping, Optional, Protocol, Sequence, runtime_checkable
 
 import numpy as np
@@ -33,6 +32,7 @@ from repro.core.carbon import CarbonMonitor
 from repro.core.cluster import EdgeCluster, TaskResult
 from repro.core.energy import carbon_g
 from repro.core.scheduler import MODES, Task, Weights
+from repro.obs.profiler import span
 
 
 # ---------------------------------------------------------------------------
@@ -766,12 +766,10 @@ class CarbonEdgeEngine:
         try:
             if res is not None:
                 res.tick(now_hour)
-            t0 = perf_counter() if prof is not None else 0.0
-            choices = self.policy.select_batch(
-                self.cluster, batch, self.weights, provider=self.provider,
-                now_hour=now_hour)
-            if prof is not None:
-                prof.add("select", perf_counter() - t0)
+            with span(prof, "select"):
+                choices = self.policy.select_batch(
+                    self.cluster, batch, self.weights,
+                    provider=self.provider, now_hour=now_hour)
             # Partitioned-execution hook (DESIGN.md §8): a policy exposing
             # execution_latency_ms (e.g. repro.partition.PartitionPolicy)
             # makes the engine execute and bill only the offloaded
@@ -957,12 +955,10 @@ class CarbonEdgeEngine:
         try:
             if res is not None:
                 res.tick(now_hour)
-            t0 = perf_counter() if prof is not None else 0.0
-            plan = self.policy.plan(self.cluster, batch,
-                                    provider=self.provider,
-                                    now_hour=now_hour)
-            if prof is not None:
-                prof.add("plan", perf_counter() - t0)
+            with span(prof, "plan"):
+                plan = self.policy.plan(self.cluster, batch,
+                                        provider=self.provider,
+                                        now_hour=now_hour)
         except BaseException:
             # admission itself failed (e.g. a partial-coverage provider
             # KeyError): nothing was consumed, so the whole batch requeues
@@ -999,12 +995,10 @@ class CarbonEdgeEngine:
         gate_fired = False
         dead_reason = None
         try:
-            t0 = perf_counter() if prof is not None else 0.0
-            full = self.policy.select_admitted(
-                self.cluster, batch, plan, self.weights,
-                provider=self.provider, now_hour=now_hour)
-            if prof is not None:
-                prof.add("select", perf_counter() - t0)
+            with span(prof, "select"):
+                full = self.policy.select_admitted(
+                    self.cluster, batch, plan, self.weights,
+                    provider=self.provider, now_hour=now_hour)
             choices = (full if aidx is None
                        else [full[i] for i in aidx])
             if res is not None and (res.down or None in choices):
@@ -1209,24 +1203,20 @@ class CarbonEdgeEngine:
                              dtype=float)
                     if base_override is None
                     else np.asarray(base_override[:cut], dtype=float))
-            t0 = perf_counter() if prof is not None else 0.0
-            res = self.cluster.execute_batch(nodes, base, distributed=True,
-                                             intensities=ev[inverse],
-                                             groups=groups)
-            if prof is not None:
-                prof.add("execute", perf_counter() - t0)
-                t0 = perf_counter()
+            with span(prof, "execute"):
+                res = self.cluster.execute_batch(
+                    nodes, base, distributed=True, intensities=ev[inverse],
+                    groups=groups)
             # The billed energy is recomputed through the cluster's own
             # cost model (the same call execute_batch makes) rather than
             # gathered back out of the B result objects — same floats, no
             # O(B) attribute reads, one source of truth for the math.
-            lat_ms, e_kwh = self.cluster.latency_energy(base,
-                                                        distributed=True)
-            self.monitor.record_energy_batch(
-                nodes, e_kwh, hour=now_hour, intensities=bv[inverse],
-                groups=groups)
-            if prof is not None:
-                prof.add("bill", perf_counter() - t0)
+            with span(prof, "bill"):
+                lat_ms, e_kwh = self.cluster.latency_energy(base,
+                                                            distributed=True)
+                self.monitor.record_energy_batch(
+                    nodes, e_kwh, hour=now_hour, intensities=bv[inverse],
+                    groups=groups)
             results.extend(res)
             if failure is None:
                 # whole batch executed: publish the step's execution
@@ -1364,56 +1354,53 @@ class CarbonEdgeEngine:
         roll = obs.rollups
         if trace is None and metrics is None and roll is None:
             return
-        prof = obs.profiler
-        t0 = perf_counter() if prof is not None else 0.0
         B = len(results)
         if B == 0:
             return
-        snap = self._exec_snapshot
-        if snap is not None:
-            uniq, inverse, ev, bv, e_kwh = snap
-            ev_t = ev[inverse]
-            # same expression execute_batch billed with — identical floats
-            carbon = carbon_g(e_kwh, ev_t, self.cluster.pue)
-        else:
-            uniq, inverse = np.unique(
-                np.asarray([r.node for r in results], dtype=object),
-                return_inverse=True)
-            ev = np.asarray(intensity_batch(self.provider, list(uniq),
-                                            now_hour), dtype=float)
-            ev_t = ev[inverse]
-            bv = np.asarray(self.monitor.billing_intensity_batch(
-                list(uniq), now_hour), dtype=float)
-            carbon = np.asarray([r.carbon_g for r in results], dtype=float)
-            e_kwh = (np.asarray([r.energy_kwh for r in results], dtype=float)
-                     if roll is not None else None)
-        if roll is not None:
-            roll.fold_exec(now_hour, carbon, e_kwh)
-            roll.fold_verdicts(now_hour, (B, 0, 0, 0, 0))  # all done
-        if trace is not None:
-            lo, hi = self._obs_intervals(uniq, inverse, now_hour)
-            score = runner = cut = None
-            ls = getattr(self.policy, "last_scores", None)
-            if ls is not None and ls.get("score") is not None \
-                    and len(ls["score"]) == B:
-                score, runner = ls["score"], ls.get("runner_up")
-                cut = ls.get("cut")
-            trace.record_batch(
-                step=self._steps, hour=now_hour,
-                verdict=np.zeros(B, dtype=np.int8),   # all done
-                node=trace.intern_names(uniq)[inverse],
-                cut=cut, mode=self._mode_idx,
-                score=score, runner_up=runner,
-                intensity=ev_t, interval_lo=lo, interval_hi=hi,
-                intensity_billed=bv[inverse], carbon_g=carbon)
-        if metrics is not None:
-            self._obs_metrics_nodes(metrics, uniq, inverse, carbon)
-            metrics.counter("engine_outcomes_total",
-                            "step outcomes by verdict", ("verdict",)
-                            ).inc(B, labels=("done",))
-            self._obs_metrics_depths(metrics)
-        if prof is not None:
-            prof.add("observe", perf_counter() - t0)
+        with span(obs.profiler, "observe"):
+            snap = self._exec_snapshot
+            if snap is not None:
+                uniq, inverse, ev, bv, e_kwh = snap
+                ev_t = ev[inverse]
+                # same expression execute_batch billed with — identical floats
+                carbon = carbon_g(e_kwh, ev_t, self.cluster.pue)
+            else:
+                uniq, inverse = np.unique(
+                    np.asarray([r.node for r in results], dtype=object),
+                    return_inverse=True)
+                ev = np.asarray(intensity_batch(self.provider, list(uniq),
+                                                now_hour), dtype=float)
+                ev_t = ev[inverse]
+                bv = np.asarray(self.monitor.billing_intensity_batch(
+                    list(uniq), now_hour), dtype=float)
+                carbon = np.asarray([r.carbon_g for r in results], dtype=float)
+                e_kwh = (np.asarray([r.energy_kwh for r in results], dtype=float)
+                         if roll is not None else None)
+            if roll is not None:
+                roll.fold_exec(now_hour, carbon, e_kwh)
+                roll.fold_verdicts(now_hour, (B, 0, 0, 0, 0))  # all done
+            if trace is not None:
+                lo, hi = self._obs_intervals(uniq, inverse, now_hour)
+                score = runner = cut = None
+                ls = getattr(self.policy, "last_scores", None)
+                if ls is not None and ls.get("score") is not None \
+                        and len(ls["score"]) == B:
+                    score, runner = ls["score"], ls.get("runner_up")
+                    cut = ls.get("cut")
+                trace.record_batch(
+                    step=self._steps, hour=now_hour,
+                    verdict=np.zeros(B, dtype=np.int8),   # all done
+                    node=trace.intern_names(uniq)[inverse],
+                    cut=cut, mode=self._mode_idx,
+                    score=score, runner_up=runner,
+                    intensity=ev_t, interval_lo=lo, interval_hi=hi,
+                    intensity_billed=bv[inverse], carbon_g=carbon)
+            if metrics is not None:
+                self._obs_metrics_nodes(metrics, uniq, inverse, carbon)
+                metrics.counter("engine_outcomes_total",
+                                "step outcomes by verdict", ("verdict",)
+                                ).inc(B, labels=("done",))
+                self._obs_metrics_depths(metrics)
 
     def _obs_record_tenancy(self, obs, batch, plan, results, now_hour,
                             aidx, exec_pos=None) -> None:
@@ -1428,119 +1415,116 @@ class CarbonEdgeEngine:
         roll = obs.rollups
         if trace is None and metrics is None and roll is None:
             return
-        prof = obs.profiler
-        t0 = perf_counter() if prof is not None else 0.0
-        from repro.tenancy.policy import ADMIT as _ADMIT
-        from repro.tenancy.policy import REJECT as _REJECT
-        B = len(batch)
-        if exec_pos is not None:
-            from repro.obs.trace import VERDICT_LABELS
-            codes = {k: c for c, k in enumerate(VERDICT_LABELS)}
-            verdict = np.array([codes[o[0]] for o in self.last_outcomes],
-                               dtype=np.int8)
-            pos_exec = np.asarray(exec_pos[:len(results)], dtype=int)
-        else:
-            # explicit action -> trace-verdict map (the two encodings order
-            # DEFER/REJECT differently)
-            verdict = np.where(
-                plan.actions == _ADMIT, 0,
-                np.where(plan.actions == _REJECT, 1, 2)).astype(np.int8)
-            pos_exec = (np.arange(len(results)) if aidx is None
-                        else np.asarray(aidx))
-        uniq = inverse = carbon = e_kwh = None
-        if results:
-            snap = self._exec_snapshot
-            if snap is not None:
-                uniq, inverse, ev, bv, e_kwh = snap
-                ev_t = ev[inverse]
-                carbon = carbon_g(e_kwh, ev_t, self.cluster.pue)
+        with span(obs.profiler, "observe"):
+            from repro.tenancy.policy import ADMIT as _ADMIT
+            from repro.tenancy.policy import REJECT as _REJECT
+            B = len(batch)
+            if exec_pos is not None:
+                from repro.obs.trace import VERDICT_LABELS
+                codes = {k: c for c, k in enumerate(VERDICT_LABELS)}
+                verdict = np.array([codes[o[0]] for o in self.last_outcomes],
+                                   dtype=np.int8)
+                pos_exec = np.asarray(exec_pos[:len(results)], dtype=int)
             else:
-                uniq, inverse = np.unique(
-                    np.asarray([r.node for r in results], dtype=object),
-                    return_inverse=True)
-                ev = np.asarray(intensity_batch(self.provider, list(uniq),
-                                                now_hour), dtype=float)
-                ev_t = ev[inverse]
-                bv = np.asarray(self.monitor.billing_intensity_batch(
-                    list(uniq), now_hour), dtype=float)
-                carbon = np.asarray([r.carbon_g for r in results],
-                                    dtype=float)
-                e_kwh = (np.asarray([r.energy_kwh for r in results],
-                                    dtype=float)
-                         if roll is not None else None)
-        if roll is not None:
+                # explicit action -> trace-verdict map (the two encodings order
+                # DEFER/REJECT differently)
+                verdict = np.where(
+                    plan.actions == _ADMIT, 0,
+                    np.where(plan.actions == _REJECT, 1, 2)).astype(np.int8)
+                pos_exec = (np.arange(len(results)) if aidx is None
+                            else np.asarray(aidx))
+            uniq = inverse = carbon = e_kwh = None
             if results:
-                roll.fold_exec(now_hour, carbon, e_kwh)
+                snap = self._exec_snapshot
+                if snap is not None:
+                    uniq, inverse, ev, bv, e_kwh = snap
+                    ev_t = ev[inverse]
+                    carbon = carbon_g(e_kwh, ev_t, self.cluster.pue)
+                else:
+                    uniq, inverse = np.unique(
+                        np.asarray([r.node for r in results], dtype=object),
+                        return_inverse=True)
+                    ev = np.asarray(intensity_batch(self.provider, list(uniq),
+                                                    now_hour), dtype=float)
+                    ev_t = ev[inverse]
+                    bv = np.asarray(self.monitor.billing_intensity_batch(
+                        list(uniq), now_hour), dtype=float)
+                    carbon = np.asarray([r.carbon_g for r in results],
+                                        dtype=float)
+                    e_kwh = (np.asarray([r.energy_kwh for r in results],
+                                        dtype=float)
+                             if roll is not None else None)
+            if roll is not None:
+                if results:
+                    roll.fold_exec(now_hour, carbon, e_kwh)
+                    reg = getattr(self.policy, "registry", None)
+                    index = getattr(reg, "index", None)
+                    if index:
+                        names = np.asarray(sorted(index, key=index.get),
+                                           dtype=object)
+                        tmap = roll.intern_tenants(names)
+                        tidx = np.asarray(plan.tenant_idx)[pos_exec]
+                        tagged = tidx >= 0
+                        if tagged.any():
+                            roll.fold_tenant_spend(now_hour, tmap[tidx[tagged]],
+                                                   carbon[tagged])
+                roll.fold_verdicts(
+                    now_hour, np.bincount(verdict, minlength=5)[:5])
+            if trace is not None:
+                node = np.full(B, -1, dtype=np.int32)
+                intens = np.full(B, np.nan)
+                billed = np.full(B, np.nan)
+                carb = np.full(B, np.nan)
+                ilo = ihi = None
+                if results:
+                    node[pos_exec] = trace.intern_names(uniq)[inverse]
+                    intens[pos_exec] = ev_t
+                    billed[pos_exec] = bv[inverse]
+                    carb[pos_exec] = carbon
+                    lo, hi = self._obs_intervals(uniq, inverse, now_hour)
+                    if lo is not None:
+                        ilo = np.full(B, np.nan)
+                        ihi = np.full(B, np.nan)
+                        ilo[pos_exec] = lo
+                        ihi[pos_exec] = hi
+                # -1 (untagged / no escalation) means the engine's own mode
+                modes = np.where(plan.modes >= 0, plan.modes,
+                                 self._mode_idx).astype(np.int8)
+                tenant = None
                 reg = getattr(self.policy, "registry", None)
                 index = getattr(reg, "index", None)
                 if index:
                     names = np.asarray(sorted(index, key=index.get),
                                        dtype=object)
-                    tmap = roll.intern_tenants(names)
-                    tidx = np.asarray(plan.tenant_idx)[pos_exec]
-                    tagged = tidx >= 0
-                    if tagged.any():
-                        roll.fold_tenant_spend(now_hour, tmap[tidx[tagged]],
-                                               carbon[tagged])
-            roll.fold_verdicts(
-                now_hour, np.bincount(verdict, minlength=5)[:5])
-        if trace is not None:
-            node = np.full(B, -1, dtype=np.int32)
-            intens = np.full(B, np.nan)
-            billed = np.full(B, np.nan)
-            carb = np.full(B, np.nan)
-            ilo = ihi = None
-            if results:
-                node[pos_exec] = trace.intern_names(uniq)[inverse]
-                intens[pos_exec] = ev_t
-                billed[pos_exec] = bv[inverse]
-                carb[pos_exec] = carbon
-                lo, hi = self._obs_intervals(uniq, inverse, now_hour)
-                if lo is not None:
-                    ilo = np.full(B, np.nan)
-                    ihi = np.full(B, np.nan)
-                    ilo[pos_exec] = lo
-                    ihi[pos_exec] = hi
-            # -1 (untagged / no escalation) means the engine's own mode
-            modes = np.where(plan.modes >= 0, plan.modes,
-                             self._mode_idx).astype(np.int8)
-            tenant = None
-            reg = getattr(self.policy, "registry", None)
-            index = getattr(reg, "index", None)
-            if index:
-                names = np.asarray(sorted(index, key=index.get),
-                                   dtype=object)
-                tmap = trace.intern_names(names, kind="tenant")
-                tidx = np.asarray(plan.tenant_idx)
-                tenant = np.where(tidx >= 0,
-                                  tmap[np.maximum(tidx, 0)],
-                                  -1).astype(np.int32)
-            score = runner = cut = None
-            ls = getattr(self.policy, "last_scores", None)
-            if ls is not None and ls.get("score") is not None \
-                    and len(ls["score"]) == B:
-                score, runner = ls["score"], ls.get("runner_up")
-                cut = ls.get("cut")
-            trace.record_batch(
-                step=self._steps, hour=now_hour, verdict=verdict,
-                node=node, cut=cut, mode=modes, tenant=tenant,
-                score=score, runner_up=runner,
-                intensity=intens, interval_lo=ilo, interval_hi=ihi,
-                intensity_billed=billed, carbon_g=carb,
-                expected_g=plan.expected_g)
-        if metrics is not None:
-            if results:
-                self._obs_metrics_nodes(metrics, uniq, inverse, carbon)
-            fam = metrics.counter("engine_outcomes_total",
-                                  "step outcomes by verdict", ("verdict",))
-            for code, label in enumerate(
-                    ("done", "reject", "defer", "dead", "retry")):
-                n = int((verdict == code).sum())
-                if n:
-                    fam.inc(n, labels=(label,))
-            self._obs_metrics_depths(metrics)
-        if prof is not None:
-            prof.add("observe", perf_counter() - t0)
+                    tmap = trace.intern_names(names, kind="tenant")
+                    tidx = np.asarray(plan.tenant_idx)
+                    tenant = np.where(tidx >= 0,
+                                      tmap[np.maximum(tidx, 0)],
+                                      -1).astype(np.int32)
+                score = runner = cut = None
+                ls = getattr(self.policy, "last_scores", None)
+                if ls is not None and ls.get("score") is not None \
+                        and len(ls["score"]) == B:
+                    score, runner = ls["score"], ls.get("runner_up")
+                    cut = ls.get("cut")
+                trace.record_batch(
+                    step=self._steps, hour=now_hour, verdict=verdict,
+                    node=node, cut=cut, mode=modes, tenant=tenant,
+                    score=score, runner_up=runner,
+                    intensity=intens, interval_lo=ilo, interval_hi=ihi,
+                    intensity_billed=billed, carbon_g=carb,
+                    expected_g=plan.expected_g)
+            if metrics is not None:
+                if results:
+                    self._obs_metrics_nodes(metrics, uniq, inverse, carbon)
+                fam = metrics.counter("engine_outcomes_total",
+                                      "step outcomes by verdict", ("verdict",))
+                for code, label in enumerate(
+                        ("done", "reject", "defer", "dead", "retry")):
+                    n = int((verdict == code).sum())
+                    if n:
+                        fam.inc(n, labels=(label,))
+                self._obs_metrics_depths(metrics)
 
     # -- reporting ---------------------------------------------------------
     def report(self, deep: bool = False) -> Dict:

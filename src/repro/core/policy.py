@@ -27,7 +27,6 @@ Policies:
 from __future__ import annotations
 
 from dataclasses import dataclass
-from time import perf_counter
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -36,6 +35,7 @@ from repro.core.api import (CarbonIntensityProvider, StaticProvider)
 from repro.core.cluster import EdgeCluster
 from repro.core.scheduler import (LOAD_THRESHOLD, Task, Weights,
                                   node_feasible, scores, vector_scores)
+from repro.obs.profiler import span
 
 # Scores below this are "invalid" sentinels (the Pallas kernel emits -1e30,
 # the numpy path -inf).
@@ -348,9 +348,15 @@ class VectorizedPolicy:
         B = F.shape[0]
         w8 = np.zeros(FEATURE_DIM, np.float32)
         w8[:5] = w5
-        idx, val = ops.select_best_node_fused(
-            jnp.asarray(self._pad_to_buckets(F)), jnp.asarray(w8))
-        return np.asarray(idx)[:B], np.asarray(val, np.float64)[:B]
+        prof = self.profiler
+        with span(prof, "select.pad"):
+            padded = self._pad_to_buckets(F)
+        with span(prof, "select.put"):
+            Fd, wd = jnp.asarray(padded), jnp.asarray(w8)
+        with span(prof, "select.launch"):
+            idx, val = ops.select_best_node_fused(Fd, wd)
+        with span(prof, "select.fetch"):
+            return np.asarray(idx)[:B], np.asarray(val, np.float64)[:B]
 
     def _select_from_features(self, F: np.ndarray, names: List[str],
                               weights: Weights) -> List[Optional[str]]:
@@ -445,15 +451,11 @@ class VectorizedPolicy:
         cache = get_cache(cluster) if self.use_cache else None
         if cache is None:
             prof = self.profiler
-            t0 = perf_counter() if prof is not None else 0.0
-            F, names = featurize(cluster, reps, provider, now_hour,
-                                 self.latency_threshold_ms)
-            if prof is not None:
-                prof.add("featurize", perf_counter() - t0)
-                t0 = perf_counter()
-            out = self._select_from_features(F, names, weights)
-            if prof is not None:
-                prof.add("score", perf_counter() - t0)
+            with span(prof, "featurize"):
+                F, names = featurize(cluster, reps, provider, now_hour,
+                                     self.latency_threshold_ms)
+            with span(prof, "score"):
+                out = self._select_from_features(F, names, weights)
             if cap:
                 self._cap_finalize()
             return out
@@ -514,15 +516,11 @@ class VectorizedPolicy:
         prof = self.profiler
         out: List[Optional[str]] = []
         for lo in range(0, len(reps), chunk):
-            t0 = perf_counter() if prof is not None else 0.0
-            F, _ = featurize_cached(cache, reps[lo:lo + chunk], provider,
-                                    now_hour, self.latency_threshold_ms)
-            if prof is not None:
-                prof.add("featurize", perf_counter() - t0)
-                t0 = perf_counter()
-            out.extend(self._select_from_features(F, names, weights))
-            if prof is not None:
-                prof.add("score", perf_counter() - t0)
+            with span(prof, "featurize"):
+                F, _ = featurize_cached(cache, reps[lo:lo + chunk], provider,
+                                        now_hour, self.latency_threshold_ms)
+            with span(prof, "score"):
+                out.extend(self._select_from_features(F, names, weights))
         return out
 
     def _select_cached_columns(self, cache, reps: Sequence[Task],
@@ -534,42 +532,38 @@ class VectorizedPolicy:
         w = weights.as_array()
         names = cache.names
         prof = self.profiler
-        t0 = perf_counter() if prof is not None else 0.0
-        task_cpu = np.array([t.cpu for t in reps], dtype=float)
-        task_mem = np.array([t.mem_mb for t in reps], dtype=float)
-        feasible = cache.feasible(task_cpu, task_mem,
-                                  self.latency_threshold_ms)     # (U, N)
-        ints = cache.intensities(provider, now_hour,
-                                 need=feasible.any(axis=0))
-        base = (w[1] * (1.0 - cache.load)
-                + w[2] * (1.0 / (1.0 + cache.avg_time_s))
-                + w[3] * (1.0 / (1.0 + cache.running * 2.0))
-                + w[4] * (1.0 / (1.0 + ints * cache.e_est)))     # (N,)
-        if prof is not None:
-            prof.add("featurize", perf_counter() - t0)
-            t0 = perf_counter()
-        out: List[Optional[str]] = []
-        chunk = max(1, self._CHUNK_ELEMS // max(cache.n, 1))
-        for lo in range(0, len(reps), chunk):
-            tc = task_cpu[lo:lo + chunk, None]
-            tm = task_mem[lo:lo + chunk, None]
-            cpu_frac = np.ones((tc.shape[0], cache.n))
-            np.divide(cache.free_cpu[None, :], tc, out=cpu_frac,
-                      where=tc > 0)
-            mem_frac = np.ones((tm.shape[0], cache.n))
-            np.divide(cache.free_mem[None, :], tm, out=mem_frac,
-                      where=tm > 0)
-            s_r = (0.5 * np.minimum(1.0, cpu_frac)
-                   + 0.5 * np.minimum(1.0, mem_frac))
-            totals = np.where(feasible[lo:lo + chunk],
-                              w[0] * s_r + base[None, :], -np.inf)
-            best = np.argmax(totals, axis=1)
-            if self.capture_scores:
-                self._cap_block(totals, best)
-            out.extend(names[b] if totals[i, b] > 0.0 else None
-                       for i, b in enumerate(best))
-        if prof is not None:
-            prof.add("score", perf_counter() - t0)
+        with span(prof, "featurize"):
+            task_cpu = np.array([t.cpu for t in reps], dtype=float)
+            task_mem = np.array([t.mem_mb for t in reps], dtype=float)
+            feasible = cache.feasible(task_cpu, task_mem,
+                                      self.latency_threshold_ms)  # (U, N)
+            ints = cache.intensities(provider, now_hour,
+                                     need=feasible.any(axis=0))
+            base = (w[1] * (1.0 - cache.load)
+                    + w[2] * (1.0 / (1.0 + cache.avg_time_s))
+                    + w[3] * (1.0 / (1.0 + cache.running * 2.0))
+                    + w[4] * (1.0 / (1.0 + ints * cache.e_est)))  # (N,)
+        with span(prof, "score"):
+            out: List[Optional[str]] = []
+            chunk = max(1, self._CHUNK_ELEMS // max(cache.n, 1))
+            for lo in range(0, len(reps), chunk):
+                tc = task_cpu[lo:lo + chunk, None]
+                tm = task_mem[lo:lo + chunk, None]
+                cpu_frac = np.ones((tc.shape[0], cache.n))
+                np.divide(cache.free_cpu[None, :], tc, out=cpu_frac,
+                          where=tc > 0)
+                mem_frac = np.ones((tm.shape[0], cache.n))
+                np.divide(cache.free_mem[None, :], tm, out=mem_frac,
+                          where=tm > 0)
+                s_r = (0.5 * np.minimum(1.0, cpu_frac)
+                       + 0.5 * np.minimum(1.0, mem_frac))
+                totals = np.where(feasible[lo:lo + chunk],
+                                  w[0] * s_r + base[None, :], -np.inf)
+                best = np.argmax(totals, axis=1)
+                if self.capture_scores:
+                    self._cap_block(totals, best)
+                out.extend(names[b] if totals[i, b] > 0.0 else None
+                           for i, b in enumerate(best))
         return out
 
     # Below this fleet size a single-task selection is cheaper through the

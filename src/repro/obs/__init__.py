@@ -9,7 +9,10 @@ Six pillars, each independently switchable and ``None`` when off:
 - :class:`~repro.obs.registry.MetricsRegistry` — numpy-column counters /
   gauges / histograms with Prometheus-style text exposition.
 - :class:`~repro.obs.profiler.StepProfiler` — ``perf_counter`` spans
-  around the engine/sim phases, folded into per-phase histograms.
+  around the engine/sim/serving phases, folded into per-phase histograms;
+  each phase is also a ``carbonedge.*`` profiler annotation
+  (:class:`~repro.obs.profiler.span`), so a ``jax.profiler`` capture
+  shows it beside the device's operations.
 - :class:`~repro.obs.journey.JourneyTrace` — per-request causal record
   keyed by sim task uid (arrival → verdicts → defer/wake → retry/failover
   → execute-or-dead-letter) with ``explain_journey`` forensics and a
@@ -22,11 +25,14 @@ Six pillars, each independently switchable and ``None`` when off:
   deterministic fire/resolve event log.
 
 ``Observability`` bundles them for threading through
-``CarbonEdgeEngine(obs=...)`` and ``AsyncEngineDriver(obs=...)``. The
-disabled default costs one ``is not None`` check per instrumented site and
-leaves every existing output byte-identical (the sim ``to_text`` contract,
-enforced by ``gate_obs``); this package imports only stdlib + numpy so the
-core/tenancy/partition layers can depend on it without cycles.
+``CarbonEdgeEngine(obs=...)``, ``ServingEngine(obs=...)`` and
+``AsyncEngineDriver(obs=...)``. The disabled default costs one
+``is not None`` check per instrumented site, plus one inactive profiler
+annotation per profiled phase, and leaves every existing output
+byte-identical (the sim ``to_text`` contract, enforced by ``gate_obs``);
+this package imports only stdlib + numpy (JAX's profiler lazily, on the
+first span) so the core/tenancy/partition layers can depend on it without
+cycles.
 """
 from __future__ import annotations
 
@@ -39,7 +45,7 @@ from repro.obs.alerts import (ALERT_KINDS, AlertEngine, AlertEvent,
 from repro.obs.journey import (J_DEAD, J_DONE, J_OPEN, J_REJECT,
                                PARK_DEFER, PARK_RETRY, STATE_LABELS,
                                JourneyTrace)
-from repro.obs.profiler import SPAN_EDGES_S, StepProfiler
+from repro.obs.profiler import SPAN_EDGES_S, TRACE_PREFIX, StepProfiler, span
 from repro.obs.registry import DEFAULT_EDGES, Family, MetricsRegistry
 from repro.obs.rollup import VERDICT_COLS, RollupStore
 from repro.obs.trace import (MODE_LABELS, VERDICT_DEAD, VERDICT_DEFER,
@@ -52,9 +58,10 @@ __all__ = [
     "J_OPEN", "J_REJECT", "JourneyTrace", "MetricsRegistry",
     "MODE_LABELS", "Observability", "PARK_DEFER", "PARK_RETRY",
     "RollupStore", "SPAN_EDGES_S", "STATE_LABELS", "StepProfiler",
+    "TRACE_PREFIX",
     "VERDICT_COLS", "VERDICT_DEAD", "VERDICT_DEFER", "VERDICT_DONE",
     "VERDICT_LABELS", "VERDICT_REJECT", "VERDICT_RETRY", "console_logger",
-    "default_rules",
+    "default_rules", "span",
 ]
 
 

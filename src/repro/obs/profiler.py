@@ -7,9 +7,13 @@ phase — so the paper's 0.03 ms scheduling-overhead claim is a continuously
 tracked artifact (``BENCH_obs.json``) instead of an ad-hoc benchmark.
 
 The accumulator is O(1) per span (a dict lookup, four scalar updates, and
-one ``searchsorted`` into the shared edge vector); instrumented call sites
-guard every ``perf_counter`` pair behind a single ``is not None`` check so
-the disabled path pays one pointer comparison per phase.
+one ``searchsorted`` into the shared edge vector). Call sites open each
+phase with :class:`span`, which puts it on two clocks: it always opens a
+``jax.profiler.TraceAnnotation`` named ``carbonedge.<phase>``, so a
+profiler capture shows the phase beside the device's operations, and only
+when a profiler is attached does it read ``perf_counter`` and fold the
+duration here. Detached, a span costs one inactive ``TraceMe``
+(about 1 us) and no accumulation.
 
 Quantiles inherit the histogram's bucket granularity: ``percentile_s``
 returns the *upper edge* of the bin holding the target rank (see the
@@ -20,9 +24,9 @@ is too coarse for a phase you care about.
 """
 from __future__ import annotations
 
-from contextlib import contextmanager
+from functools import lru_cache
 from time import perf_counter
-from typing import Dict
+from typing import Dict, Optional
 
 import numpy as np
 
@@ -30,6 +34,9 @@ import numpy as np
 # to 10 s, plus an implicit overflow bin. Fixed edges keep summaries
 # comparable across phases, runs, and CI artifacts.
 SPAN_EDGES_S = 10.0 ** np.arange(-7.0, 1.5, 0.5)
+
+# Prefix of every program span on the profiler's trace.
+TRACE_PREFIX = "carbonedge."
 
 
 class _Phase:
@@ -70,14 +77,9 @@ class StepProfiler:
             p.max_s = dt_s
         p.bins[int(np.searchsorted(self.edges, dt_s, side="right"))] += 1
 
-    @contextmanager
-    def span(self, phase: str):
-        """Context-manager form of :meth:`add` for coarse, cold spans."""
-        t0 = perf_counter()
-        try:
-            yield
-        finally:
-            self.add(phase, perf_counter() - t0)
+    def span(self, phase: str) -> "span":
+        """Context-manager form of :meth:`add`: ``span(self, phase)``."""
+        return span(self, phase)
 
     def count(self, phase: str) -> int:
         p = self._phases.get(phase)
@@ -122,3 +124,42 @@ class StepProfiler:
 
     def reset(self) -> None:
         self._phases.clear()
+
+
+@lru_cache(maxsize=None)
+def _trace_me():
+    """``jax.profiler.TraceAnnotation``, imported on the first span so that
+    this package keeps no import of JAX."""
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation
+
+
+class span:
+    """One program phase on both clocks: ``with span(prof, "score"): ...``.
+
+    Always a ``TraceAnnotation`` named ``carbonedge.<phase>``, which the
+    profiler records only while a capture is running (inactive, about
+    1 us). With ``prof`` not ``None`` the ``perf_counter`` duration of a
+    phase that completes is also folded into ``prof`` as
+    :meth:`StepProfiler.add` does; one that raises is not. It neither
+    waits for the device nor reorders the enclosed work."""
+
+    __slots__ = ("prof", "phase", "_me", "_t0")
+
+    def __init__(self, prof: Optional[StepProfiler], phase: str) -> None:
+        self.prof = prof
+        self.phase = phase
+        self._me = _trace_me()(TRACE_PREFIX + phase)
+        self._t0 = 0.0
+
+    def __enter__(self) -> "span":
+        self._me.__enter__()
+        if self.prof is not None:
+            self._t0 = perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        if self.prof is not None and exc_type is None:
+            self.prof.add(self.phase, perf_counter() - self._t0)
+        self._me.__exit__(exc_type, exc, tb)
+        return False

@@ -24,7 +24,6 @@ the oracle for multi-segment splits of a *fixed* node list.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from time import perf_counter
 from typing import List, Optional, Sequence
 
 import numpy as np
@@ -35,6 +34,7 @@ from repro.core.policy import (COL_CPU_FREE, COL_IXE, COL_LOAD, COL_MEM_FREE,
                                FEATURE_DIM, VectorizedPolicy, _SelectionMemo,
                                get_cache)
 from repro.core.scheduler import Task, Weights, node_feasible
+from repro.obs.profiler import span
 from repro.partition.profile import CutProfile
 
 # Default uplink between the requesting device and the fleet: a 100 Mbps
@@ -268,26 +268,22 @@ class PartitionPolicy:
 
     def _decide_cached(self, cache, reps, weights, provider, now_hour):
         prof = self.profiler
-        t0 = perf_counter() if prof is not None else 0.0
-        t_pn, e_pn = cache.partition_block(self._block_key, self._rf,
-                                           self._cs)           # (P, N)
-        task_cpu = np.array([t.cpu for t in reps], dtype=float)
-        task_mem = np.array([t.mem_mb for t in reps], dtype=float)
-        feas = cache.feasible(task_cpu, task_mem,
-                              self.latency_threshold_ms)       # (U, N)
-        ints = cache.intensities(provider, now_hour,
-                                 need=feas.any(axis=0))        # (N,)
-        if prof is not None:
-            prof.add("featurize", perf_counter() - t0)
-            t0 = perf_counter()
-        if self._resolved_backend() == "pallas":
-            out = self._decide_pallas(cache, task_cpu, task_mem, feas,
-                                      ints, t_pn, e_pn, weights)
-        else:
-            out = self._decide_numpy(cache, task_cpu, task_mem, feas, ints,
-                                     t_pn, e_pn, weights)
-        if prof is not None:
-            prof.add("score", perf_counter() - t0)
+        with span(prof, "featurize"):
+            t_pn, e_pn = cache.partition_block(self._block_key, self._rf,
+                                               self._cs)           # (P, N)
+            task_cpu = np.array([t.cpu for t in reps], dtype=float)
+            task_mem = np.array([t.mem_mb for t in reps], dtype=float)
+            feas = cache.feasible(task_cpu, task_mem,
+                                  self.latency_threshold_ms)       # (U, N)
+            ints = cache.intensities(provider, now_hour,
+                                     need=feas.any(axis=0))        # (N,)
+        with span(prof, "score"):
+            if self._resolved_backend() == "pallas":
+                out = self._decide_pallas(cache, task_cpu, task_mem, feas,
+                                          ints, t_pn, e_pn, weights)
+            else:
+                out = self._decide_numpy(cache, task_cpu, task_mem, feas,
+                                         ints, t_pn, e_pn, weights)
         return out
 
     @staticmethod

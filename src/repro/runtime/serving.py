@@ -20,6 +20,7 @@ from repro.configs.base import ModelConfig
 from repro.core import costmodel, energy
 from repro.core.router import GreenRouter
 from repro.kernels.decode_attention import BLOCK_K
+from repro.obs.profiler import span
 from repro.runtime import steps
 
 
@@ -49,10 +50,15 @@ class Completion:
 
 
 class ServingEngine:
-    """Batched prefill+decode with greedy sampling and carbon accounting."""
+    """Batched prefill+decode with greedy sampling and carbon accounting.
+
+    ``obs`` (a ``repro.obs.Observability``) with a profiler gets the
+    ``serve.*`` phases of :meth:`run_batch`: route, prefill, and per token
+    decode dispatch, sampling, the host's wait for the token, and billing.
+    They reach a profiler trace as ``carbonedge.serve.*`` either way."""
 
     def __init__(self, cfg: ModelConfig, params, router: GreenRouter,
-                 max_len: int = 256, batch_size: int = 4):
+                 max_len: int = 256, batch_size: int = 4, obs=None):
         self.cfg = cfg
         self.params = params
         self.router = router
@@ -61,6 +67,7 @@ class ServingEngine:
         # so the extra length never changes a token.
         self.max_len = -(-max_len // BLOCK_K) * BLOCK_K
         self.batch_size = batch_size
+        self.obs = obs if obs is not None and obs.enabled else None
         self._prefill = jax.jit(steps.prefill_step(cfg, self.max_len))
         self._decode = jax.jit(steps.decode_fn(cfg))
         self.queue: List[Request] = []
@@ -108,20 +115,27 @@ class ServingEngine:
         toks = np.zeros((B, S), np.int32)
         for i, r in enumerate(batch):
             toks[i, S - len(r.prompt):] = r.prompt  # left-pad
-        pod = self.router.route(now_hour=now_hour)
+        prof = self.obs.profiler if self.obs is not None else None
+        with span(prof, "serve.route"):
+            pod = self.router.route(now_hour=now_hour)
         chips = self.router.pods[pod].chips
         t0 = time.perf_counter()
         start_s = t0 if now_s is None else now_s
-        cache, logits = self._prefill(self.params, {"tokens": jnp.asarray(toks)})
-        carbon = self.router.commit(pod, self._step_terms("prefill", S, B, chips),
-                                    hour=now_hour)
+        with span(prof, "serve.prefill"):
+            cache, logits = self._prefill(self.params,
+                                          {"tokens": jnp.asarray(toks)})
+        with span(prof, "serve.bill"):
+            carbon = self.router.commit(
+                pod, self._step_terms("prefill", S, B, chips), hour=now_hour)
         prefill_elapsed = time.perf_counter() - t0
         max_new = max(r.max_new_tokens for r in batch)
         out = np.zeros((B, max_new), np.int32)
         elapsed = np.zeros(max_new)     # service elapsed when token t exists
-        tok = steps.greedy_sample(logits)[:, None]
+        with span(prof, "serve.sample"):
+            tok = steps.greedy_sample(logits)[:, None]
         for t in range(max_new):
-            out[:, t] = np.asarray(tok[:, 0])
+            with span(prof, "serve.sync"):
+                out[:, t] = np.asarray(tok[:, 0])
             elapsed[t] = time.perf_counter() - t0
             if t == max_new - 1:
                 # token 0 came from prefill, so max_new tokens need only
@@ -129,11 +143,15 @@ class ServingEngine:
                 # decode whose sample is discarded inflated carbon by one
                 # step per batch
                 break
-            logits, cache = self._decode(self.params, cache, tok, jnp.int32(S + t))
-            carbon += self.router.commit(
-                pod, self._step_terms("decode", S + t + 1, B, chips),
-                hour=now_hour)
-            tok = steps.greedy_sample(logits)[:, None]
+            with span(prof, "serve.decode"):
+                logits, cache = self._decode(self.params, cache, tok,
+                                             jnp.int32(S + t))
+            with span(prof, "serve.bill"):
+                carbon += self.router.commit(
+                    pod, self._step_terms("decode", S + t + 1, B, chips),
+                    hour=now_hour)
+            with span(prof, "serve.sample"):
+                tok = steps.greedy_sample(logits)[:, None]
         comps = []
         for i, r in enumerate(batch):
             # a zero-token request's service ends at prefill

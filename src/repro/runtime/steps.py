@@ -43,20 +43,22 @@ def train_step(cfg: ModelConfig, opt_cfg: adamw.AdamWConfig):
     return step
 
 
+# The inner functions' names name the jitted modules (``jit_prefill``,
+# ``jit_decode``), which is how a profiler trace tells the two apart.
 def prefill_step(cfg: ModelConfig, max_len: int):
-    def step(params, batch):
+    def prefill(params, batch):
         cache, last_h = transformer.prefill(cfg, params, batch, max_len)
         logits = transformer.unembed(cfg, params, last_h)
         return cache, logits
 
-    return step
+    return prefill
 
 
 def decode_fn(cfg: ModelConfig):
-    def step(params, cache, token, pos):
+    def decode(params, cache, token, pos):
         return transformer.decode_step(cfg, params, cache, token, pos)
 
-    return step
+    return decode
 
 
 def greedy_sample(logits):

@@ -47,13 +47,13 @@ wall-clock service, so its runs repeat only up to host timing noise.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from time import perf_counter
 from typing import (Callable, Dict, List, Optional, Protocol, Sequence,
                     runtime_checkable)
 
 import numpy as np
 
 from repro.obs.journey import PARK_DEFER, PARK_RETRY
+from repro.obs.profiler import span
 from repro.sim.arrivals import ArrivalProcess, ClosedLoopClientPool
 from repro.sim.clock import VirtualClock, hours_to_s, ms_to_hours, s_to_hours
 from repro.sim.events import (KIND_CODE, EventCalendar, EventHeap, EventKind)
@@ -261,18 +261,16 @@ class AsyncEngineDriver:
         if cluster is None:
             return now
         prof = self.obs.profiler if self.obs is not None else None
-        t0 = perf_counter() if prof is not None else 0.0
-        if self.risk_coverage is not None:
-            from repro.core.temporal import plan_wake_risk
-            wake = plan_wake_risk(self.forecast, cluster, task, now,
-                                  slot_hours=self.slot_hours,
-                                  coverage=self.risk_coverage)
-        else:
-            from repro.core.temporal import plan_wake
-            wake = plan_wake(self.forecast, cluster, task, now,
-                             slot_hours=self.slot_hours)
-        if prof is not None:
-            prof.add("sim_plan", perf_counter() - t0)
+        with span(prof, "sim_plan"):
+            if self.risk_coverage is not None:
+                from repro.core.temporal import plan_wake_risk
+                wake = plan_wake_risk(self.forecast, cluster, task, now,
+                                      slot_hours=self.slot_hours,
+                                      coverage=self.risk_coverage)
+            else:
+                from repro.core.temporal import plan_wake
+                wake = plan_wake(self.forecast, cluster, task, now,
+                                 slot_hours=self.slot_hours)
         return wake
 
     # -- event handlers ------------------------------------------------------
@@ -697,23 +695,19 @@ class AsyncEngineDriver:
         monitor = self._monitor()
         e0 = monitor.total_energy_kwh() if monitor is not None else None
         prof = self.obs.profiler if self.obs is not None else None
-        t0 = perf_counter() if prof is not None else 0.0
-        results = self.executor.step(now_hour=now, limit=n)
-        if prof is not None:
-            prof.add("sim_step", perf_counter() - t0)
+        with span(prof, "sim_step"):
+            results = self.executor.step(now_hour=now, limit=n)
         e_batch = (monitor.total_energy_kwh() - e0
                    if monitor is not None else None)
         outcomes = getattr(self.executor, "last_outcomes", None)
-        t0 = perf_counter() if prof is not None else 0.0
-        if (self._vectorized and outcomes is None and results
-                and hasattr(results[0], "latency_ms")
-                and getattr(results[0], "energy_kwh", None) is not None):
-            self._busy_until = self._record_batch_vec(results, now)
-        else:
-            self._busy_until = self._record_batch(results, now, e_batch,
-                                                  outcomes)
-        if prof is not None:
-            prof.add("sim_record", perf_counter() - t0)
+        with span(prof, "sim_record"):
+            if (self._vectorized and outcomes is None and results
+                    and hasattr(results[0], "latency_ms")
+                    and getattr(results[0], "energy_kwh", None) is not None):
+                self._busy_until = self._record_batch_vec(results, now)
+            else:
+                self._busy_until = self._record_batch(results, now, e_batch,
+                                                      outcomes)
         if len(self._pending) >= self.max_batch:
             # saturated: drain back-to-back the moment the executor frees
             # up instead of idling a whole window on a full batch

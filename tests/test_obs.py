@@ -17,9 +17,10 @@ from repro.core.cluster import EdgeCluster, PAPER_NODES
 from repro.core.policy import VectorizedPolicy
 from repro.core.scheduler import MODES, Task
 from repro.core.temporal import DeferrableTask, synthetic_trace
-from repro.obs import (MODE_LABELS, VERDICT_LABELS, DecisionTrace,
-                       MetricsRegistry, Observability, StepProfiler,
-                       console_logger)
+from repro.obs import (MODE_LABELS, TRACE_PREFIX, VERDICT_LABELS,
+                       DecisionTrace, MetricsRegistry, Observability,
+                       StepProfiler, console_logger, span)
+from repro.obs import profiler as profiler_mod
 from repro.partition import PartitionPolicy, profile_costs
 from repro.sim import AsyncEngineDriver, PoissonArrivals
 from repro.tenancy import (MODE_ORDER, TenantPolicy, TenantRegistry,
@@ -211,6 +212,73 @@ def test_profiler_bins_handle_out_of_range_durations():
     s = p.summary()["phases"]["x"]
     assert s["count"] == 2 and sum(s["hist"]) == 2
     assert p.percentile_s("x", 99) == pytest.approx(1e6)
+
+
+def test_span_detached_reads_no_clock(monkeypatch):
+    """Off means an inactive profiler annotation and nothing else: no
+    ``perf_counter`` and nothing folded anywhere."""
+    def no_clock():
+        raise AssertionError("perf_counter read by a detached span")
+
+    monkeypatch.setattr(profiler_mod, "perf_counter", no_clock)
+    p = StepProfiler()
+    for _ in range(3):
+        with span(None, "score"):
+            pass
+    assert p.phases() == []
+
+
+def test_span_attached_folds_one_per_completed_call():
+    p = StepProfiler()
+    for _ in range(3):
+        with span(p, "select.pad"):
+            pass
+    with pytest.raises(ValueError):
+        with span(p, "select.pad"):
+            raise ValueError("a phase that raises is not folded")
+    assert p.phases() == ["select.pad"]
+    assert p.count("select.pad") == 3
+
+
+def test_span_lands_in_profiler_host_plane(tmp_path):
+    """Under a ``jax.profiler`` capture the span is a host event named
+    ``carbonedge.<phase>``, on the clock of the device's events."""
+    import glob
+
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    with jax.profiler.trace(str(tmp_path)):
+        with span(None, "unit.outer"):
+            with span(None, "unit.inner"):
+                jnp.ones((8, 8)).sum().block_until_ready()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    events = {ev.name: (ev.start_ns, ev.end_ns)
+              for plane in ProfileData.from_file(path).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for ev in line.events
+              if ev.name.startswith(TRACE_PREFIX)}
+    outer, inner = events["carbonedge.unit.outer"], events["carbonedge.unit.inner"]
+    assert outer[0] <= inner[0] <= inner[1] <= outer[1]
+
+
+def test_pallas_select_splits_score_into_pad_put_launch_fetch():
+    """On the fused-kernel path ``score`` holds one ``select.pad``,
+    ``select.put``, ``select.launch`` and ``select.fetch`` per chunk, and
+    they add up to no more than ``score``."""
+    rng = np.random.default_rng(3)
+    pol = VectorizedPolicy(backend="pallas", use_select_memo=False)
+    pol._CHUNK_ELEMS = 4 * len(PAPER_NODES)        # chunks of 4 rows
+    pol.profiler = p = StepProfiler()
+    tasks = [Task(cpu=float(c), mem_mb=float(m))
+             for c, m in zip(rng.uniform(0.01, 0.2, 10),
+                             rng.uniform(8, 64, 10))]
+    pol.select_batch(fresh_cluster(), tasks, MODES["green"])
+    parts = ("select.pad", "select.put", "select.launch", "select.fetch")
+    assert [p.count(ph) for ph in parts] == [3] * 4
+    assert p.count("score") == 3
+    assert sum(p.total_s(ph) for ph in parts) <= p.total_s("score")
 
 
 # ---------------------------------------------------------------------------

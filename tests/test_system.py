@@ -37,7 +37,7 @@ PODS = [
 ]
 
 
-def _engine(mode):
+def _engine(mode, obs=None):
     cfg = reduced_config("qwen3-1.7b")
     params = transformer.init_params(cfg, jax.random.PRNGKey(0))
     router = GreenRouter(PODS, mode=mode)
@@ -45,7 +45,8 @@ def _engine(mode):
     hbm = costmodel.step_hbm_bytes(cfg, 16, 2, "decode")
     terms = energy.roofline(flops, hbm, 0.0, 256)
     router.seed_profile({p.name: terms for p in PODS})
-    eng = ServingEngine(cfg, params, router, max_len=32, batch_size=2)
+    eng = ServingEngine(cfg, params, router, max_len=32, batch_size=2,
+                        obs=obs)
     return cfg, eng
 
 
@@ -91,6 +92,41 @@ def test_serving_cache_rounds_to_decode_block_without_changing_tokens():
         tok = steps.greedy_sample(logits)[:, None]
         want.append(tok[:, 0])
     assert got == np.stack(want, axis=1).tolist()
+
+
+def test_serving_phases_per_batch_and_token():
+    """An attached profiler gets one route and prefill per batch and, per
+    token, the host's wait; per decode the dispatch, sampling and one
+    billing per model step."""
+    from repro.obs import Observability, StepProfiler
+
+    prof = StepProfiler()
+    cfg, eng = _engine("green", obs=Observability(profile=prof))
+    rng = np.random.default_rng(2)
+    for i in range(2):
+        eng.submit(Request(uid=i, prompt=rng.integers(
+            0, cfg.vocab_size, size=12).astype(np.int32), max_new_tokens=4))
+    assert len(eng.run_batch()) == 2
+    counts = {ph: prof.count(ph) for ph in prof.phases()
+              if ph.startswith("serve.")}
+    assert counts == {"serve.route": 1, "serve.prefill": 1,
+                      "serve.sync": 4, "serve.decode": 3,
+                      "serve.sample": 4, "serve.bill": 4}
+
+
+def test_prefill_and_decode_modules_are_named():
+    """The two jitted steps lower as ``jit_prefill`` and ``jit_decode``, so
+    a profiler trace tells them apart by name."""
+    cfg, eng = _engine("green")
+    toks = jnp.zeros((2, 12), jnp.int32)
+    pre = jax.jit(steps.prefill_step(cfg, eng.max_len)).lower(
+        eng.params, {"tokens": toks})
+    cache, _ = jax.eval_shape(steps.prefill_step(cfg, eng.max_len),
+                              eng.params, {"tokens": toks})
+    dec = jax.jit(steps.decode_fn(cfg)).lower(
+        eng.params, cache, jnp.zeros((2, 1), jnp.int32), jnp.int32(12))
+    assert "module @jit_prefill" in pre.as_text()
+    assert "module @jit_decode" in dec.as_text()
 
 
 def test_green_pod_availability_changes_carbon():
