@@ -232,3 +232,19 @@ class FeatureCache:
         return (self.node_ok(latency_threshold_ms)[None, :]
                 & (self.free_cpu[None, :] >= np.asarray(task_cpu)[:, None])
                 & (self.free_mem[None, :] >= np.asarray(task_mem)[:, None]))
+
+    def usable(self, task_cpu: np.ndarray, task_mem: np.ndarray,
+               ok: np.ndarray) -> np.ndarray:
+        """(N,) nodes that some task fits, given the ``node_ok`` mask:
+        ``feasible(...).any(axis=0)`` in O((B + N) log B), without the
+        (B, N) array. With the tasks sorted by cpu, a node holds the cpu of
+        a prefix of them, and fits one iff the prefix's least memory fits;
+        every compare is the float64 one ``feasible`` makes."""
+        order = np.argsort(task_cpu, kind="stable")
+        cpu = np.asarray(task_cpu, np.float64)[order]
+        least_mem = np.minimum.accumulate(np.asarray(task_mem,
+                                                     np.float64)[order])
+        k = np.searchsorted(cpu, self.free_cpu, side="right")
+        fits = k > 0
+        fits[fits] = least_mem[k[fits] - 1] <= self.free_mem[fits]
+        return ok & fits

@@ -14,6 +14,10 @@ nowhere else:
   6 valid           feasibility filter (Algorithm 1 lines 3-5)
   7 padding
 
+:func:`featurize_columns` hands the Pallas column kernel the same values
+as node columns and task profiles, for the kernel to form each cell on the
+chip.
+
 Policies:
 
 - :class:`WeightedScoringPolicy` — the scalar Python loop (Algorithm 1
@@ -136,6 +140,41 @@ def featurize_cached(cache, tasks: Sequence[Task],
     return F, list(cache.names)
 
 
+def featurize_columns(cache, tasks: Sequence[Task],
+                      provider: Optional[CarbonIntensityProvider] = None,
+                      now_hour: float = 0.0,
+                      latency_threshold_ms: float = 5000.0):
+    """The column select kernel's operands (``kernels/node_score.py``) from
+    a synced :class:`~repro.core.featcache.FeatureCache`, in O(B + N)
+    where :func:`featurize_cached` is O(B x N): (7, N) float32 node rows —
+    free cpu, free memory, load, avg time, running, I x E_est, node_ok —
+    their (4, N) int32 float64 keys of free cpu and memory, the (B, 2)
+    float32 task profiles and their (B, 4) keys.
+
+    Each scored value is the f32 cast of the float64 value
+    ``featurize_cached`` puts in its tensor, and feasibility is left to the
+    kernel's exact compare of the keys. Grid intensity is read for the
+    nodes some task can use (``FeatureCache.usable``), the same set that
+    ``featurize_cached`` queries, so the partial-coverage guarantee
+    carries over.
+    """
+    from repro.kernels.node_score import f64_keys
+
+    task_cpu = np.array([t.cpu for t in tasks], dtype=float)
+    task_mem = np.array([t.mem_mb for t in tasks], dtype=float)
+    ok = cache.node_ok(latency_threshold_ms)
+    ints = cache.intensities(provider, now_hour,
+                             need=cache.usable(task_cpu, task_mem, ok))
+    nodes = np.stack([cache.free_cpu, cache.free_mem, cache.load,
+                      cache.avg_time_s, cache.running, ints * cache.e_est,
+                      ok]).astype(np.float32)
+    node_keys = np.concatenate([f64_keys(cache.free_cpu),
+                                f64_keys(cache.free_mem)])
+    task_rows = np.stack([task_cpu, task_mem], axis=1).astype(np.float32)
+    task_keys = np.concatenate([f64_keys(task_cpu), f64_keys(task_mem)]).T
+    return nodes, node_keys, task_rows, task_keys
+
+
 def get_cache(cluster):
     """The cluster's synced FeatureCache, or None for cluster-likes that
     don't carry one (anything without the EdgeCluster topology plumbing).
@@ -243,17 +282,20 @@ class VectorizedPolicy:
     Fleet-scale fast path (DESIGN.md §3, on by default): features come
     from the cluster's incremental :class:`~repro.core.featcache.
     FeatureCache` (O(changed) per step instead of an O(N) Python rebuild),
-    duplicate task resource profiles share one scored row, the task axis
-    is chunked to bound peak memory, and Pallas shapes are padded to
-    power-of-two buckets so distinct (B, N) stop retriggering jit.
-    ``use_cache=False`` forces the fresh ``featurize`` rebuild — the
-    parity oracle for all of the above.
+    duplicate task resource profiles share one scored row, and Pallas
+    shapes are padded to power-of-two buckets so distinct (B, N) stop
+    retriggering jit. On numpy the task axis is chunked to bound peak
+    memory; on Pallas the host ships node columns and task profiles
+    (:func:`featurize_columns`) and the kernel scores every (task, node)
+    cell on the chip in one launch per step. ``use_cache=False`` forces
+    the fresh ``featurize`` rebuild and the tensor kernel — the parity
+    oracle for all of the above.
     """
 
     name = "vectorized"
 
-    # Bound on elements per (chunk x nodes) scoring block: ~64 MB of f64
-    # features per chunk at FEATURE_DIM=8.
+    # Bound on elements per (chunk x nodes) scoring block of the numpy
+    # paths: ~64 MB of f64 features per chunk at FEATURE_DIM=8.
     _CHUNK_ELEMS = 1 << 20
 
     # Per-config selection-memo size bound: a request mix has a handful of
@@ -358,16 +400,22 @@ class VectorizedPolicy:
         with span(prof, "select.fetch"):
             return np.asarray(idx)[:B], np.asarray(val, np.float64)[:B]
 
+    def _pallas_choices(self, idx: np.ndarray, val: np.ndarray,
+                        names: List[str]) -> List[Optional[str]]:
+        """Names of the fused kernels' winners; Algorithm 1 requires a
+        strictly positive score (best_score init 0)."""
+        if self.capture_scores:
+            # winner-only kernel: runner-up not materialized
+            self._cap_s.append(np.asarray(val, dtype=float))
+            self._cap_r.append(np.full(len(val), np.nan))
+        return [names[b] if v > 0.0 else None for b, v in zip(idx, val)]
+
     def _select_from_features(self, F: np.ndarray, names: List[str],
                               weights: Weights) -> List[Optional[str]]:
-        # Algorithm 1 requires a strictly positive score (best_score init 0).
         if self._resolved_backend() == "pallas":
             idx, val = self._select_pallas_fused(F, weights.as_array())
-            if self.capture_scores:
-                # winner-only kernel: runner-up not materialized
-                self._cap_s.append(np.asarray(val, dtype=float))
-                self._cap_r.append(np.full(len(val), np.nan))
-            return [names[b] if v > 0.0 else None for b, v in zip(idx, val)]
+            return self._pallas_choices(idx, val, names)
+        # Algorithm 1 requires a strictly positive score (best_score init 0).
         totals = self._score_numpy(F, weights.as_array())
         best = np.argmax(totals, axis=1)
         if self.capture_scores:
@@ -507,8 +555,10 @@ class VectorizedPolicy:
     def _select_cached(self, cache, reps: Sequence[Task], weights: Weights,
                        provider, now_hour: float) -> List[Optional[str]]:
         """One fresh scoring pass over the synced cache columns (no memo)."""
-        if (cache.n >= self.COLUMN_PATH_MIN_N
-                and self._resolved_backend() == "numpy"):
+        if self._resolved_backend() == "pallas":
+            return self._select_cached_pallas(cache, reps, weights, provider,
+                                              now_hour)
+        if cache.n >= self.COLUMN_PATH_MIN_N:
             return self._select_cached_columns(cache, reps, weights,
                                                provider, now_hour)
         names = cache.names
@@ -522,6 +572,51 @@ class VectorizedPolicy:
             with span(prof, "score"):
                 out.extend(self._select_from_features(F, names, weights))
         return out
+
+    @classmethod
+    def _pad_columns(cls, nodes: np.ndarray, node_keys: np.ndarray,
+                     tasks: np.ndarray, task_keys: np.ndarray):
+        """:func:`featurize_columns`' operands padded to power-of-two
+        (U, N) buckets: padding nodes are not ok, so they score NEG_INF."""
+        def pad(a, shape):              # np.pad costs 3x this per step
+            out = np.zeros(shape, a.dtype)
+            out[:a.shape[0], :a.shape[1]] = a
+            return out
+
+        Up, Np = cls._bucket(tasks.shape[0]), cls._bucket(nodes.shape[1])
+        return (pad(nodes, (nodes.shape[0], Np)), pad(node_keys, (4, Np)),
+                pad(tasks, (Up, 2)), pad(task_keys, (Up, 4)))
+
+    def _select_cached_pallas(self, cache, reps: Sequence[Task],
+                              weights: Weights, provider,
+                              now_hour: float) -> List[Optional[str]]:
+        """Pallas selection straight from the cache's node columns: the
+        host assembles O(U + N) columns (``featurize``) and the column
+        kernel scores every (task, node) cell on the chip, all U rows in
+        one launch, with the winners of ``select_best_fused`` on
+        ``featurize_cached``'s tensor, bit for bit."""
+        import jax
+
+        from repro.kernels import ops
+
+        prof = self.profiler
+        with span(prof, "featurize"):
+            cols = featurize_columns(cache, reps, provider, now_hour,
+                                     self.latency_threshold_ms)
+            w8 = np.zeros(FEATURE_DIM, np.float32)
+            w8[:5] = weights.as_array()
+        with span(prof, "score"), span(prof, "select.columns"):
+            with span(prof, "select.pad"):
+                padded = self._pad_columns(*cols)
+            with span(prof, "select.put"):
+                args = jax.device_put((*padded, w8))
+            with span(prof, "select.launch"):
+                idx, val = ops.select_best_node_columns(*args)
+            with span(prof, "select.fetch"):
+                idx, val = jax.device_get((idx, val))
+                U = len(reps)
+                idx, val = idx[:U], np.asarray(val[:U], np.float64)
+        return self._pallas_choices(idx, val, cache.names)
 
     def _select_cached_columns(self, cache, reps: Sequence[Task],
                                weights: Weights, provider,

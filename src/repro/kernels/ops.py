@@ -66,6 +66,15 @@ def select_best_node_fused(features, weights):
     return _ns.select_best_fused(features, weights, interpret=_interpret())
 
 
+def select_best_node_columns(nodes, node_keys, tasks, task_keys, weights):
+    """Node columns (7, N) + keys (4, N), task profiles (U, 2) + keys
+    (U, 4), weights (8,) -> ((U,) int32 best index, (U,) f32 best score):
+    the fused select scored from columns on the chip, no (U, N, 8) tensor;
+    see node_score.select_best_columns."""
+    return _ns.select_best_columns(nodes, node_keys, tasks, task_keys,
+                                   weights, interpret=_interpret())
+
+
 def select_best_node_joint(features, weights):
     """(B, P, N, 8) x (8,) -> ((B,) int32 cut idx, (B,) int32 node idx,
     (B,) f32 best score): the fused joint partition+placement reduction —

@@ -10,7 +10,8 @@ from repro.core.api import (CarbonEdgeEngine, FallbackProvider,
                             StaticProvider, TraceProvider)
 from repro.core.cluster import EdgeCluster, NodeSpec, PAPER_NODES
 from repro.core.policy import (VectorizedPolicy, WeightedScoringPolicy,
-                               featurize, featurize_cached)
+                               featurize, featurize_cached,
+                               featurize_columns)
 from repro.core.scheduler import MODES, Task
 from repro.core.temporal import synthetic_trace
 
@@ -82,14 +83,40 @@ def test_parity_with_partial_coverage_provider():
                       if c.nodes[n].load <= 0.8]
     provider = StaticProvider({n: 500.0 for n in feasible_names})
     assert_cache_parity(c, [task], provider)
+    featurize_columns(c.feature_cache(), [task], provider)
 
 
-def test_partial_coverage_uncovered_feasible_node_raises():
+@pytest.mark.parametrize("featurizer", [featurize_cached, featurize_columns])
+def test_partial_coverage_uncovered_feasible_node_raises(featurizer):
     c = EdgeCluster(nodes=PAPER_NODES)
     c.profile(250.0)
     provider = StaticProvider({"node-high": 600.0})   # others uncovered
     with pytest.raises(KeyError):
-        featurize_cached(c.feature_cache(), [Task()], provider)
+        featurizer(c.feature_cache(), [Task()], provider)
+
+
+@pytest.mark.parametrize("seed", range(8))
+def test_usable_matches_feasible_any(seed):
+    """``usable`` is ``feasible(...).any(axis=0)`` without the (B, N)
+    array, also where needs sit at a node's free cpu or memory or one
+    float64 ulp to either side of it."""
+    rng = np.random.default_rng(seed)
+    c = random_cluster(rng, 40)
+    cache = c.feature_cache()
+    n = int(rng.integers(1, 4)) if seed % 2 else int(rng.integers(4, 16))
+    j = rng.integers(0, 40, n)
+    step = rng.choice([-np.inf, 0.0, np.inf], n)  # 0.0: exactly at it
+    cpu = np.where(step == 0.0, cache.free_cpu[j],
+                   np.nextafter(cache.free_cpu[j], step))
+    mem = np.where(step == 0.0, cache.free_mem[j],
+                   np.nextafter(cache.free_mem[j], step))
+    low = rng.random(n) < 0.5           # the other need fits node j
+    cpu[low] *= rng.uniform(0.0, 1.0, n)[low]
+    mem[~low] *= rng.uniform(0.0, 1.0, n)[~low]
+    for sl in [slice(t, t + 1) for t in range(n)] + [slice(None)]:
+        np.testing.assert_array_equal(
+            cache.usable(cpu[sl], mem[sl], cache.node_ok()),
+            cache.feasible(cpu[sl], mem[sl]).any(axis=0))
 
 
 def test_topology_changes_rebuild():

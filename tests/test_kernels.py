@@ -224,3 +224,82 @@ def test_select_best_node():
     ref = int(np.argmax(np.asarray(ops.node_scores_ref(jnp.asarray(f), jnp.asarray(w)))))
     assert best == ref
     assert f[best, 6] == 1.0
+
+
+def _column_case(U, N, seed):
+    """A fleet of N nodes (node 0 the best for a small task, copied at
+    node 1 and at node N - 1 for exact score ties within and across node
+    tiles) and U task profiles, led by the cases the column kernel must
+    decide as the host's float64 compare does: cpu and memory one ulp
+    above, at and one ulp below node 0's free cpu and free memory, a zero
+    need of each, and a task that fits nowhere; then random profiles."""
+    from repro.core.cluster import EdgeCluster, NodeSpec
+    from repro.core.scheduler import Task
+
+    rng = np.random.default_rng(seed)
+    cpu = rng.uniform(0.1, 4.0, N)
+    mem = rng.integers(128, 4096, N)
+    inten = rng.uniform(10.0, 1200.0, N)
+    load = rng.uniform(0.0, 0.95, N)
+    used = rng.uniform(0.0, 1.0, N) * mem
+    running = rng.integers(0, 5, N)
+    cpu[0], mem[0], inten[0], load[0], used[0], running[0] = (
+        4.0, 4096, 5.0, 0.0123456789, 7.654321, 0)
+    for k in {1, N - 1}:
+        cpu[k], mem[k], inten[k], load[k], used[k], running[k] = (
+            cpu[0], mem[0], inten[0], load[0], used[0], running[0])
+    c = EdgeCluster(nodes=[NodeSpec(f"n{i}", cpu=float(cpu[i]),
+                                    mem_mb=int(mem[i]),
+                                    carbon_intensity=float(inten[i]))
+                           for i in range(N)], host_power_w=142.0)
+    c.profile(250.0)
+    for i, st in enumerate(c.nodes.values()):
+        st.load, st.mem_used_mb = float(load[i]), float(used[i])
+        st.running = int(running[i])
+    cache = c.feature_cache()
+    fc, fm = cache.free_cpu[0], cache.free_mem[0]
+    up, down = (lambda x: np.nextafter(x, np.inf),
+                lambda x: np.nextafter(x, -np.inf))
+    prof = [(up(fc), 1.0), (0.01, up(fm)), (fc, 1.0), (0.01, fm),
+            (down(fc), 1.0), (0.01, down(fm)), (0.0, 64.0), (0.5, 0.0),
+            (0.01, 1e9)]
+    prof += [(rng.uniform(0.01, 2.0), rng.uniform(8.0, 4000.0))
+             for _ in range(max(0, U - len(prof)))]
+    return cache, [Task(cpu=float(a), mem_mb=float(b)) for a, b in prof[:U]]
+
+
+@pytest.mark.parametrize("U,N", [(1, 3), (9, 3), (12, 300), (1, 1500),
+                                 (40, 1500), (9, 1024)])
+def test_select_best_columns_matches_fused_tensor(U, N):
+    """The column kernel returns what ``select_best_fused`` returns on
+    ``featurize_cached``'s padded tensor, bit for bit: exact ties to the
+    lowest index, NEG_INF rows, zero needs, and feasibility one float64
+    ulp either side of a node's free cpu and memory, which a float32
+    compare decides wrongly."""
+    from repro.core.policy import (VectorizedPolicy, featurize_cached,
+                                   featurize_columns)
+
+    cache, tasks = _column_case(U, N, seed=U * N)
+    w = np.array([0.15, 0.15, 0.1, 0.1, 0.5, 0, 0, 0], np.float32)
+    F, _ = featurize_cached(cache, tasks)
+    want_i, want_v = ops.select_best_node_fused(
+        jnp.asarray(VectorizedPolicy._pad_to_buckets(F)), jnp.asarray(w))
+    got_i, got_v = ops.select_best_node_columns(
+        *map(jnp.asarray, featurize_columns(cache, tasks)), jnp.asarray(w))
+    np.testing.assert_array_equal(np.asarray(got_i),
+                                  np.asarray(want_i)[:U])
+    np.testing.assert_array_equal(np.asarray(got_v),
+                                  np.asarray(want_v)[:U])
+    # the case is hard: the leading rows' exact feasibility on node 0
+    # differs from a float32 compare's, and node 0 (the best) wins
+    # exactly where it is feasible (index 0 with NEG_INF is no winner)
+    need = np.array([[t.cpu, t.mem_mb] for t in tasks])
+    free = np.array([cache.free_cpu[0], cache.free_mem[0]])
+    exact = np.all(free >= need, axis=1)
+    f32 = np.all(free.astype(np.float32) >= need.astype(np.float32), axis=1)
+    assert not np.array_equal(exact, f32)
+    lead = slice(0, min(U, 6))
+    won = (np.asarray(got_i) == 0) & (np.asarray(got_v) > 0)
+    np.testing.assert_array_equal(won[lead], exact[lead])
+    if U >= 9:
+        assert np.asarray(got_v)[8] < -1e29        # fits nowhere

@@ -264,21 +264,23 @@ def test_span_lands_in_profiler_host_plane(tmp_path):
 
 
 def test_pallas_select_splits_score_into_pad_put_launch_fetch():
-    """On the fused-kernel path ``score`` holds one ``select.pad``,
-    ``select.put``, ``select.launch`` and ``select.fetch`` per chunk, and
-    they add up to no more than ``score``."""
+    """On the Pallas column path each call's ``score`` holds one
+    ``select.columns``, and it one ``select.pad``, ``select.put``,
+    ``select.launch`` and ``select.fetch``, which add up to no more than
+    it: all the call's rows go in one launch."""
     rng = np.random.default_rng(3)
     pol = VectorizedPolicy(backend="pallas", use_select_memo=False)
-    pol._CHUNK_ELEMS = 4 * len(PAPER_NODES)        # chunks of 4 rows
     pol.profiler = p = StepProfiler()
     tasks = [Task(cpu=float(c), mem_mb=float(m))
              for c, m in zip(rng.uniform(0.01, 0.2, 10),
                              rng.uniform(8, 64, 10))]
-    pol.select_batch(fresh_cluster(), tasks, MODES["green"])
+    for _ in range(3):
+        pol.select_batch(fresh_cluster(), tasks, MODES["green"])
     parts = ("select.pad", "select.put", "select.launch", "select.fetch")
     assert [p.count(ph) for ph in parts] == [3] * 4
-    assert p.count("score") == 3
-    assert sum(p.total_s(ph) for ph in parts) <= p.total_s("score")
+    assert p.count("score") == p.count("select.columns") == 3
+    assert (sum(p.total_s(ph) for ph in parts) <= p.total_s("select.columns")
+            <= p.total_s("score"))
 
 
 # ---------------------------------------------------------------------------

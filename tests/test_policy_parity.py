@@ -151,28 +151,78 @@ def test_select_batch_matches_select():
     assert batch == ORACLE.select_batch(c, tasks, w)
 
 
-def test_pallas_compile_count_bounded_across_fleet_sizes():
+@pytest.mark.parametrize("use_cache,kernel", [
+    (True, "select_best_columns"), (False, "select_best_fused")])
+def test_pallas_compile_count_bounded_across_fleet_sizes(use_cache, kernel):
     """Regression (ISSUE 3 satellite): the Pallas scorer pads (B, N) to
     power-of-two shape buckets, so a sweep over many distinct fleet/batch
     sizes may only add as many jit entries as there are distinct buckets —
-    not one per (B, N)."""
+    not one per (B, N) — on the cached column path and on the fresh
+    tensor path alike."""
     from repro.kernels import node_score as ns
 
-    pol = VectorizedPolicy(backend="pallas")
+    fn = getattr(ns, kernel)
+    pol = VectorizedPolicy(backend="pallas", use_cache=use_cache)
     sweep = [(1, 3), (2, 5), (3, 9), (2, 17), (4, 33), (1, 40),
              (5, 65), (2, 100), (3, 129), (1, 200)]
     buckets = set()
     rng = np.random.default_rng(0)
-    baseline = ns.select_best_fused._cache_size()
+    baseline = fn._cache_size()
     for b, n in sweep:
         c = random_cluster(rng, n)
         tasks = [random_task(rng) for _ in range(b)]
         pol.select_batch(c, tasks, MODES["green"])
         buckets.add((pol._bucket(len({(t.cpu, t.mem_mb) for t in tasks})),
                      pol._bucket(n)))
-    grown = ns.select_best_fused._cache_size() - baseline
+    grown = fn._cache_size() - baseline
     assert grown <= len(buckets), (grown, sorted(buckets))
     assert len(buckets) < len(sweep)           # bucketing actually coalesces
+
+
+def _engine(backend, n=256, seed=31, **kw):
+    from repro.core.api import CarbonEdgeEngine
+
+    return CarbonEdgeEngine(random_cluster(np.random.default_rng(seed), n),
+                            policy=VectorizedPolicy(backend=backend), **kw)
+
+
+def _continuous_batches(seed, steps=3, b=48):
+    """Distinct continuous profiles, memory up to about half the largest
+    node's, so the feasibility filter moves winners."""
+    rng = np.random.default_rng(seed)
+    return [[Task(cpu=float(rng.uniform(0.01, 1.0)),
+                  mem_mb=float(rng.uniform(8.0, 1000.0)),
+                  base_latency_ms=float(rng.uniform(50.0, 500.0)))
+             for _ in range(b)] for _ in range(steps)]
+
+
+def test_engine_pallas_places_as_numpy_at_fleet_scale():
+    """With ``backend="pallas"`` (the column kernel) the engine places a
+    256-node fleet with continuous task profiles exactly as the float64
+    numpy backend does, step after step."""
+    placed = {}
+    for backend in ("numpy", "pallas"):
+        eng = _engine(backend)
+        placed[backend] = [[r.node for r in eng.submit_many(b).step()]
+                           for b in _continuous_batches(37)]
+    assert [len(p) for p in placed["pallas"]] == [48] * 3
+    assert placed["pallas"] == placed["numpy"]
+
+
+@pytest.mark.parametrize("use_cache", [True, False])
+def test_select_columns_span_counts_one_per_step(use_cache):
+    """``select.columns`` counts the steps the column path scored: one per
+    engine step on the cached Pallas path, none on the fresh tensor path;
+    either way one kernel launch per step."""
+    from repro.obs import Observability, StepProfiler
+
+    prof = StepProfiler()
+    eng = _engine("pallas", n=64, obs=Observability(profile=prof))
+    eng.policy.use_cache = use_cache
+    for b in _continuous_batches(41):
+        eng.submit_many(b).step()
+    assert prof.count("select.columns") == (3 if use_cache else 0)
+    assert prof.count("select.launch") == 3
 
 
 def test_cached_column_path_matches_fresh_at_fleet_scale():

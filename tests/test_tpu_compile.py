@@ -61,6 +61,20 @@ def test_select_best_fused_compiles(one_chip, B, N):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("U,N", [(8, 8), (1024, 16384)])
+def test_select_best_columns_compiles(one_chip, U, N):
+    """The router's (8, 8) bucket and metro-10k's step of 1024 rows over
+    10^4 nodes, scored from node columns in one launch."""
+    spec = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    text = _compiled_text(
+        ns.select_best_columns,
+        spec((7, N), jnp.float32), spec((4, N), jnp.int32),
+        spec((U, 2), jnp.float32), spec((U, 4), jnp.int32),
+        spec((8,), jnp.float32))
+    assert "tpu_custom_call" in text
+
+
 def test_select_best_joint_compiles(one_chip):
     text = _compiled_text(
         ns.select_best_joint,
