@@ -4,10 +4,12 @@ from repro.configs.base import (
     INPUT_SHAPES,
     InputShape,
     LayerDef,
+    MLAConfig,
     ModelConfig,
     MoEConfig,
     SSMConfig,
     XLSTMConfig,
+    YarnScaling,
 )
 
 __all__ = [
@@ -16,8 +18,10 @@ __all__ = [
     "INPUT_SHAPES",
     "InputShape",
     "LayerDef",
+    "MLAConfig",
     "ModelConfig",
     "MoEConfig",
     "SSMConfig",
     "XLSTMConfig",
+    "YarnScaling",
 ]

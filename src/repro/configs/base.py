@@ -35,10 +35,63 @@ class MoEConfig:
     # router-masked and unreachable — pure deployment layout, no semantic
     # change). 0 = num_experts.
     padded_experts: int = 0
+    # DeepSeekMoE options: renormalise the top-k weights (qwen2-moe) or
+    # keep the softmax scores (DeepSeek-V2, ``norm_topk_prob`` false);
+    # scale the routed sum; put the shared experts behind a sigmoid gate.
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    shared_gate: bool = True
+    # Expert parallelism without the mesh: this chip holds experts
+    # [expert_offset, expert_offset + experts_held) of ``num_experts``; the
+    # router still scores all of them and the layer adds only the held
+    # experts' part (plus the shared experts). 0 = all of ``e_pad``.
+    experts_held: int = 0
+    expert_offset: int = 0
 
     @property
     def e_pad(self) -> int:
         return max(self.num_experts, self.padded_experts)
+
+    @property
+    def n_held(self) -> int:
+        """Experts whose weights this chip holds (the expert axis of the
+        weight arrays)."""
+        return self.experts_held or self.e_pad
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434 §2.1)
+    without a query low-rank projection: keys and values come from a
+    ``kv_lora_rank`` latent plus one ``qk_rope_head_dim`` rope key shared
+    by all heads."""
+
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def latent_dim(self) -> int:
+        """Width of one cached position: the normed latent and the roped
+        shared key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+
+@dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rope scaling (arXiv:2309.00071; DeepSeek-V2's appendix)."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -67,12 +120,15 @@ class XLSTMConfig:
 class LayerDef:
     """One layer in the stack pattern.
 
-    kind: "attn" | "mamba2" | "mlstm" | "slstm"
+    kind: "attn" | "mla" | "mamba2" | "mlstm" | "slstm"
     window: sliding-window size for attention layers (None = global/full).
+    dense: in a model with experts, this layer's feed-forward is the dense
+    SwiGLU of width ``d_ff`` instead (DeepSeek's leading dense layers).
     """
 
     kind: str = "attn"
     window: Optional[int] = None
+    dense: bool = False
 
 
 # ---------------------------------------------------------------------------
@@ -92,23 +148,27 @@ class ModelConfig:
     vocab_size: int
     head_dim: int = 0            # 0 -> d_model // num_heads
 
-    # Layer stack: `pattern` repeated `repeats` times followed by `suffix`.
-    # len(pattern) * repeats + len(suffix) must equal num_layers.
+    # Layer stack: `prefix`, then `pattern` repeated `repeats` times, then
+    # `suffix`. len(prefix) + len(pattern) * repeats + len(suffix) must
+    # equal num_layers. Each of prefix and suffix is one kind of layer.
     pattern: Tuple[LayerDef, ...] = (LayerDef("attn"),)
-    repeats: int = 0             # 0 -> num_layers (pattern must be length 1)
+    repeats: int = 0             # 0 -> the rest (pattern must be length 1)
     suffix: Tuple[LayerDef, ...] = ()
+    prefix: Tuple[LayerDef, ...] = ()
 
     # Attention details.
     qkv_bias: bool = False
     qk_norm: bool = False
     attn_logit_softcap: float = 0.0
     rope_theta: float = 10000.0
+    rope_scaling: Optional[YarnScaling] = None
     pos_emb: str = "rope"        # rope | learned | none
     mrope_sections: Tuple[int, ...] = ()   # qwen2-vl M-RoPE (sums to head_dim/2)
     max_position: int = 1 << 20  # for learned pos-emb sizing
 
     # Sub-blocks.
     moe: Optional[MoEConfig] = None
+    mla: Optional[MLAConfig] = None
     ssm: Optional[SSMConfig] = None
     xlstm: Optional[XLSTMConfig] = None
 
@@ -143,12 +203,13 @@ class ModelConfig:
         if self.repeats == 0:
             if len(self.pattern) != 1:
                 raise ValueError(f"{self.name}: repeats=0 needs len(pattern)==1")
-            object.__setattr__(self, "repeats", self.num_layers - len(self.suffix))
-        n = len(self.pattern) * self.repeats + len(self.suffix)
+            object.__setattr__(self, "repeats", self.num_layers
+                               - len(self.prefix) - len(self.suffix))
+        n = len(self.prefix) + len(self.pattern) * self.repeats + len(self.suffix)
         if n != self.num_layers:
             raise ValueError(
-                f"{self.name}: pattern*repeats+suffix = {n} != num_layers "
-                f"{self.num_layers}"
+                f"{self.name}: prefix+pattern*repeats+suffix = {n} != "
+                f"num_layers {self.num_layers}"
             )
         if self.num_heads % max(self.num_kv_heads, 1):
             raise ValueError(f"{self.name}: heads {self.num_heads} not divisible "
@@ -159,7 +220,11 @@ class ModelConfig:
     # -- derived ----------------------------------------------------------
     @property
     def layer_defs(self) -> Tuple[LayerDef, ...]:
-        return self.pattern * self.repeats + self.suffix
+        return self.prefix + self.pattern * self.repeats + self.suffix
+
+    def is_moe_layer(self, ld: LayerDef) -> bool:
+        """Whether layer ``ld``'s feed-forward is the expert layer."""
+        return self.moe is not None and ld.kind in ("attn", "mla") and not ld.dense
 
     @property
     def q_per_kv(self) -> int:
@@ -182,6 +247,7 @@ class ModelConfig:
             self,
             pattern=tuple(w(ld) for ld in self.pattern),
             suffix=tuple(w(ld) for ld in self.suffix),
+            prefix=tuple(w(ld) for ld in self.prefix),
         )
 
     # -- parameter counting (analytic; used by partitioner & roofline) ----

@@ -6,6 +6,7 @@ from typing import Callable, Dict
 
 from repro.configs.base import (
     LayerDef,
+    MLAConfig,
     ModelConfig,
     MoEConfig,
     SSMConfig,
@@ -15,6 +16,7 @@ from repro.configs.base import (
 from repro.configs import (  # noqa: E402
     arctic_480b,
     command_r_35b,
+    deepseek_v2_lite,
     gemma3_27b,
     qwen1p5_4b,
     qwen2_moe_a2p7b,
@@ -36,6 +38,7 @@ _REGISTRY: Dict[str, Callable[[], ModelConfig]] = {
     "qwen2-moe-a2.7b": qwen2_moe_a2p7b.make_config,
     "qwen3-1.7b": qwen3_1p7b.make_config,
     "qwen2-vl-2b": qwen2_vl_2b.make_config,
+    "deepseek-v2-lite": deepseek_v2_lite.make_config,
 }
 
 ARCH_IDS = tuple(_REGISTRY)
@@ -53,12 +56,15 @@ def list_archs():
 
 # ---------------------------------------------------------------------------
 # Reduced variants for CPU smoke tests: <=2-ish layers (one of each block
-# kind in the family), d_model<=512, <=4 experts, small vocab.
+# kind in the family), d_model<=512, <=4 experts, small vocab. A model with
+# leading dense layers keeps one of them and one layer of its pattern.
 # ---------------------------------------------------------------------------
 
 
 def reduced_config(arch: str) -> ModelConfig:
     cfg = get_config(arch)
+    if cfg.prefix:
+        return _reduced_with_prefix(cfg)
     # Keep one instance of every distinct layer kind (max 2 layers).
     kinds = []
     pat = []
@@ -110,6 +116,38 @@ def reduced_config(arch: str) -> ModelConfig:
         encoder_seq=32 if cfg.encoder_seq else 0,
         vision_tokens=16 if cfg.vision_tokens else 0,
         mrope_sections=(8, 12, 12) if cfg.mrope_sections else (),
+        max_position=1 << 14,
+        dtype="float32",
+        param_dtype="float32",
+        remat=False,
+    )
+
+
+def _reduced_with_prefix(cfg: ModelConfig) -> ModelConfig:
+    """One leading dense layer and one pattern layer at small widths; MLA,
+    YaRN and the MoE options (scores, gating, held share) kept."""
+    mla = cfg.mla and MLAConfig(kv_lora_rank=64, qk_nope_head_dim=32,
+                                qk_rope_head_dim=16, v_head_dim=32)
+    moe = cfg.moe and dataclasses.replace(
+        cfg.moe, num_experts=4, top_k=min(2, cfg.moe.top_k), expert_ff=128,
+        num_shared_experts=min(2, cfg.moe.num_shared_experts),
+        padded_experts=0, experts_held=0, expert_offset=0)
+    return dataclasses.replace(
+        cfg,
+        name=cfg.name + "-reduced",
+        num_layers=2,
+        d_model=256,
+        num_heads=4,
+        num_kv_heads=4,
+        head_dim=mla.qk_head_dim if mla else 64,
+        d_ff=512,
+        vocab_size=512,
+        prefix=cfg.prefix[:1],
+        pattern=cfg.pattern[:1],
+        repeats=1,
+        suffix=(),
+        moe=moe,
+        mla=mla,
         max_position=1 << 14,
         dtype="float32",
         param_dtype="float32",

@@ -53,19 +53,61 @@ def _attn_params(cfg: ModelConfig) -> int:
     return n
 
 
+def _mla_params(cfg: ModelConfig) -> int:
+    D, H, m = cfg.d_model, cfg.num_heads, cfg.mla
+    return (D * H * m.qk_head_dim + D * m.latent_dim + m.kv_lora_rank
+            + m.kv_lora_rank * H * (m.qk_nope_head_dim + m.v_head_dim)
+            + H * m.v_head_dim * D)
+
+
 def _mlp_params(cfg: ModelConfig, d_ff: int, gated: bool = True) -> int:
     return cfg.d_model * d_ff * (3 if gated else 2)
 
 
-def _moe_params(cfg: ModelConfig, active_only: bool = False) -> int:
+def expert_params(cfg: ModelConfig) -> int:
+    """One routed expert's SwiGLU weights."""
+    return 3 * cfg.d_model * cfg.moe.expert_ff
+
+
+def experts_held(cfg: ModelConfig) -> int:
+    """Real (not padding) experts whose weights this chip holds."""
     m = cfg.moe
-    e = m.top_k if active_only else m.num_experts
-    n = e * 3 * cfg.d_model * m.expert_ff + cfg.d_model * m.num_experts
+    return max(0, min(m.n_held, m.num_experts - m.expert_offset))
+
+
+def routed_per_token(cfg: ModelConfig) -> float:
+    """Expected held experts one token is routed to: top_k of the router's
+    num_experts, scaled by the share held here."""
+    m = cfg.moe
+    return m.top_k * experts_held(cfg) / m.num_experts
+
+
+def experts_touched(cfg: ModelConfig, tokens: int) -> float:
+    """Expected held experts that at least one of ``tokens`` tokens is
+    routed to (each token picks top_k of num_experts)."""
+    m = cfg.moe
+    return experts_held(cfg) * (1.0 - (1.0 - m.top_k / m.num_experts) ** tokens)
+
+
+def _moe_params(cfg: ModelConfig, active_only: bool = False) -> float:
+    m = cfg.moe
+    e = routed_per_token(cfg) if active_only else experts_held(cfg)
+    n = e * expert_params(cfg) + cfg.d_model * m.num_experts
     if m.num_shared_experts:
-        n += _mlp_params(cfg, m.num_shared_experts * m.expert_ff) + cfg.d_model
+        n += _mlp_params(cfg, m.num_shared_experts * m.expert_ff)
+        if m.shared_gate:
+            n += cfg.d_model
     if m.dense_residual_ff:
         n += _mlp_params(cfg, m.dense_residual_ff)
     return n
+
+
+def _ffn_params(cfg: ModelConfig, ld: LayerDef, active_only: bool) -> float:
+    if cfg.is_moe_layer(ld):
+        return _moe_params(cfg, active_only)
+    if cfg.d_ff > 0:
+        return _mlp_params(cfg, cfg.d_ff, cfg.mlp_gated)
+    return 0
 
 
 def _mamba2_params(cfg: ModelConfig) -> int:
@@ -97,17 +139,18 @@ def _slstm_params(cfg: ModelConfig) -> int:
     return gates + D + 3 * D * ff
 
 
-def block_params(cfg: ModelConfig, ld: LayerDef, active_only: bool = False) -> int:
+def block_params(cfg: ModelConfig, ld: LayerDef, active_only: bool = False) -> float:
+    """Parameters of one block; ``active_only``: those one token multiplies
+    through (an expert layer's routed experts in expectation over the
+    experts held here)."""
     D = cfg.d_model
     if ld.kind == "attn":
         n = _attn_params(cfg) + 2 * D  # + norms
         if cfg.cross_attention:
             n += _attn_params(cfg) + D
-        if cfg.moe is not None:
-            n += _moe_params(cfg, active_only)
-        elif cfg.d_ff > 0:
-            n += _mlp_params(cfg, cfg.d_ff, cfg.mlp_gated)
-        return n
+        return n + _ffn_params(cfg, ld, active_only)
+    if ld.kind == "mla":
+        return _mla_params(cfg) + 2 * D + _ffn_params(cfg, ld, active_only)
     if ld.kind == "mamba2":
         return _mamba2_params(cfg) + D
     if ld.kind == "mlstm":
@@ -126,10 +169,13 @@ def model_param_count(cfg: ModelConfig) -> int:
         n += cfg.encoder_layers * (_attn_params(cfg)
                                    + _mlp_params(cfg, cfg.d_ff, cfg.mlp_gated)
                                    + 2 * cfg.d_model)
-    return n
+    return int(round(n))
 
 
 def model_active_param_count(cfg: ModelConfig) -> int:
+    """Parameters one token multiplies through on this chip: everything
+    outside the routed experts in full, and the routed experts held here in
+    expectation (top_k of num_experts, times the held share)."""
     n = cfg.vocab_size * cfg.d_model
     if not cfg.tie_embeddings:
         n += cfg.d_model * cfg.vocab_size
@@ -138,7 +184,7 @@ def model_active_param_count(cfg: ModelConfig) -> int:
         n += cfg.encoder_layers * (_attn_params(cfg)
                                    + _mlp_params(cfg, cfg.d_ff, cfg.mlp_gated)
                                    + 2 * cfg.d_model)
-    return n
+    return int(round(n))
 
 
 def block_flops(cfg: ModelConfig, ld: LayerDef, seq: int, batch: int,
@@ -150,7 +196,14 @@ def block_flops(cfg: ModelConfig, ld: LayerDef, seq: int, batch: int,
     """
     tokens = batch * (1 if kind == "decode" else seq)
     f = 2.0 * tokens * block_params(cfg, ld, active_only=True)
-    if ld.kind == "attn":
+    if ld.kind == "mla":
+        m = cfg.mla
+        if kind == "decode":   # absorbed: scores over the latent, values over c
+            f += 2.0 * batch * cfg.num_heads * kv_len * (m.latent_dim + m.kv_lora_rank)
+        else:                  # expanded, causal
+            f += (2.0 * batch * cfg.num_heads * (m.qk_head_dim + m.v_head_dim)
+                  * seq * seq / 2.0)
+    elif ld.kind == "attn":
         ctx = kv_len if kind == "decode" else seq
         if ld.window is not None:
             ctx = min(ctx, ld.window)
@@ -210,17 +263,15 @@ def _block_act_bytes(cfg: ModelConfig, ld: LayerDef, tokens: int, seq: int,
             ctx = seq if ld.window is None else min(seq, ld.window)
             nb = max(1, seq // _Q_BLOCK)
             b += nb * tokens / max(seq, 1) * ctx * 2 * K * hd * _ACT_B  # kv re-reads
-        if cfg.moe is not None:
-            m = cfg.moe
-            cap = tokens * m.top_k * 1.25
-            b += cap * D * rw * 2                # grouped in/out buffers
-            b += cap * m.expert_ff * rw          # expert hidden
-            if m.num_shared_experts:
-                b += tokens * m.num_shared_experts * m.expert_ff * rw
-            if m.dense_residual_ff:
-                b += tokens * m.dense_residual_ff * rw
-        elif cfg.d_ff > 0:
-            b += tokens * cfg.d_ff * rw * (2 if cfg.mlp_gated else 1)
+        b += _ffn_act_bytes(cfg, ld, tokens)
+    elif ld.kind == "mla":
+        H, m = cfg.num_heads, cfg.mla
+        b += tokens * D * rw * 2                 # block in/out residual
+        b += tokens * (H * m.qk_head_dim + m.latent_dim) * rw   # q, latent
+        b += tokens * H * m.v_head_dim * rw      # attn out pre-proj
+        if kind != "decode":                     # per-head k, v expanded
+            b += tokens * H * (m.qk_head_dim + m.v_head_dim) * rw
+        b += _ffn_act_bytes(cfg, ld, tokens)
     elif ld.kind == "mamba2":
         from repro.models import ssm as ssm_mod
 
@@ -246,6 +297,23 @@ def _block_act_bytes(cfg: ModelConfig, ld: LayerDef, tokens: int, seq: int,
     return b
 
 
+def _ffn_act_bytes(cfg: ModelConfig, ld: LayerDef, tokens: int) -> float:
+    rw = 2 * _ACT_B
+    if cfg.is_moe_layer(ld):
+        m = cfg.moe
+        cap = tokens * m.top_k * 1.25
+        b = cap * cfg.d_model * rw * 2           # grouped in/out buffers
+        b += cap * m.expert_ff * rw              # expert hidden
+        if m.num_shared_experts:
+            b += tokens * m.num_shared_experts * m.expert_ff * rw
+        if m.dense_residual_ff:
+            b += tokens * m.dense_residual_ff * rw
+        return b
+    if cfg.d_ff > 0:
+        return tokens * cfg.d_ff * rw * (2 if cfg.mlp_gated else 1)
+    return 0.0
+
+
 def _cache_bytes(cfg: ModelConfig, seq: int, batch: int) -> float:
     """KV/state cache read+write traffic for one decode step."""
     total = 0.0
@@ -255,6 +323,8 @@ def _cache_bytes(cfg: ModelConfig, seq: int, batch: int) -> float:
             total += batch * ctx * 2 * cfg.num_kv_heads * cfg.head_dim * _ACT_B
             if cfg.cross_attention:
                 total += batch * cfg.encoder_seq * 2 * cfg.num_kv_heads * cfg.head_dim * _ACT_B
+        elif ld.kind == "mla":
+            total += batch * seq * cfg.mla.latent_dim * _ACT_B
         elif ld.kind == "mamba2":
             from repro.models import ssm as ssm_mod
 
@@ -273,10 +343,16 @@ def _cache_bytes(cfg: ModelConfig, seq: int, batch: int) -> float:
 
 
 def step_hbm_bytes(cfg: ModelConfig, seq: int, batch: int, kind: str) -> float:
-    """Whole-step analytic HBM bytes (global, all chips combined)."""
+    """Whole-step analytic HBM bytes (global, all chips combined). In
+    serving, an expert layer reads the held experts that the step's tokens
+    touch, not one token's share."""
     p_act = model_active_param_count(cfg)
     tokens = batch * (1 if kind == "decode" else seq)
     wb = _ACT_B * p_act
+    if cfg.moe is not None and kind != "train":
+        n_moe = sum(cfg.is_moe_layer(ld) for ld in cfg.layer_defs)
+        wb += _ACT_B * n_moe * expert_params(cfg) * (
+            experts_touched(cfg, tokens) - routed_per_token(cfg))
     act = sum(_block_act_bytes(cfg, ld, tokens, seq, kind)
               for ld in cfg.layer_defs)
     if cfg.encoder_layers and kind != "decode":

@@ -73,12 +73,14 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 def flash_attention(q, k, v, *, causal: bool = True,
                     window: Optional[int] = None, softcap: float = 0.0,
                     bq: int = 128, bk: int = 128, interpret: bool = False):
-    """q: (B, H, Sq, hd); k/v: (B, K, Sk, hd) with H % K == 0.
+    """q/k: (B, H|K, S, hd); v: (B, K, Sk, hdv) with H % K == 0. The value
+    head may be narrower than the query/key head (MLA: 192 and 128).
 
-    Returns (B, H, Sq, hd). Sq/Sk must be multiples of bq/bk.
+    Returns (B, H, Sq, hdv). Sq/Sk must be multiples of bq/bk.
     """
     B, H, Sq, hd = q.shape
     K, Sk = k.shape[1], k.shape[2]
+    hdv = v.shape[3]
     g = H // K
     nq, nk = Sq // bq, Sk // bk
     scale = hd ** -0.5
@@ -94,12 +96,12 @@ def flash_attention(q, k, v, *, causal: bool = True,
         in_specs=[
             pl.BlockSpec((1, 1, bq, hd), lambda b, h, iq, ik: (b, h, iq, 0)),
             pl.BlockSpec((1, 1, bk, hd), lambda b, h, iq, ik: (b, h // g, ik, 0)),
-            pl.BlockSpec((1, 1, bk, hd), lambda b, h, iq, ik: (b, h // g, ik, 0)),
+            pl.BlockSpec((1, 1, bk, hdv), lambda b, h, iq, ik: (b, h // g, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, bq, hd), lambda b, h, iq, ik: (b, h, iq, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, Sq, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, bq, hdv), lambda b, h, iq, ik: (b, h, iq, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, H, Sq, hdv), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((bq, hd), jnp.float32),
+            pltpu.VMEM((bq, hdv), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],

@@ -54,6 +54,26 @@ def decode_attention_ref(q, k, v, pos, *, window: Optional[int] = None,
     return jnp.einsum("bhk,bhkd->bhd", p, v.astype(jnp.float32)).astype(q.dtype)
 
 
+def mla_decode_attention_ref(q, cache, pos, *, scale: float, rank: int):
+    """q: (B,H,W) absorbed queries; cache: (B,S,W); pos scalar. Softmax
+    over the positions up to ``pos`` of q . cache, weighting the first
+    ``rank`` columns of the cache."""
+    c = cache.astype(jnp.float32)
+    s = jnp.einsum("bhw,btw->bht", q.astype(jnp.float32), c) * scale
+    s = jnp.where((jnp.arange(cache.shape[1]) <= pos)[None, None], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bht,btr->bhr", p, c[..., :rank]).astype(cache.dtype)
+
+
+def moe_gmm_ref(x, w_gate, w_up, w_down, sizes, act=jax.nn.silu):
+    """Grouped gated MLP by ``ragged_dot``: rows [sum(sizes[:e]), +sizes[e])
+    through expert e; rows past sum(sizes) give zeros."""
+    g = jax.lax.ragged_dot(x, w_gate, sizes)
+    u = jax.lax.ragged_dot(x, w_up, sizes)
+    h = (act(g) * u).astype(x.dtype)
+    return jax.lax.ragged_dot(h, w_down, sizes)
+
+
 def mamba2_chunk_ref(xdt, Bh, Ch, cum, state):
     """Sequential within-chunk recurrence (the ground truth).
 

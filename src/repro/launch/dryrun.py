@@ -165,7 +165,8 @@ def full_pass(cfg, shape, multi_pod: bool):
 
 def _acct_cfg(cfg: ModelConfig, r: int) -> ModelConfig:
     return dataclasses.replace(
-        cfg, repeats=r, num_layers=len(cfg.pattern) * r + len(cfg.suffix))
+        cfg, repeats=r,
+        num_layers=len(cfg.prefix) + len(cfg.pattern) * r + len(cfg.suffix))
 
 
 def _acct_metrics(cfg, shape, mesh):

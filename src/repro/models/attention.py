@@ -166,7 +166,7 @@ def chunked_attention(cfg: ModelConfig, q, k, v, *, causal: bool,
         return None, _attend_dense(cfg, qb, k, v, mask)
 
     _, outs = modes.scan(body, None, jnp.arange(nb))
-    out = outs.transpose(1, 0, 2, 3, 4).reshape(B, nb * q_block, H, hd)
+    out = outs.transpose(1, 0, 2, 3, 4).reshape(B, nb * q_block, H, v.shape[-1])
     if rem:
         qb = q[:, nb * q_block:]
         mask = _make_mask_dyn(rem, S, nb * q_block, causal, window)

@@ -10,6 +10,8 @@ from the spec we derive, without duplication:
 """
 from __future__ import annotations
 
+import math
+import zlib
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple
 
@@ -41,13 +43,16 @@ def map_spec(fn, spec: PyTree) -> PyTree:
 
 
 def init_from_spec(spec: PyTree, key: jax.Array, dtype: jnp.dtype) -> PyTree:
-    """Deterministic init: each leaf's key is fold_in(key, hash(path))."""
+    """Deterministic init: each leaf's key is fold_in(key, crc32(path)),
+    the same in every process (``hash`` of a string is salted per
+    process)."""
     leaves_with_path = jax.tree_util.tree_flatten_with_path(spec, is_leaf=_is_spec)
     flat, treedef = leaves_with_path
 
     def init_one(path, p: ParamSpec):
         pathstr = jax.tree_util.keystr(path)
-        k = jax.random.fold_in(key, np.uint32(hash(pathstr) & 0x7FFFFFFF))
+        k = jax.random.fold_in(key, np.uint32(zlib.crc32(pathstr.encode())
+                                              & 0x7FFFFFFF))
         if p.init == "zeros":
             return jnp.zeros(p.shape, dtype)
         if p.init == "ones":
@@ -121,17 +126,53 @@ def activation(name: str):
 # ---------------------------------------------------------------------------
 
 
-def rope_freqs(head_dim: int, theta: float) -> jnp.ndarray:
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+def rope_freqs(head_dim: int, theta: float, scaling=None) -> jnp.ndarray:
+    """Inverse frequencies of rotate-half rope; with ``scaling`` (a
+    ``YarnScaling``) YaRN's blend of the base frequencies and those
+    divided by ``factor``, ramped between the dimensions that turn
+    ``beta_fast`` and ``beta_slow`` times over the original context."""
+    if scaling is None:
+        return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                                / head_dim))
+    base = 1.0 / (theta ** (np.arange(0, head_dim, 2, dtype=np.float64) / head_dim))
+
+    def dim_of(rotations):
+        return (head_dim * math.log(scaling.original_max_position
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    lo = max(math.floor(dim_of(scaling.beta_fast)), 0)
+    hi = min(math.ceil(dim_of(scaling.beta_slow)), head_dim - 1)
+    if lo == hi:
+        hi += 0.001
+    ramp = np.clip((np.arange(head_dim // 2) - lo) / (hi - lo), 0.0, 1.0)
+    keep = 1.0 - ramp                      # 1: base frequency, 0: scaled
+    inv = base / scaling.factor * (1.0 - keep) + base * keep
+    return jnp.asarray(inv, jnp.float32)
 
 
-def apply_rope(x, positions, theta: float):
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_cos_scale(scaling) -> float:
+    """YaRN's multiplier on cos and sin (1 without scaling)."""
+    if scaling is None:
+        return 1.0
+    return (yarn_mscale(scaling.factor, scaling.mscale)
+            / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+
+
+def apply_rope(x, positions, theta: float, scaling=None):
     """x: (..., S, H, D); positions: broadcastable to (..., S)."""
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)                           # (D/2,)
+    freqs = rope_freqs(d, theta, scaling)                  # (D/2,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, D/2)
     angles = angles[..., None, :]                          # (..., S, 1, D/2)
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if scaling is not None:
+        m = rope_cos_scale(scaling)
+        cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import LayerDef, ModelConfig
-from repro.models import attention, common, mlp, modes, moe, ssm, xlstm
+from repro.models import attention, common, mla, mlp, modes, moe, ssm, xlstm
 from repro.models.common import ParamSpec
 from repro.sharding.constraints import constrain
 
@@ -34,6 +34,16 @@ PyTree = Any
 # ---------------------------------------------------------------------------
 
 
+def _ffn_spec(cfg: ModelConfig, ld: LayerDef) -> Dict:
+    D = cfg.d_model
+    if cfg.is_moe_layer(ld):
+        return {"ln2": common.norm_spec(cfg, D), "moe": moe.moe_spec(cfg)}
+    if cfg.d_ff > 0:
+        return {"ln2": common.norm_spec(cfg, D),
+                "mlp": mlp.mlp_spec(cfg, cfg.d_ff, cfg.mlp_gated)}
+    return {}
+
+
 def block_spec(cfg: ModelConfig, ld: LayerDef, decoder: bool) -> Dict:
     D = cfg.d_model
     if ld.kind == "attn":
@@ -41,13 +51,11 @@ def block_spec(cfg: ModelConfig, ld: LayerDef, decoder: bool) -> Dict:
         if decoder and cfg.cross_attention:
             spec["ln_x"] = common.norm_spec(cfg, D)
             spec["xattn"] = attention.attention_spec(cfg)
-        if cfg.moe is not None:
-            spec["ln2"] = common.norm_spec(cfg, D)
-            spec["moe"] = moe.moe_spec(cfg)
-        elif cfg.d_ff > 0:
-            spec["ln2"] = common.norm_spec(cfg, D)
-            spec["mlp"] = mlp.mlp_spec(cfg, cfg.d_ff, cfg.mlp_gated)
+        spec.update(_ffn_spec(cfg, ld))
         return spec
+    if ld.kind == "mla":
+        return {"ln1": common.norm_spec(cfg, D), "attn": mla.mla_spec(cfg),
+                **_ffn_spec(cfg, ld)}
     if ld.kind == "mamba2":
         return {"ln1": common.norm_spec(cfg, D), "mamba": ssm.mamba2_spec(cfg)}
     if ld.kind == "mlstm":
@@ -65,6 +73,9 @@ def model_spec(cfg: ModelConfig) -> Dict:
     }
     if not cfg.tie_embeddings:
         spec["lm_head"] = ParamSpec((D, V), ("embed", "vocab"))
+    if cfg.prefix:
+        spec["prefix"] = common.stack_spec(
+            block_spec(cfg, cfg.prefix[0], decoder=True), len(cfg.prefix))
     # pattern positions, each stacked over repeats
     spec["pattern"] = {
         str(i): common.stack_spec(block_spec(cfg, ld, decoder=True), cfg.repeats)
@@ -107,6 +118,20 @@ def logical_axes(cfg: ModelConfig) -> PyTree:
 # ---------------------------------------------------------------------------
 
 
+def _ffn(cfg: ModelConfig, p, h):
+    """Feed-forward sub-block with its residual: (h, aux, rows), ``rows``
+    the (n_held,) count of rows routed to held experts (None for a dense
+    feed-forward)."""
+    aux = jnp.zeros((), jnp.float32)
+    if "moe" in p:
+        y, aux, rows = moe.moe_apply(cfg, p["moe"], common.apply_norm(cfg, p["ln2"], h))
+        return h + y, aux, rows
+    if "mlp" in p:
+        h = h + mlp.mlp_forward(cfg, p["mlp"], common.apply_norm(cfg, p["ln2"], h),
+                                cfg.mlp_gated)
+    return h, aux, None
+
+
 def _block_forward(cfg: ModelConfig, ld: LayerDef, p, h, ctx) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Residual block. ctx: dict(positions, mrope_pos, enc_kv_fn, causal)."""
     aux = jnp.zeros((), jnp.float32)
@@ -119,12 +144,11 @@ def _block_forward(cfg: ModelConfig, ld: LayerDef, p, h, ctx) -> Tuple[jnp.ndarr
             xn = common.apply_norm(cfg, p["ln_x"], h)
             ek, ev = attention.encode_kv(cfg, p["xattn"], ctx["enc_out"])
             h = h + attention.cross_attn_forward(cfg, p["xattn"], xn, ek, ev)
-        if cfg.moe is not None:
-            y, aux = moe.moe_forward(cfg, p["moe"], common.apply_norm(cfg, p["ln2"], h))
-            h = h + y
-        elif cfg.d_ff > 0:
-            h = h + mlp.mlp_forward(cfg, p["mlp"], common.apply_norm(cfg, p["ln2"], h),
-                                    cfg.mlp_gated)
+        h, aux, _ = _ffn(cfg, p, h)
+    elif ld.kind == "mla":
+        h = h + mla.mla_forward(cfg, p["attn"], common.apply_norm(cfg, p["ln1"], h),
+                                positions=ctx.get("positions"))
+        h, aux, _ = _ffn(cfg, p, h)
     elif ld.kind == "mamba2":
         h = h + ssm.mamba2_forward(cfg, p["mamba"], common.apply_norm(cfg, p["ln1"], h))
     elif ld.kind == "mlstm":
@@ -221,7 +245,12 @@ def encode(cfg: ModelConfig, params, enc_embeds):
 
 def forward(cfg: ModelConfig, params, batch) -> Tuple[jnp.ndarray, jnp.ndarray]:
     h, ctx = _assemble_inputs(cfg, params, batch)
+    if cfg.prefix:
+        h, aux0 = _scan_blocks(cfg, (cfg.prefix[0],), {"0": params["prefix"]},
+                               h, ctx)
     h, aux = _scan_blocks(cfg, cfg.pattern, params["pattern"], h, ctx)
+    if cfg.prefix:
+        aux = aux + aux0
     if cfg.suffix:
         h, aux2 = _scan_blocks(cfg, (cfg.suffix[0],), {"0": params["suffix"]},
                                h, ctx)
@@ -233,6 +262,60 @@ def forward(cfg: ModelConfig, params, batch) -> Tuple[jnp.ndarray, jnp.ndarray]:
 # ---------------------------------------------------------------------------
 # KV / state caches
 # ---------------------------------------------------------------------------
+#
+# The cache holds one entry per stack ("prefix", "pattern" by position,
+# "suffix"), stacked over that stack's layers, and, in a model with
+# experts, "moe_rows": (expert layers, n_held) int32 rows routed to each
+# held expert, accumulated by prefill and every decode step.
+
+
+def _stacks(cfg: ModelConfig):
+    """(cache/param key, layer defs of one scan step) in layer order."""
+    out = [("prefix", (cfg.prefix[0],))] if cfg.prefix else []
+    out.append(("pattern", cfg.pattern))
+    if cfg.suffix:
+        out.append(("suffix", (cfg.suffix[0],)))
+    return out
+
+
+def _by_position(name, tree):
+    """A stack's params or cache keyed by pattern position (prefix and
+    suffix have one)."""
+    return tree if name == "pattern" else {"0": tree}
+
+
+def _scan_params(cfg: ModelConfig, name, defs, stacked):
+    """The stack's params to scan, by position, and the routed experts'
+    weights of its expert layers kept whole: on one chip the grouped matmul
+    reads the layer's experts from the stacked weights by index, so the
+    scan makes no per-layer copy of them."""
+    from repro.sharding.constraints import _current_mesh
+
+    tree = _by_position(name, stacked)
+    if cfg.moe is None or _current_mesh() is not None:
+        return tree, {}
+    xs, whole = {}, {}
+    for i, ld in enumerate(defs):
+        p = tree[str(i)]
+        if cfg.is_moe_layer(ld):
+            whole[str(i)] = {k: p["moe"][k] for k in moe.EXPERT_WEIGHTS}
+            p = dict(p, moe={k: v for k, v in p["moe"].items()
+                             if k not in moe.EXPERT_WEIGHTS})
+        xs[str(i)] = p
+    return xs, whole
+
+
+def _layer_index(stacked, whole):
+    """The layer index scanned beside the params where a layer's experts
+    are kept whole (None, no scan input, elsewhere)."""
+    return jnp.arange(jax.tree.leaves(stacked)[0].shape[0]) if whole else None
+
+
+def _layer_params(p, whole, layer):
+    """Layer ``layer``'s params at one position of a scan step."""
+    if whole is None:
+        return p
+    return dict(p, moe=dict(p["moe"], layer=layer, **whole))
 
 
 def _block_cache(cfg: ModelConfig, ld: LayerDef, batch: int, max_len: int, dtype):
@@ -244,6 +327,8 @@ def _block_cache(cfg: ModelConfig, ld: LayerDef, batch: int, max_len: int, dtype
             c["xk"] = jnp.zeros((batch, cfg.encoder_seq, K, hd), dtype)
             c["xv"] = jnp.zeros((batch, cfg.encoder_seq, K, hd), dtype)
         return c
+    if ld.kind == "mla":
+        return {"latent": jnp.zeros((batch, max_len, cfg.mla.latent_dim), dtype)}
     if ld.kind == "mamba2":
         return ssm.mamba2_init_cache(cfg, batch, dtype)
     if ld.kind == "mlstm":
@@ -257,15 +342,24 @@ def _stack_cache(tree, n):
     return jax.tree.map(lambda x: jnp.broadcast_to(x[None], (n,) + x.shape), tree)
 
 
+def moe_layers(cfg: ModelConfig) -> int:
+    return sum(cfg.is_moe_layer(ld) for ld in cfg.layer_defs)
+
+
 def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> PyTree:
     dtype = jnp.dtype(cfg.dtype)
     cache: Dict = {"pattern": {
         str(i): _stack_cache(_block_cache(cfg, ld, batch, max_len, dtype), cfg.repeats)
         for i, ld in enumerate(cfg.pattern)
     }}
+    if cfg.prefix:
+        cache["prefix"] = _stack_cache(
+            _block_cache(cfg, cfg.prefix[0], batch, max_len, dtype), len(cfg.prefix))
     if cfg.suffix:
         cache["suffix"] = _stack_cache(
             _block_cache(cfg, cfg.suffix[0], batch, max_len, dtype), len(cfg.suffix))
+    if cfg.moe is not None:
+        cache["moe_rows"] = jnp.zeros((moe_layers(cfg), cfg.moe.n_held), jnp.int32)
     return cache
 
 
@@ -273,12 +367,27 @@ def abstract_cache(cfg: ModelConfig, batch: int, max_len: int) -> PyTree:
     return jax.eval_shape(lambda: init_cache(cfg, batch, max_len))
 
 
+def _gather_rows(cfg: ModelConfig, rows_by_stack):
+    """Concatenate the per-stack (layers, n_held) counts in layer order
+    (prefix, pattern positions, suffix)."""
+    parts = [rows_by_stack[name][i] for name, defs in _stacks(cfg)
+             for i, ld in enumerate(defs) if cfg.is_moe_layer(ld)]
+    return jnp.concatenate(parts, axis=0)
+
+
 # ---------------------------------------------------------------------------
 # Prefill
 # ---------------------------------------------------------------------------
 
+# Prompt tokens one prefill pass takes at once: a larger batch is prefilled
+# in row chunks inside the same jitted step, each writing its rows of the
+# cache, so its activations stay within what the chip holds beside the
+# weights and the whole cache.
+PREFILL_ROW_TOKENS = 16384
+
 
 def _block_prefill(cfg, ld, p, h, ctx, max_len):
+    """Returns (h, cache entry, held-expert rows or None)."""
     if ld.kind == "attn":
         y, (ck, cv) = attention.attn_prefill(
             cfg, p["attn"], common.apply_norm(cfg, p["ln1"], h), max_len,
@@ -291,47 +400,84 @@ def _block_prefill(cfg, ld, p, h, ctx, max_len):
             ek, ev = attention.encode_kv(cfg, p["xattn"], ctx["enc_out"])
             h = h + attention.cross_attn_forward(cfg, p["xattn"], xn, ek, ev)
             c["xk"], c["xv"] = ek, ev
-        if cfg.moe is not None:
-            y, _ = moe.moe_forward(cfg, p["moe"], common.apply_norm(cfg, p["ln2"], h))
-            h = h + y
-        elif cfg.d_ff > 0:
-            h = h + mlp.mlp_forward(cfg, p["mlp"],
-                                    common.apply_norm(cfg, p["ln2"], h), cfg.mlp_gated)
-        return h, c
+        h, _, rows = _ffn(cfg, p, h)
+        return h, c, rows
+    if ld.kind == "mla":
+        y, lat = mla.mla_prefill(cfg, p["attn"], common.apply_norm(cfg, p["ln1"], h),
+                                 max_len, positions=ctx.get("positions"))
+        h, _, rows = _ffn(cfg, p, h + y)
+        return h, {"latent": lat}, rows
     if ld.kind == "mamba2":
         y, c = ssm.mamba2_prefill(cfg, p["mamba"], common.apply_norm(cfg, p["ln1"], h))
-        return h + y, c
+        return h + y, c, None
     if ld.kind == "mlstm":
         y, c = xlstm.mlstm_forward(cfg, p["mlstm"],
                                    common.apply_norm(cfg, p["ln1"], h), return_state=True)
-        return h + y, c
+        return h + y, c, None
     if ld.kind == "slstm":
         y, c = xlstm.slstm_forward(cfg, p["slstm"],
                                    common.apply_norm(cfg, p["ln1"], h), return_state=True)
-        return h + y, c
+        return h + y, c, None
     raise ValueError(ld.kind)
+
+
+def _prefill_all(cfg: ModelConfig, params, batch, max_len: int):
+    h, ctx = _assemble_inputs(cfg, params, batch)
+    cache, rows = {}, {}
+    for name, defs in _stacks(cfg):
+        xs, whole = _scan_params(cfg, name, defs, params[name])
+
+        def body(hh, xs, defs=defs, whole=whole, name=name):
+            p, layer = xs
+            caches, counts = {}, {}
+            for i, ld in enumerate(defs):
+                pi = _layer_params(p[str(i)], whole.get(str(i)), layer)
+                hh, c, r = _block_prefill(cfg, ld, pi, hh, ctx, max_len)
+                caches[str(i)] = c
+                if r is not None:
+                    counts[i] = r
+            return hh, (caches if name == "pattern" else caches["0"], counts)
+
+        h, (cache[name], rows[name]) = modes.scan(
+            body, h, (xs, _layer_index(params[name], whole)))
+    if cfg.moe is not None:
+        cache["moe_rows"] = _gather_rows(cfg, rows)
+    h = common.apply_norm(cfg, params["final_norm"], h)
+    return cache, h[:, -1]
+
+
+def _prefill_rows(cfg: ModelConfig, batch) -> int:
+    """Rows per prefill pass: the whole batch, or the largest divisor of
+    it whose prompts hold at most ``PREFILL_ROW_TOKENS`` tokens (token-only
+    batches)."""
+    B, S = batch["tokens"].shape
+    if B * S <= PREFILL_ROW_TOKENS or set(batch) != {"tokens"}:
+        return B
+    return max(r for r in range(1, B + 1)
+               if B % r == 0 and r * S <= max(PREFILL_ROW_TOKENS, S))
 
 
 def prefill(cfg: ModelConfig, params, batch, max_len: int):
     """Run the prompt, build the cache. Returns (cache, last_hidden)."""
-    h, ctx = _assemble_inputs(cfg, params, batch)
+    tokens = batch["tokens"]
+    B = tokens.shape[0]
+    rows = _prefill_rows(cfg, batch)
+    if rows == B:
+        return _prefill_all(cfg, params, batch, max_len)
 
-    def body(hh, xs):
-        caches = {}
-        for i, ld in enumerate(cfg.pattern):
-            hh, c = _block_prefill(cfg, ld, xs[str(i)], hh, ctx, max_len)
-            caches[str(i)] = c
-        return hh, caches
+    def body(i, carry):
+        cache, last = carry
+        sub = jax.lax.dynamic_slice_in_dim(tokens, i * rows, rows, axis=0)
+        c, h = _prefill_all(cfg, params, {"tokens": sub}, max_len)
+        cache = {k: (cache[k] + c[k] if k == "moe_rows" else jax.tree.map(
+            lambda full, part: jax.lax.dynamic_update_slice_in_dim(
+                full, part, i * rows, axis=1), cache[k], c[k]))
+            for k in cache}
+        return cache, jax.lax.dynamic_update_slice_in_dim(last, h, i * rows, axis=0)
 
-    h, pattern_cache = modes.scan(body, h, params["pattern"])
-    cache = {"pattern": pattern_cache}
-    if cfg.suffix:
-        def sbody(hh, xs):
-            hh, c = _block_prefill(cfg, cfg.suffix[0], xs, hh, ctx, max_len)
-            return hh, c
-        h, cache["suffix"] = modes.scan(sbody, h, params["suffix"])
-    h = common.apply_norm(cfg, params["final_norm"], h)
-    return cache, h[:, -1]
+    last = jnp.zeros((B, cfg.d_model), jnp.dtype(cfg.dtype))
+    return jax.lax.fori_loop(0, B // rows, body,
+                             (init_cache(cfg, B, max_len), last))
 
 
 # ---------------------------------------------------------------------------
@@ -340,6 +486,7 @@ def prefill(cfg: ModelConfig, params, batch, max_len: int):
 
 
 def _block_decode(cfg, ld, p, c, h, pos, ctx):
+    """Returns (h, cache entry, held-expert rows or None)."""
     if ld.kind == "attn":
         xn = common.apply_norm(cfg, p["ln1"], h)
         mrope = None
@@ -354,22 +501,22 @@ def _block_decode(cfg, ld, p, c, h, pos, ctx):
         if "xattn" in p and "xk" in c:
             xn = common.apply_norm(cfg, p["ln_x"], h)
             h = h + attention.cross_attn_decode(cfg, p["xattn"], xn, (c["xk"], c["xv"]))
-        if cfg.moe is not None:
-            y, _ = moe.moe_forward(cfg, p["moe"], common.apply_norm(cfg, p["ln2"], h))
-            h = h + y
-        elif cfg.d_ff > 0:
-            h = h + mlp.mlp_forward(cfg, p["mlp"],
-                                    common.apply_norm(cfg, p["ln2"], h), cfg.mlp_gated)
-        return h, c
+        h, _, rows = _ffn(cfg, p, h)
+        return h, c, rows
+    if ld.kind == "mla":
+        y, lat = mla.mla_decode(cfg, p["attn"], common.apply_norm(cfg, p["ln1"], h),
+                                c["latent"], pos)
+        h, _, rows = _ffn(cfg, p, h + y)
+        return h, {"latent": lat}, rows
     if ld.kind == "mamba2":
         y, c = ssm.mamba2_decode(cfg, p["mamba"], common.apply_norm(cfg, p["ln1"], h), c)
-        return h + y, c
+        return h + y, c, None
     if ld.kind == "mlstm":
         y, c = xlstm.mlstm_decode(cfg, p["mlstm"], common.apply_norm(cfg, p["ln1"], h), c)
-        return h + y, c
+        return h + y, c, None
     if ld.kind == "slstm":
         y, c = xlstm.slstm_decode(cfg, p["slstm"], common.apply_norm(cfg, p["ln1"], h), c)
-        return h + y, c
+        return h + y, c, None
     raise ValueError(ld.kind)
 
 
@@ -380,23 +527,26 @@ def decode_step(cfg: ModelConfig, params, cache, token, pos):
         h = h + common.sinusoidal_pos_emb(
             jnp.full((h.shape[0], 1), pos), cfg.d_model).astype(h.dtype)
     ctx: Dict = {}
+    new_cache, rows = {}, {}
+    for name, defs in _stacks(cfg):
+        xs, whole = _scan_params(cfg, name, defs, params[name])
 
-    def body(hh, xs):
-        p, c = xs
-        new_c = {}
-        for i, ld in enumerate(cfg.pattern):
-            hh, nc = _block_decode(cfg, ld, p[str(i)], c[str(i)], hh, pos, ctx)
-            new_c[str(i)] = nc
-        return hh, new_c
+        def body(hh, xs, defs=defs, whole=whole, name=name):
+            p, c, layer = xs
+            c = _by_position(name, c)
+            new_c, counts = {}, {}
+            for i, ld in enumerate(defs):
+                pi = _layer_params(p[str(i)], whole.get(str(i)), layer)
+                hh, nc, r = _block_decode(cfg, ld, pi, c[str(i)], hh, pos, ctx)
+                new_c[str(i)] = nc
+                if r is not None:
+                    counts[i] = r
+            return hh, (new_c if name == "pattern" else new_c["0"], counts)
 
-    h, new_pattern = modes.scan(body, h, (params["pattern"], cache["pattern"]))
-    new_cache = {"pattern": new_pattern}
-    if cfg.suffix:
-        def sbody(hh, xs):
-            p, c = xs
-            hh, nc = _block_decode(cfg, cfg.suffix[0], p, c, hh, pos, ctx)
-            return hh, nc
-        h, new_cache["suffix"] = modes.scan(sbody, (h), (params["suffix"], cache["suffix"]))
+        h, (new_cache[name], rows[name]) = modes.scan(
+            body, h, (xs, cache[name], _layer_index(params[name], whole)))
+    if cfg.moe is not None:
+        new_cache["moe_rows"] = cache["moe_rows"] + _gather_rows(cfg, rows)
     h = common.apply_norm(cfg, params["final_norm"], h)
     logits = unembed(cfg, params, h[:, 0])
     return logits, new_cache

@@ -55,7 +55,14 @@ class ServingEngine:
     ``obs`` (a ``repro.obs.Observability``) with a profiler gets the
     ``serve.*`` phases of :meth:`run_batch`: route, prefill, and per token
     decode dispatch, sampling, the host's wait for the token, and billing.
-    They reach a profiler trace as ``carbonedge.serve.*`` either way."""
+    They reach a profiler trace as ``carbonedge.serve.*`` either way.
+
+    For a model with experts the cache carries, per expert layer, the rows
+    routed to each expert held here; with ``obs`` metrics on, the engine
+    reads it once per batch after the last token (``serve.moe_counts``)
+    into the counter ``serve.moe_rows_held`` (all rows) and the gauge
+    ``serve.moe_rows_max`` (the batch's busiest held expert in one
+    layer)."""
 
     def __init__(self, cfg: ModelConfig, params, router: GreenRouter,
                  max_len: int = 256, batch_size: int = 4, obs=None):
@@ -152,6 +159,17 @@ class ServingEngine:
                     hour=now_hour)
             with span(prof, "serve.sample"):
                 tok = steps.greedy_sample(logits)[:, None]
+        if "moe_rows" in cache and self.obs is not None \
+                and self.obs.metrics is not None:
+            with span(prof, "serve.moe_counts"):
+                rows = np.asarray(cache["moe_rows"])
+                m = self.obs.metrics
+                m.counter("serve.moe_rows_held",
+                          "rows routed to experts held here").inc(
+                              float(rows.sum()))
+                m.gauge("serve.moe_rows_max",
+                        "rows of the batch's busiest held expert").set(
+                            float(rows.max()))
         comps = []
         for i, r in enumerate(batch):
             # a zero-token request's service ends at prefill

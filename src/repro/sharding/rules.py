@@ -186,6 +186,9 @@ def cache_pspecs(cfg, batch: int, mesh: Mesh):
             return P(None, bspec, _div(leaf.shape[2]), None, None)
         if name == "C":
             return P(None, bspec, None, None, None)
+        if name == "moe_rows":
+            # (expert layers, n_held) counts, no batch axis
+            return P()
         # n/m/c/h and other small states
         return P(*([None, bspec] + [None] * (nd - 2)))
 

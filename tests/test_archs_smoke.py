@@ -80,6 +80,7 @@ def test_full_config_matches_assignment(arch):
         "qwen2-moe-a2.7b": (24, 2048, 16, 16, 1408, 151936),
         "qwen3-1.7b": (28, 2048, 16, 8, 6144, 151936),
         "qwen2-vl-2b": (28, 1536, 12, 2, 8960, 151936),
+        "deepseek-v2-lite": (27, 2048, 16, 16, 10944, 102400),
     }[arch]
     cfg = get_config(arch)
     assert (cfg.num_layers, cfg.d_model, cfg.num_heads, cfg.num_kv_heads,

@@ -7,6 +7,7 @@ is in the compiled program. The topology is described only inside a
 fixture (a process that loads the TPU library keeps it until it exits), so
 every pytest-xdist worker collects the same tests.
 """
+import functools
 import os
 
 import jax
@@ -16,6 +17,8 @@ import pytest
 
 from repro.kernels import decode_attention as dec
 from repro.kernels import flash_attention as fa
+from repro.kernels import mla_decode
+from repro.kernels import moe_gmm
 from repro.kernels import node_score as ns
 
 
@@ -110,6 +113,48 @@ def test_attention_kernels_compile_at_qwen3_widths(one_chip, kernel):
         text = _compiled_text(dec.decode_attention, sds(B, H, hd),
                               sds(B, K, S, hd), sds(B, K, S, hd),
                               sds(dtype=jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+# DeepSeek-V2-Lite widths: 16 heads of 192 (q, k) and 128 (v) in prefill;
+# a 512 + 64 latent per position in decode; experts of 2048 x 1408, 16
+# held, rows in the tile layouts of decode (64 x 6 rows) and of one
+# prefill pass (16 x 1024 x 6 rows).
+def test_flash_attention_compiles_at_mla_widths(one_chip):
+    B, H, S = 2, 16, 1024
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    text = _compiled_text(fa.flash_attention, sds(B, H, S, 192),
+                          sds(B, H, S, 192), sds(B, H, S, 128))
+    assert "tpu_custom_call" in text
+
+
+def test_mla_decode_attention_compiles(one_chip):
+    B, H, S = 64, 16, 1280
+    fn = functools.partial(mla_decode.mla_decode_attention,
+                           scale=192 ** -0.5, rank=512)
+    text = _compiled_text(
+        fn, jax.ShapeDtypeStruct((B, H, 576), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((B, S, 576), jnp.bfloat16, sharding=one_chip),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("rows,tm", [(64 * 6, 16), (16 * 1024 * 6, 512)])
+def test_moe_gmm_compiles(one_chip, rows, tm):
+    E, D, F = 16, 2048, 1408
+    M = -(-(rows + E * (tm - 1)) // tm) * tm
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fn = functools.partial(moe_gmm.moe_gmm, tm=tm)
+    text = _compiled_text(fn, sds((M, D)), sds((26, E, D, F)),
+                          sds((26, E, D, F)), sds((26, E, F, D)),
+                          sds((M // tm,), jnp.int32), sds((1,), jnp.int32),
+                          sds((1,), jnp.int32))
     assert "tpu_custom_call" in text
 
 
