@@ -42,9 +42,10 @@ def decode_attention(q, k, v, pos, *, window: Optional[int] = None,
                                  softcap=softcap, interpret=_interpret())
 
 
-def mla_decode_attention(q, cache, pos, *, scale: float, rank: int):
-    return _mla.mla_decode_attention(q, cache, pos, scale=scale, rank=rank,
-                                     interpret=_interpret())
+def mla_decode_attention(q, cache, pos, layer=None, *, scale: float,
+                         rank: int):
+    return _mla.mla_decode_attention(q, cache, pos, layer, scale=scale,
+                                     rank=rank, interpret=_interpret())
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(8,))

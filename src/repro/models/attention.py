@@ -250,14 +250,16 @@ def attn_prefill(cfg: ModelConfig, p, x, cache_len: int, *, causal=True,
 
 
 def attn_decode(cfg: ModelConfig, p, x, cache: Tuple, pos, *, window=None,
-                mrope_pos=None):
-    """x: (B,1,D); cache (ck, cv): (B,Smax,K,hd); pos: scalar int32.
+                mrope_pos=None, layer=None):
+    """x: (B,1,D); cache (ck, cv): (B,Smax,K,hd), or with ``layer`` (a
+    scalar int32) the (L,B,Smax,K,hd) stacks over layers, written at that
+    layer in place and read there; pos: scalar int32.
 
     Returns (y, new_cache). The attention over the cache is the jnp oracle
     for kernels/decode_attention.
     """
     ck, cv = cache
-    B, Smax, K, hd = ck.shape
+    B, Smax, K, hd = ck.shape[-4:]
     q = _project_q(cfg, p, x)
     k, v = _project_kv(cfg, p, x)
     pos_b = jnp.full((B, 1), pos)
@@ -273,9 +275,19 @@ def attn_decode(cfg: ModelConfig, p, x, cache: Tuple, pos, *, window=None,
         sel = (jnp.arange(Smax) == pos)[None, :, None, None]
         ck = jnp.where(sel, k.astype(ck.dtype), ck)
         cv = jnp.where(sel, v.astype(cv.dtype), cv)
-    else:
+    elif layer is None:
         ck = jax.lax.dynamic_update_slice_in_dim(ck, k, pos, axis=1)
         cv = jax.lax.dynamic_update_slice_in_dim(cv, v, pos, axis=1)
+    else:
+        at = (layer, 0, pos, 0, 0)
+        ck = jax.lax.dynamic_update_slice(ck, k[None].astype(ck.dtype), at)
+        cv = jax.lax.dynamic_update_slice(cv, v[None].astype(cv.dtype), at)
+    # this layer's (B,Smax,K,hd) keys and values
+    if layer is None:
+        lk, lv = ck, cv
+    else:
+        lk, lv = (jax.lax.dynamic_index_in_dim(c, layer, 0, keepdims=False)
+                  for c in (ck, cv))
     ki = jnp.arange(Smax)
     valid = ki <= pos
     if window is not None:
@@ -293,16 +305,25 @@ def attn_decode(cfg: ModelConfig, p, x, cache: Tuple, pos, *, window=None,
     if (_ops.use_pallas() and _current_mesh() is None
             and Smax % _dec.BLOCK_K == 0
             and H % K == 0 and not cfg.mrope_sections):
+        if layer is not None:
+            # The stack stays in its row-major layout: the kernel's
+            # (B,K,S,hd) operand is staged one layer at a time, where the
+            # compiler would otherwise relayout the whole stack on the way
+            # in and out of the step.
+            from jax.experimental.layout import Layout, with_layout_constraint
+
+            lk, lv = (with_layout_constraint(x, Layout(tuple(range(x.ndim))))
+                      for x in (lk, lv))
         out = _ops.decode_attention(
-            q[:, 0], ck.transpose(0, 2, 1, 3), cv.transpose(0, 2, 1, 3), pos,
+            q[:, 0], lk.transpose(0, 2, 1, 3), lv.transpose(0, 2, 1, 3), pos,
             window=window, softcap=cfg.attn_logit_softcap)[:, None]
         y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
         return y, (ck, cv)
 
-    ke, ve = ck, cv
+    ke, ve = lk, lv
     if K != H:
-        ke = jnp.repeat(ck, H // K, axis=2)
-        ve = jnp.repeat(cv, H // K, axis=2)
+        ke = jnp.repeat(lk, H // K, axis=2)
+        ve = jnp.repeat(lv, H // K, axis=2)
     msize = mesh_axis_size("model")
     mesh = _current_mesh()
     seq_layout = (mesh is not None

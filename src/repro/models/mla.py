@@ -106,26 +106,35 @@ def mla_prefill(cfg: ModelConfig, p, x, cache_len: int, positions=None):
     return y, cache.at[:, :S].set(latent)
 
 
-def mla_decode(cfg: ModelConfig, p, x, cache, pos):
-    """x: (B,1,D); cache: (B,Smax,rank+rope); pos: scalar int32. Absorbed
-    attention over the latent cache; returns (y, new cache)."""
+def mla_decode(cfg: ModelConfig, p, x, cache, pos, layer=None):
+    """x: (B,1,D); cache: (B,Smax,rank+rope), or with ``layer`` (a scalar
+    int32) the (L,B,Smax,rank+rope) stack over layers, read and written at
+    that layer in place; pos: scalar int32. Absorbed attention over the
+    latent cache; returns (y, new cache)."""
     from repro.kernels import ops
     from repro.kernels.decode_attention import BLOCK_K
     from repro.sharding.constraints import _current_mesh
 
     m = cfg.mla
-    B, Smax, _ = cache.shape
+    B, Smax, _ = cache.shape[-3:]
     nope, r = m.qk_nope_head_dim, m.kv_lora_rank
     q_nope, q_pe, latent = _project(cfg, p, x, jnp.full((B, 1), pos))
-    cache = jax.lax.dynamic_update_slice_in_dim(
-        cache, latent.astype(cache.dtype), pos, axis=1)
+    if layer is None:
+        cache = jax.lax.dynamic_update_slice_in_dim(
+            cache, latent.astype(cache.dtype), pos, axis=1)
+    else:
+        cache = jax.lax.dynamic_update_slice(
+            cache, latent[None].astype(cache.dtype), (layer, 0, pos, 0))
     q_lat = jnp.einsum("bhk,rhk->bhr", q_nope[:, 0], p["wkv_b"][..., :nope],
                        preferred_element_type=jnp.float32)
     q = jnp.concatenate([q_lat, q_pe[:, 0].astype(jnp.float32)], axis=-1)
     scale = softmax_scale(cfg)
     if ops.use_pallas() and _current_mesh() is None and Smax % BLOCK_K == 0:
-        o_lat = ops.mla_decode_attention(q, cache, pos, scale=scale, rank=r)
+        o_lat = ops.mla_decode_attention(q, cache, pos, layer, scale=scale,
+                                         rank=r)
     else:
-        o_lat = ops.mla_decode_attention_ref(q, cache, pos, scale=scale, rank=r)
+        lat = cache if layer is None else \
+            jax.lax.dynamic_index_in_dim(cache, layer, 0, keepdims=False)
+        o_lat = ops.mla_decode_attention_ref(q, lat, pos, scale=scale, rank=r)
     o = jnp.einsum("bhr,rhk->bhk", o_lat, p["wkv_b"][..., nope:])
     return jnp.einsum("bhk,hkd->bd", o, p["wo"])[:, None], cache

@@ -13,6 +13,10 @@ Public API:
     init_cache / abstract_cache
     prefill(cfg, params, batch, max_len)  -> (cache, last_hidden)
     decode_step(cfg, params, cache, token, pos) -> (logits, cache)
+
+Decode on one device carries each stack's attention caches (K/V, MLA
+latent) through its layer scan and writes one position a layer in place;
+the serving engine donates the cache, so a token never copies it whole.
 """
 from __future__ import annotations
 
@@ -375,6 +379,24 @@ def _gather_rows(cfg: ModelConfig, rows_by_stack):
     return jnp.concatenate(parts, axis=0)
 
 
+# The cache entries of each layer kind that one decode token writes one
+# position of. On one device the decode step carries them through the layer
+# scan, stacked over the stack's layers, and writes that position in place;
+# every other entry (recurrent state, cross-attention K/V) is small and
+# rewritten whole, so it is scanned per layer.
+CARRIED = {"attn": ("k", "v"), "mla": ("latent",)}
+
+
+def cache_in_place() -> bool:
+    """Whether ``decode_step`` carries the attention caches and updates them
+    in place: on one device. Under a mesh (the dry-run and GSPMD paths) the
+    sequence-sharded cache takes the mask update, so each layer's cache is
+    scanned in and out whole."""
+    from repro.sharding.constraints import _current_mesh
+
+    return _current_mesh() is None
+
+
 # ---------------------------------------------------------------------------
 # Prefill
 # ---------------------------------------------------------------------------
@@ -485,8 +507,10 @@ def prefill(cfg: ModelConfig, params, batch, max_len: int):
 # ---------------------------------------------------------------------------
 
 
-def _block_decode(cfg, ld, p, c, h, pos, ctx):
-    """Returns (h, cache entry, held-expert rows or None)."""
+def _block_decode(cfg, ld, p, c, h, pos, ctx, layer=None):
+    """Returns (h, cache entry, held-expert rows or None). With ``layer``,
+    the entry's ``CARRIED`` arrays are the whole stack's, read and written
+    at that layer; the rest are the layer's own."""
     if ld.kind == "attn":
         xn = common.apply_norm(cfg, p["ln1"], h)
         mrope = None
@@ -495,7 +519,7 @@ def _block_decode(cfg, ld, p, c, h, pos, ctx):
             mrope = jnp.broadcast_to(jnp.asarray(pos)[None, None, None], (B, 3, 1))
         y, (ck, cv) = attention.attn_decode(
             cfg, p["attn"], xn, (c["k"], c["v"]), pos, window=ld.window,
-            mrope_pos=mrope)
+            mrope_pos=mrope, layer=layer)
         h = h + y
         c = dict(c, k=ck, v=cv)
         if "xattn" in p and "xk" in c:
@@ -505,7 +529,7 @@ def _block_decode(cfg, ld, p, c, h, pos, ctx):
         return h, c, rows
     if ld.kind == "mla":
         y, lat = mla.mla_decode(cfg, p["attn"], common.apply_norm(cfg, p["ln1"], h),
-                                c["latent"], pos)
+                                c["latent"], pos, layer)
         h, _, rows = _ffn(cfg, p, h + y)
         return h, {"latent": lat}, rows
     if ld.kind == "mamba2":
@@ -520,31 +544,83 @@ def _block_decode(cfg, ld, p, c, h, pos, ctx):
     raise ValueError(ld.kind)
 
 
+def _split_carried(defs, c):
+    """A stack's cache by position -> (``CARRIED`` entries, the rest)."""
+    carried, rest = {}, {}
+    for i, ld in enumerate(defs):
+        keys = CARRIED.get(ld.kind, ())
+        carried[str(i)] = {k: v for k, v in c[str(i)].items() if k in keys}
+        rest[str(i)] = {k: v for k, v in c[str(i)].items() if k not in keys}
+    return carried, rest
+
+
+def _decode_stack(cfg, name, defs, stacked, c, h, pos, ctx):
+    """One stack's layers for one token, each layer's cache scanned in and
+    out whole. Returns (h, the stack's new cache, held-expert rows)."""
+    xs, whole = _scan_params(cfg, name, defs, stacked)
+
+    def body(hh, xs):
+        p, c, layer = xs
+        c = _by_position(name, c)
+        new_c, counts = {}, {}
+        for i, ld in enumerate(defs):
+            pi = _layer_params(p[str(i)], whole.get(str(i)), layer)
+            hh, nc, r = _block_decode(cfg, ld, pi, c[str(i)], hh, pos, ctx)
+            new_c[str(i)] = nc
+            if r is not None:
+                counts[i] = r
+        return hh, (new_c if name == "pattern" else new_c["0"], counts)
+
+    h, (c, rows) = modes.scan(body, h, (xs, c, _layer_index(stacked, whole)))
+    return h, c, rows
+
+
+def _decode_stack_in_place(cfg, name, defs, stacked, c, h, pos, ctx):
+    """``_decode_stack`` with the ``CARRIED`` entries in the scan's carry:
+    each layer writes its one position into the stacked arrays, which are
+    never sliced out or rebuilt whole."""
+    xs, whole = _scan_params(cfg, name, defs, stacked)
+    carried, rest = _split_carried(defs, _by_position(name, c))
+    layers = jnp.arange(jax.tree.leaves(stacked)[0].shape[0])
+
+    def body(carry, xs):
+        hh, cc = carry
+        p, rc, layer = xs
+        entries, counts = {}, {}
+        for i, ld in enumerate(defs):
+            pi = _layer_params(p[str(i)], whole.get(str(i)), layer)
+            hh, entries[str(i)], r = _block_decode(
+                cfg, ld, pi, {**cc[str(i)], **rc[str(i)]}, hh, pos, ctx, layer)
+            if r is not None:
+                counts[i] = r
+        cc, rc = _split_carried(defs, entries)
+        return (hh, cc), (rc, counts)
+
+    (h, carried), (rest, rows) = modes.scan(body, (h, carried),
+                                            (xs, rest, layers))
+    c = {i: {**carried[i], **rest[i]} for i in carried}
+    return h, (c if name == "pattern" else c["0"]), rows
+
+
 def decode_step(cfg: ModelConfig, params, cache, token, pos):
-    """token: (B,1) int32; pos: scalar int32. Returns (logits (B,V), cache)."""
+    """token: (B,1) int32; pos: scalar int32. Returns (logits (B,V), cache).
+
+    On one device (``cache_in_place``) the attention caches (K/V, MLA
+    latent) ride in each stack's layer scan as its carry and each layer
+    writes its new position into them in place, so a token moves one
+    position a layer of cache and never the whole of it; the serving engine
+    donates the cache, so the step returns it in the same buffers. Under a
+    mesh each layer's cache is scanned in and out whole."""
     h = embed(cfg, params, token)
     if cfg.pos_emb == "sinusoidal":
         h = h + common.sinusoidal_pos_emb(
             jnp.full((h.shape[0], 1), pos), cfg.d_model).astype(h.dtype)
     ctx: Dict = {}
+    stack_fn = _decode_stack_in_place if cache_in_place() else _decode_stack
     new_cache, rows = {}, {}
     for name, defs in _stacks(cfg):
-        xs, whole = _scan_params(cfg, name, defs, params[name])
-
-        def body(hh, xs, defs=defs, whole=whole, name=name):
-            p, c, layer = xs
-            c = _by_position(name, c)
-            new_c, counts = {}, {}
-            for i, ld in enumerate(defs):
-                pi = _layer_params(p[str(i)], whole.get(str(i)), layer)
-                hh, nc, r = _block_decode(cfg, ld, pi, c[str(i)], hh, pos, ctx)
-                new_c[str(i)] = nc
-                if r is not None:
-                    counts[i] = r
-            return hh, (new_c if name == "pattern" else new_c["0"], counts)
-
-        h, (new_cache[name], rows[name]) = modes.scan(
-            body, h, (xs, cache[name], _layer_index(params[name], whole)))
+        h, new_cache[name], rows[name] = stack_fn(
+            cfg, name, defs, params[name], cache[name], h, pos, ctx)
     if cfg.moe is not None:
         new_cache["moe_rows"] = cache["moe_rows"] + _gather_rows(cfg, rows)
     h = common.apply_norm(cfg, params["final_norm"], h)

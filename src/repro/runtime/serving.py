@@ -20,6 +20,7 @@ from repro.configs.base import ModelConfig
 from repro.core import costmodel, energy
 from repro.core.router import GreenRouter
 from repro.kernels.decode_attention import BLOCK_K
+from repro.models import transformer
 from repro.obs.profiler import span
 from repro.runtime import steps
 
@@ -62,7 +63,15 @@ class ServingEngine:
     reads it once per batch after the last token (``serve.moe_counts``)
     into the counter ``serve.moe_rows_held`` (all rows) and the gauge
     ``serve.moe_rows_max`` (the batch's busiest held expert in one
-    layer)."""
+    layer).
+
+    The decode jit donates the cache (argument 1): each step returns the
+    cache in the buffers it was given, and on one device the attention
+    caches are updated in place, one position a layer
+    (``transformer.cache_in_place``). A caller of ``_decode`` that keeps the
+    cache it passed in must copy it first. With ``obs`` metrics on, the
+    gauge ``serve.decode_cache_in_place`` says which decode path the jits
+    take: 1 in place, 0 the mesh path."""
 
     def __init__(self, cfg: ModelConfig, params, router: GreenRouter,
                  max_len: int = 256, batch_size: int = 4, obs=None):
@@ -76,7 +85,12 @@ class ServingEngine:
         self.batch_size = batch_size
         self.obs = obs if obs is not None and obs.enabled else None
         self._prefill = jax.jit(steps.prefill_step(cfg, self.max_len))
-        self._decode = jax.jit(steps.decode_fn(cfg))
+        self._decode = jax.jit(steps.decode_fn(cfg), donate_argnums=(1,))
+        if self.obs is not None and self.obs.metrics is not None:
+            self.obs.metrics.gauge(
+                "serve.decode_cache_in_place",
+                "1 if decode updates the attention caches in place").set(
+                    float(transformer.cache_in_place()))
         self.queue: List[Request] = []
         self.completions: List[Completion] = []
 

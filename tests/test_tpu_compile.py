@@ -9,6 +9,7 @@ every pytest-xdist worker collects the same tests.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -176,3 +177,125 @@ def test_select_best_sharded_compiles_on_four_chips(topo):
     # the cross-shard combine (the compiler may lower the gather as an
     # all-reduce over a zero-padded buffer)
     assert "all-gather" in text or "all-reduce" in text
+
+
+def _instructions(text):
+    """(name, shape, memory space, opcode, root opcode of a fusion) of each
+    bf16 instruction a compiled program materialises: those inside a
+    fusion's own computation are left out."""
+    comps, comp = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.-]+) .*\{$", line)
+        if head:
+            comp = head.group(1)
+            comps[comp] = []
+        elif comp is not None and " = " in line:
+            comps[comp].append(line)
+    fused = {m.group(1) for m in re.finditer(r" fusion\(.*?calls=%([\w.-]+)",
+                                             text)}
+    roots = {c: re.search(r"\} ([\w-]+)\(", ls[-1]) for c, ls in comps.items()}
+    pat = re.compile(r"%([\w.-]+) = bf16\[([\d,]*)\]\{[^}]*?(S\(\d\))?\} "
+                     r"([\w-]+)\(")
+    for c, lines in comps.items():
+        if c in fused:
+            continue
+        for line in lines:
+            m = pat.search(line)
+            if m is None:
+                continue
+            calls = re.search(r"calls=%([\w.-]+)", line)
+            root = roots.get(calls.group(1)) if calls else None
+            yield (m.group(1),
+                   tuple(int(d) for d in m.group(2).split(",") if d),
+                   m.group(3), m.group(4), root and root.group(1))
+
+
+def _serving_engine(monkeypatch, arch):
+    """``ServingEngine`` with the Pallas paths on, at full widths and a few
+    layers: Qwen3-1.7B at 4 layers and batch 8, DeepSeek-V2-Lite with 16
+    held experts, its prefix and 2 pattern layers at batch 64 (prefilled in
+    4 row chunks); cache 1280. Returns the engine, the config and the
+    batch."""
+    import dataclasses
+
+    from repro.configs import deepseek_v2_lite
+    from repro.configs.registry import get_config
+    from repro.kernels import ops
+    from repro.runtime.serving import ServingEngine
+
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    if arch == "qwen3-1.7b":
+        cfg, B = dataclasses.replace(get_config(arch), num_layers=4,
+                                     repeats=0), 8
+    else:
+        cfg, B = dataclasses.replace(
+            deepseek_v2_lite.make_config(experts_held=16), num_layers=3,
+            repeats=0), 64
+    return ServingEngine(cfg, None, None, max_len=1280, batch_size=B), cfg, B
+
+
+def _lowered(one_chip, eng, cfg, B):
+    """(compiled prefill, compiled decode, abstract cache) on one chip."""
+    from repro.models import transformer
+
+    def sds(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = sds(transformer.abstract_params(cfg))
+    cache = sds(transformer.abstract_cache(cfg, B, eng.max_len))
+    prefill = eng._prefill.lower(params, {"tokens": jax.ShapeDtypeStruct(
+        (B, 1024), jnp.int32, sharding=one_chip)}).compile()
+    decode = eng._decode.lower(
+        params, cache,
+        jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)).compile()
+    return prefill, decode, cache
+
+
+@pytest.mark.parametrize("arch", ["qwen3-1.7b", "deepseek-v2-lite"])
+def test_serving_decode_updates_the_cache_in_place(one_chip, monkeypatch,
+                                                   arch):
+    """``ServingEngine``'s own decode jit (the cache donated): the whole
+    cache is aliased to the output, the program needs less scratch than one
+    layer's cache, and nothing makes an array of the stacked cache's or one
+    layer's shape in HBM but an in-place ``dynamic-update-slice``. Allowed:
+    ``decode_attention``'s staging of one layer's K/V into VMEM (memory
+    space S(1)) as the (B, K, S, hd) it reads; its removal belongs to the
+    kernel."""
+    eng, cfg, B = _serving_engine(monkeypatch, arch)
+    _, compiled, cache = _lowered(one_chip, eng, cfg, B)
+    mem = compiled.memory_analysis()
+    nbytes = lambda x: int(np.prod(x.shape)) * x.dtype.itemsize  # noqa: E731
+    assert mem.alias_size_in_bytes >= sum(map(nbytes, jax.tree.leaves(cache)))
+    one_layer = jax.tree.leaves(cache["pattern"]["0"])
+    assert mem.temp_size_in_bytes < sum(nbytes(x) // x.shape[0]
+                                        for x in one_layer)
+    stacks = {x.shape for x in jax.tree.leaves(cache) if x.ndim >= 4}
+    shapes = stacks | {s[1:] for s in stacks} | {(1,) + s[1:] for s in stacks}
+    staging = {v for _, b, t, k, d in (s for s in stacks if len(s) == 5)
+               for v in ((b, t, k, d), (b, k, t, d))}
+    bad = [(name, shape, space, op)
+           for name, shape, space, op, root in _instructions(compiled.as_text())
+           if shape in shapes and op in ("copy", "fusion")
+           and not (space is None and root == "dynamic-update-slice")
+           and not (space == "S(1)" and shape in staging)]
+    assert not bad, bad
+
+
+@pytest.mark.parametrize("arch", ["qwen3-1.7b", "deepseek-v2-lite"])
+def test_serving_prefill_builds_the_cache_in_decodes_layout(
+        one_chip, monkeypatch, arch):
+    """The engine's prefill returns the cache in the layouts its decode
+    takes, and copies no array of the stacked cache's shape to get there
+    (DeepSeek's batch of 64 is prefilled in row chunks through a loop)."""
+    eng, cfg, B = _serving_engine(monkeypatch, arch)
+    prefill, decode, cache = _lowered(one_chip, eng, cfg, B)
+    made = [f.layout for f in jax.tree.leaves(prefill.output_formats[0])]
+    taken = [f.layout for f in jax.tree.leaves(decode.input_formats[0][1])]
+    assert made == taken
+    stacks = {x.shape for x in jax.tree.leaves(cache) if x.ndim >= 4}
+    bad = [(name, shape, op)
+           for name, shape, _, op, _ in _instructions(prefill.as_text())
+           if shape in stacks and op == "copy"]
+    assert not bad, bad
