@@ -4,7 +4,8 @@ Sweeps fleet size N x batch size B and times one engine scheduling
 decision (``VectorizedPolicy.select_batch``) through three paths:
 
 - **legacy** — the rebuild-everything path: fresh ``featurize`` (O(N)
-  Python per-node loop + N provider calls) per step (``use_cache=False``);
+  Python per-node loop + N provider calls) of the distinct task profiles
+  per step, scored by the float64 numpy scorer;
 - **cached** — the incremental FeatureCache fast path (DESIGN.md §3):
   O(changed) sync, one batched provider read, task-profile dedup, chunked
   vectorized scoring (selection memo off, so the rows keep measuring the
@@ -31,13 +32,12 @@ from typing import Dict, List
 
 import numpy as np
 
-from repro.core.api import StaticProvider
 from repro.core.cluster import EdgeCluster, NodeSpec
-from repro.core.policy import VectorizedPolicy
+from repro.core.policy import VectorizedPolicy, featurize
+from repro.core.providers import StaticProvider, TraceProvider
 from repro.core.scheduler import MODES, Task
 from repro.core.temporal import (DeferrableTask, plan_wake, plan_wake_scalar,
                                  synthetic_trace)
-from repro.core.api import TraceProvider
 
 PAPER_PER_TASK_MS = 0.03
 
@@ -87,23 +87,28 @@ def bench_select(cluster: EdgeCluster, tasks: List[Task], *,
                  legacy_reps: int, cached_reps: int) -> Dict:
     w = MODES["green"]
     provider = StaticProvider.from_cluster(cluster)
-    legacy = VectorizedPolicy(backend="numpy", use_cache=False)
+    scorer = VectorizedPolicy(backend="numpy")
     # memo off: these rows measure the incremental-featurize scoring pass,
     # not the steady-state profile memo (bench_step measures that)
-    cached = VectorizedPolicy(backend="numpy", use_cache=True,
-                              use_select_memo=False)
+    cached = VectorizedPolicy(backend="numpy", use_select_memo=False)
     # dirty a handful of nodes between steps, like a live engine would
     names = list(cluster.nodes)
+
+    def step_legacy():
+        reps = {(t.cpu, t.mem_mb): t for t in tasks}
+        F, node_names = featurize(cluster, list(reps.values()), provider)
+        chosen = dict(zip(reps, scorer._select_from_features(F, node_names,
+                                                             w)))
+        return [chosen[(t.cpu, t.mem_mb)] for t in tasks]
+
     def step_cached():
         for nm in names[:8]:
             cluster.nodes[nm].running += 1
             cluster.nodes[nm].running -= 1
         return cached.select_batch(cluster, tasks, w, provider)
-    legacy_s = _time(lambda: legacy.select_batch(cluster, tasks, w, provider),
-                     legacy_reps)
+    legacy_s = _time(step_legacy, legacy_reps)
     cached_s = _time(step_cached, cached_reps)
-    assert (cached.select_batch(cluster, tasks, w, provider)
-            == legacy.select_batch(cluster, tasks, w, provider)), \
+    assert cached.select_batch(cluster, tasks, w, provider) == step_legacy(), \
         "cached fast path diverged from the fresh-featurize oracle"
     b = len(tasks)
     return {

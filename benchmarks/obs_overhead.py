@@ -123,9 +123,10 @@ def sim_byte_identity() -> Dict:
     """Fixed-seed sim ``to_text`` byte-equality: obs absent vs disabled vs
     fully enabled, across the batched/scalar execute paths AND the
     calendar/heap event queues."""
-    from repro.core.api import (CarbonEdgeEngine, ForecastProvider,
-                                StaticProvider, TraceProvider)
+    from repro.core.api import CarbonEdgeEngine
     from repro.core.cluster import EdgeCluster, PAPER_NODES
+    from repro.core.providers import (ForecastProvider, StaticProvider,
+                                      TraceProvider)
     from repro.core.scheduler import Task
     from repro.core.temporal import DeferrableTask, synthetic_trace
     from repro.obs import Observability
@@ -176,8 +177,9 @@ def _chaos_driver(obs, event_queue: str):
     """The fixed-seed chaos scenario (examples/chaos_serving.py):
     two closed-loop tenants through a lagged-detection node crash + feed
     blackout, obs wired to BOTH the engine and the driver."""
-    from repro.core.api import CarbonEdgeEngine, StaticProvider
+    from repro.core.api import CarbonEdgeEngine
     from repro.core.cluster import EdgeCluster, PAPER_NODES
+    from repro.core.providers import StaticProvider
     from repro.resilience import (Fault, FaultInjector, Resilience,
                                   ResilientProvider)
     from repro.sim import (AsyncEngineDriver, ClientPopulation,

@@ -31,8 +31,9 @@ from typing import Dict, List
 import numpy as np
 
 from benchmarks.fleet_scale import PAPER_PER_TASK_MS, _time, make_fleet, make_tasks
-from repro.core.api import (CarbonEdgeEngine, ForecastProvider, StaticProvider,
-                            TraceProvider, intensity_interval_batch)
+from repro.core.api import CarbonEdgeEngine
+from repro.core.providers import (ForecastProvider, StaticProvider,
+                                  TraceProvider, intensity_interval_batch)
 from repro.core.scheduler import MODES
 from repro.core.temporal import (DeferrableTask, plan_wake_batch,
                                  plan_wake_risk_batch, synthetic_trace)

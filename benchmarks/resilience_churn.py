@@ -48,8 +48,9 @@ def _sim(faults=None, *, resilient: bool = True, n_nodes: int = 6,
     last-known-good provider wired, faults optional."""
     import numpy as np
 
-    from repro.core.api import CarbonEdgeEngine, TraceProvider
+    from repro.core.api import CarbonEdgeEngine
     from repro.core.cluster import EdgeCluster, NodeSpec
+    from repro.core.providers import TraceProvider
     from repro.core.scheduler import Task
     from repro.core.temporal import IntensityTrace
     from repro.resilience import Resilience, ResilientProvider

@@ -45,8 +45,9 @@ from time import perf_counter
 
 import numpy as np
 
-from repro.core.api import CarbonEdgeEngine, TraceProvider
+from repro.core.api import CarbonEdgeEngine
 from repro.core.cluster import EdgeCluster, PAPER_NODES
+from repro.core.providers import TraceProvider
 from repro.core.scheduler import Task
 from repro.sim import (AsyncEngineDriver, ClientPopulation,
                        ClosedLoopClientPool, PoissonArrivals,

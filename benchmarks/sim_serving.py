@@ -18,9 +18,10 @@ from __future__ import annotations
 
 from typing import Dict, List
 
-from repro.core.api import (CarbonEdgeEngine, ForecastProvider,
-                            StaticProvider, TraceProvider)
+from repro.core.api import CarbonEdgeEngine
 from repro.core.cluster import EdgeCluster, PAPER_NODES
+from repro.core.providers import (ForecastProvider, StaticProvider,
+                                  TraceProvider)
 from repro.core.scheduler import Task
 from repro.core.temporal import DeferrableTask, synthetic_trace
 from repro.sim import AsyncEngineDriver, ConstantRateArrivals, PoissonArrivals

@@ -12,8 +12,9 @@ failover placement after the fact.
 
 Run:  PYTHONPATH=src python examples/chaos_serving.py
 """
-from repro.core.api import CarbonEdgeEngine, StaticProvider
+from repro.core.api import CarbonEdgeEngine
 from repro.core.cluster import EdgeCluster, PAPER_NODES
+from repro.core.providers import StaticProvider
 from repro.obs import Observability
 from repro.resilience import (Fault, FaultInjector, Resilience,
                               ResilientProvider)

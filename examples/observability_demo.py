@@ -13,8 +13,9 @@ Run:  PYTHONPATH=src python examples/observability_demo.py
 """
 import numpy as np
 
-from repro.core.api import CarbonEdgeEngine, StaticProvider
+from repro.core.api import CarbonEdgeEngine
 from repro.core.cluster import PAPER_NODES, EdgeCluster
+from repro.core.providers import StaticProvider
 from repro.core.scheduler import Task
 from repro.obs import Observability
 from repro.partition.policy import PartitionPolicy

@@ -19,8 +19,8 @@ import numpy as np
 
 from repro.configs.cnn_zoo import get_cnn_config
 from repro.configs.registry import get_config
-from repro.core.api import ForecastProvider, TraceProvider
 from repro.core.cluster import EdgeCluster, NodeSpec
+from repro.core.providers import ForecastProvider, TraceProvider
 from repro.core.scheduler import MODES, Task
 from repro.core.temporal import synthetic_trace
 from repro.partition import (PartitionPolicy, calibrate_intensity,

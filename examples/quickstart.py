@@ -10,10 +10,11 @@ Run:  PYTHONPATH=src python examples/quickstart.py
 import numpy as np
 
 from repro.configs.cnn_zoo import get_cnn_config
-from repro.core.api import CarbonEdgeEngine, StaticProvider
+from repro.core.api import CarbonEdgeEngine
 from repro.core.carbon import CarbonMonitor
 from repro.core.cluster import EdgeCluster, PAPER_NODES
 from repro.core.partitioner import green_weights, partition_cnn
+from repro.core.providers import StaticProvider
 from repro.core.scheduler import MODES, Task, score_table
 
 # -- 1. carbon-aware scheduling (engine facade) ------------------------------

@@ -2,18 +2,18 @@
 # (scheduler, optimizer, data path, serving loop, etc.) in the
 # host framework. Add sibling subpackages for substrates.
 #
-# Public API (DESIGN.md): providers + policies + engine, lazily re-exported
-# so `import repro.core` stays cheap.
+# Public API (DESIGN.md): providers, policies and the engine, lazily
+# re-exported so `import repro.core` stays cheap.
 
 _API = {
     "CarbonEdgeEngine": "repro.core.api",
-    "CarbonIntensityProvider": "repro.core.api",
+    "CarbonIntensityProvider": "repro.core.providers",
     "SchedulingPolicy": "repro.core.api",
-    "StaticProvider": "repro.core.api",
-    "TraceProvider": "repro.core.api",
-    "ForecastProvider": "repro.core.api",
-    "FallbackProvider": "repro.core.api",
-    "intensity_batch": "repro.core.api",
+    "StaticProvider": "repro.core.providers",
+    "TraceProvider": "repro.core.providers",
+    "ForecastProvider": "repro.core.providers",
+    "FallbackProvider": "repro.core.providers",
+    "intensity_batch": "repro.core.providers",
     "WeightedScoringPolicy": "repro.core.policy",
     "VectorizedPolicy": "repro.core.policy",
     "TemporalPolicy": "repro.core.policy",

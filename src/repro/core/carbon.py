@@ -19,6 +19,7 @@ import numpy as np
 
 from repro.core import energy as energy_mod
 from repro.core.energy import RooflineTerms
+from repro.core.providers import intensity_batch
 
 RAM_W_PER_GB = 0.375  # paper §III.B.1 DDR4 approximation
 
@@ -100,7 +101,7 @@ class CarbonMonitor:
         """(len(regions),) billing intensities at ``hour`` — the batched,
         side-effect-free form of :meth:`billing_intensity` (DESIGN.md §6).
         Provider-driven (non-pinned) regions are resolved through one
-        ``api.intensity_batch`` call instead of a per-region Python loop;
+        ``providers.intensity_batch`` call instead of a per-region Python loop;
         pinned or provider-less regions read their registered value. An
         unregistered region raises ``KeyError`` like the scalar probe."""
         accs = [self.regions[r] for r in regions]     # KeyError like scalar
@@ -108,8 +109,6 @@ class CarbonMonitor:
         if self.provider is not None:
             live = [i for i, a in enumerate(accs) if not a.pinned]
             if live:
-                from repro.core.api import intensity_batch
-
                 vals = intensity_batch(self.provider,
                                        [regions[i] for i in live], hour)
                 out[live] = np.asarray(vals, dtype=float)

@@ -11,7 +11,7 @@ scheduling overhead. :class:`FeatureCache` removes it:
   ``NodeState.__setattr__``), so :meth:`sync` refreshes **O(changed)** rows
   — an engine step that executed B tasks re-reads B rows, not N;
 - grid intensity is fetched through the **batched provider API**
-  (``api.intensity_batch``: one vectorized call, not N Python calls) and
+  (``providers.intensity_batch``: one vectorized call, not N Python calls) and
   memoized per (provider, hour) — a ``TIME_INVARIANT`` provider (e.g.
   ``StaticProvider``) is queried at most once per node, ever;
 - only nodes some task in the batch could actually use are queried
@@ -34,6 +34,7 @@ from typing import List, Optional
 
 import numpy as np
 
+from repro.core.providers import intensity_batch
 from repro.core.scheduler import LOAD_THRESHOLD
 
 
@@ -160,7 +161,7 @@ class FeatureCache:
         regional column. Nodes already fetched under the current
         (provider, hour) key — or under the provider alone when it declares
         ``TIME_INVARIANT`` — are served from cache; the rest go through one
-        ``api.intensity_batch`` call.
+        ``providers.intensity_batch`` call.
         """
         if provider is None:
             return self.carbon_static
@@ -173,8 +174,6 @@ class FeatureCache:
         self._int_hour = now_hour
         missing = ~self._int_have if need is None else (need & ~self._int_have)
         if missing.any():
-            from repro.core.api import intensity_batch
-
             idx = np.nonzero(missing)[0]
             vals = intensity_batch(provider, [self.names[i] for i in idx],
                                    now_hour)

@@ -1,7 +1,7 @@
 """Scheduling policies (DESIGN.md §1.2): one scoring rule, three engines.
 
 ``featurize`` is the single source of the (N, 8) feature-matrix layout the
-Pallas ``node_score`` kernel, the numpy scorer, and the scalar oracle all
+numpy scorer, the scalar oracle and the Pallas ``node_scores`` kernel all
 share — the paper's Eq. 3/4 components are computed from these columns and
 nowhere else:
 
@@ -22,8 +22,10 @@ Policies:
 
 - :class:`WeightedScoringPolicy` — the scalar Python loop (Algorithm 1
   verbatim). Survives as the parity oracle.
-- :class:`VectorizedPolicy` — batched (B, N) scoring in one call; numpy on
-  CPU hosts, the Pallas ``node_score`` kernel on TPU. The engine default.
+- :class:`VectorizedPolicy` — batched (B, N) selection in one call, with
+  one scorer per backend: the float64 numpy tensor path, and on TPU the
+  Pallas column kernel ``select_best_columns`` over the cluster's
+  :class:`~repro.core.featcache.FeatureCache`. The engine default.
 - :class:`TemporalPolicy` — deferral as a (slot x node) grid where the
   Eq. 4 column is time-indexed through the intensity provider; min-carbon
   placement with the weighted score as tie-breaker.
@@ -35,8 +37,9 @@ from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro.core.api import (CarbonIntensityProvider, StaticProvider)
 from repro.core.cluster import EdgeCluster
+from repro.core.providers import (CarbonIntensityProvider, StaticProvider,
+                                  intensity_batch)
 from repro.core.scheduler import (LOAD_THRESHOLD, Task, Weights,
                                   node_feasible, scores, vector_scores)
 from repro.obs.profiler import span
@@ -184,10 +187,6 @@ def get_cache(cluster):
     return fc() if callable(fc) else None
 
 
-# Backwards-compatible alias (pre-partition-subsystem name).
-_get_cache = get_cache
-
-
 class _SelectionMemo:
     """Profile-level selection memo over unchanged feature state
     (DESIGN.md §6).
@@ -276,20 +275,20 @@ class VectorizedPolicy:
     ``backend``:
       - ``"auto"``   — Pallas kernel on TPU, numpy elsewhere (default);
       - ``"numpy"``  — float64 numpy (bit-matches the scalar oracle);
-      - ``"pallas"`` — the ``kernels/node_score`` kernel (interpret mode off
-        TPU), float32.
+      - ``"pallas"`` — the column kernel ``select_best_columns`` (interpret
+        mode off TPU), float32 scores with exact float64 feasibility.
 
-    Fleet-scale fast path (DESIGN.md §3, on by default): features come
-    from the cluster's incremental :class:`~repro.core.featcache.
-    FeatureCache` (O(changed) per step instead of an O(N) Python rebuild),
-    duplicate task resource profiles share one scored row, and Pallas
-    shapes are padded to power-of-two buckets so distinct (B, N) stop
-    retriggering jit. On numpy the task axis is chunked to bound peak
-    memory; on Pallas the host ships node columns and task profiles
-    (:func:`featurize_columns`) and the kernel scores every (task, node)
-    cell on the chip in one launch per step. ``use_cache=False`` forces
-    the fresh ``featurize`` rebuild and the tensor kernel — the parity
-    oracle for all of the above.
+    One scorer per backend (DESIGN.md §3). Numpy builds the (B, N, 8)
+    float64 tensor — ``featurize_cached`` from the cluster's incremental
+    :class:`~repro.core.featcache.FeatureCache` in ``_CHUNK_ELEMS``
+    chunks of the task axis — and scores it in
+    :meth:`_select_from_features`. Pallas ships node columns and task
+    profiles (:func:`featurize_columns`), padded to power-of-two buckets
+    so distinct (B, N) stop retriggering jit, and the kernel scores every
+    (task, node) cell on the chip in one launch per step. A cluster-like
+    without a FeatureCache is scored by the float64 reference
+    (``featurize`` + :meth:`_select_from_features`) on either backend.
+    Duplicate task resource profiles share one scored row.
     """
 
     name = "vectorized"
@@ -305,12 +304,11 @@ class VectorizedPolicy:
 
     def __init__(self, backend: str = "auto",
                  latency_threshold_ms: float = 5000.0,
-                 use_cache: bool = True, use_select_memo: bool = True):
+                 use_select_memo: bool = True):
         if backend not in ("auto", "numpy", "pallas"):
             raise ValueError(f"unknown backend {backend!r}")
         self.backend = backend
         self.latency_threshold_ms = latency_threshold_ms
-        self.use_cache = use_cache
         # Steady-state fast path (DESIGN.md §6): memoize per-profile
         # selection while the cache's data_rev / provider / hour epoch
         # holds. False forces a fresh scoring pass every call — what the
@@ -380,42 +378,11 @@ class VectorizedPolicy:
                                       jnp.asarray(w8))
         return np.asarray(out, np.float64)[:B, :N]
 
-    def _select_pallas_fused(self, F: np.ndarray, w5: np.ndarray):
-        """Fused score+argmax kernel: ships (B,) winner indices/scores to
-        host instead of the full (B, N) score matrix."""
-        import jax.numpy as jnp
-
-        from repro.kernels import ops
-
-        B = F.shape[0]
-        w8 = np.zeros(FEATURE_DIM, np.float32)
-        w8[:5] = w5
-        prof = self.profiler
-        with span(prof, "select.pad"):
-            padded = self._pad_to_buckets(F)
-        with span(prof, "select.put"):
-            Fd, wd = jnp.asarray(padded), jnp.asarray(w8)
-        with span(prof, "select.launch"):
-            idx, val = ops.select_best_node_fused(Fd, wd)
-        with span(prof, "select.fetch"):
-            return np.asarray(idx)[:B], np.asarray(val, np.float64)[:B]
-
-    def _pallas_choices(self, idx: np.ndarray, val: np.ndarray,
-                        names: List[str]) -> List[Optional[str]]:
-        """Names of the fused kernels' winners; Algorithm 1 requires a
-        strictly positive score (best_score init 0)."""
-        if self.capture_scores:
-            # winner-only kernel: runner-up not materialized
-            self._cap_s.append(np.asarray(val, dtype=float))
-            self._cap_r.append(np.full(len(val), np.nan))
-        return [names[b] if v > 0.0 else None for b, v in zip(idx, val)]
-
     def _select_from_features(self, F: np.ndarray, names: List[str],
                               weights: Weights) -> List[Optional[str]]:
-        if self._resolved_backend() == "pallas":
-            idx, val = self._select_pallas_fused(F, weights.as_array())
-            return self._pallas_choices(idx, val, names)
-        # Algorithm 1 requires a strictly positive score (best_score init 0).
+        """The float64 scorer: (B, N, 8) features -> B winners (numpy on
+        every backend). Algorithm 1 requires a strictly positive score
+        (best_score init 0)."""
         totals = self._score_numpy(F, weights.as_array())
         best = np.argmax(totals, axis=1)
         if self.capture_scores:
@@ -480,23 +447,13 @@ class VectorizedPolicy:
                             for k, v in self._cap.items()}
         return np.asarray(chosen, dtype=object)[idx].tolist()
 
-    # Above this fleet size the numpy backend scores straight from the
-    # cache's column arrays (one (N,) task-independent component base per
-    # step + an (U, N) S_R/feasibility pass) instead of materializing the
-    # (B, N, 8) tensor — ~3-4x less memory traffic, at the cost of a
-    # last-ulp different summation order than vector_scores' dot product
-    # (argmax-equivalent except on sub-1e-12 score ties). Below it, the
-    # featurize_cached + vector_scores path keeps scoring bit-identical
-    # to the scalar oracle.
-    COLUMN_PATH_MIN_N = 4096
-
     def _select_unique(self, cluster, reps: Sequence[Task], weights: Weights,
                        provider, now_hour: float) -> List[Optional[str]]:
         cap = self.capture_scores
         if cap:
             self._cap_s, self._cap_r = [], []
             self.last_scores = None
-        cache = get_cache(cluster) if self.use_cache else None
+        cache = get_cache(cluster)
         if cache is None:
             prof = self.profiler
             with span(prof, "featurize"):
@@ -558,9 +515,6 @@ class VectorizedPolicy:
         if self._resolved_backend() == "pallas":
             return self._select_cached_pallas(cache, reps, weights, provider,
                                               now_hour)
-        if cache.n >= self.COLUMN_PATH_MIN_N:
-            return self._select_cached_columns(cache, reps, weights,
-                                               provider, now_hour)
         names = cache.names
         chunk = max(1, self._CHUNK_ELEMS // max(cache.n, 1))
         prof = self.profiler
@@ -591,10 +545,9 @@ class VectorizedPolicy:
                               weights: Weights, provider,
                               now_hour: float) -> List[Optional[str]]:
         """Pallas selection straight from the cache's node columns: the
-        host assembles O(U + N) columns (``featurize``) and the column
-        kernel scores every (task, node) cell on the chip, all U rows in
-        one launch, with the winners of ``select_best_fused`` on
-        ``featurize_cached``'s tensor, bit for bit."""
+        host assembles O(U + N) columns (:func:`featurize_columns`) and the
+        column kernel scores every (task, node) cell on the chip, all U
+        rows in one launch."""
         import jax
 
         from repro.kernels import ops
@@ -616,50 +569,13 @@ class VectorizedPolicy:
                 idx, val = jax.device_get((idx, val))
                 U = len(reps)
                 idx, val = idx[:U], np.asarray(val[:U], np.float64)
-        return self._pallas_choices(idx, val, cache.names)
-
-    def _select_cached_columns(self, cache, reps: Sequence[Task],
-                               weights: Weights, provider,
-                               now_hour: float) -> List[Optional[str]]:
-        """Fleet-scale numpy selection straight from cache columns: the
-        task-independent components (S_L, S_P, S_B, S_C) are one (N,)
-        vector per step; only S_R and feasibility touch (U, N)."""
-        w = weights.as_array()
+        if self.capture_scores:
+            # winner-only kernel: runner-up not materialized
+            self._cap_s.append(val)
+            self._cap_r.append(np.full(U, np.nan))
+        # Algorithm 1 requires a strictly positive score (best_score init 0)
         names = cache.names
-        prof = self.profiler
-        with span(prof, "featurize"):
-            task_cpu = np.array([t.cpu for t in reps], dtype=float)
-            task_mem = np.array([t.mem_mb for t in reps], dtype=float)
-            feasible = cache.feasible(task_cpu, task_mem,
-                                      self.latency_threshold_ms)  # (U, N)
-            ints = cache.intensities(provider, now_hour,
-                                     need=feasible.any(axis=0))
-            base = (w[1] * (1.0 - cache.load)
-                    + w[2] * (1.0 / (1.0 + cache.avg_time_s))
-                    + w[3] * (1.0 / (1.0 + cache.running * 2.0))
-                    + w[4] * (1.0 / (1.0 + ints * cache.e_est)))  # (N,)
-        with span(prof, "score"):
-            out: List[Optional[str]] = []
-            chunk = max(1, self._CHUNK_ELEMS // max(cache.n, 1))
-            for lo in range(0, len(reps), chunk):
-                tc = task_cpu[lo:lo + chunk, None]
-                tm = task_mem[lo:lo + chunk, None]
-                cpu_frac = np.ones((tc.shape[0], cache.n))
-                np.divide(cache.free_cpu[None, :], tc, out=cpu_frac,
-                          where=tc > 0)
-                mem_frac = np.ones((tm.shape[0], cache.n))
-                np.divide(cache.free_mem[None, :], tm, out=mem_frac,
-                          where=tm > 0)
-                s_r = (0.5 * np.minimum(1.0, cpu_frac)
-                       + 0.5 * np.minimum(1.0, mem_frac))
-                totals = np.where(feasible[lo:lo + chunk],
-                                  w[0] * s_r + base[None, :], -np.inf)
-                best = np.argmax(totals, axis=1)
-                if self.capture_scores:
-                    self._cap_block(totals, best)
-                out.extend(names[b] if totals[i, b] > 0.0 else None
-                           for i, b in enumerate(best))
-        return out
+        return [names[b] if v > 0.0 else None for b, v in zip(idx, val)]
 
     # Below this fleet size a single-task selection is cheaper through the
     # scalar loop than through featurize + array machinery (measured ~11 us
@@ -750,7 +666,7 @@ class TemporalPolicy:
         # so skip the N provider queries featurize would otherwise spend on
         # a column that gets overwritten.
         slot_provider = None if duration > 0 else provider
-        cache = get_cache(cluster) if self.scorer.use_cache else None
+        cache = get_cache(cluster)
         if cache is not None:
             F, names = featurize_cached(cache, [task], slot_provider,
                                         now_hour,
@@ -779,7 +695,6 @@ class TemporalPolicy:
             idx = np.nonzero(feasible)[0]
             if idx.size:
                 # the whole (S, N_feasible) slot grid in one batched read
-                from repro.core.api import intensity_batch
                 grid[:, idx] = np.asarray(
                     intensity_batch(provider, [names[j] for j in idx], mid)
                 ).reshape(n_slots, idx.size)

@@ -11,11 +11,11 @@ from dataclasses import dataclass
 from typing import Dict, List, Optional
 
 from repro.core import energy as energy_mod
-from repro.core.api import (CarbonIntensityProvider, FallbackProvider,
-                            StaticProvider)
 from repro.core.carbon import CarbonMonitor
 from repro.core.cluster import EdgeCluster, NodeSpec
 from repro.core.energy import RooflineTerms
+from repro.core.providers import (CarbonIntensityProvider, FallbackProvider,
+                                  StaticProvider)
 from repro.core.scheduler import MODES, Task
 
 

@@ -18,7 +18,7 @@ time-indexed — so the weight semantics of Table I are unchanged.
 The slot-grid search itself lives in
 :class:`repro.core.policy.TemporalPolicy` (the Eq. 3 math is *not*
 duplicated here); intensity is read through a
-:class:`repro.core.api.TraceProvider`. This module keeps the trace types,
+:class:`repro.core.providers.TraceProvider`. This module keeps the trace types,
 the deferrable-task model, and the thin scheduler wrapper.
 """
 from __future__ import annotations
@@ -28,9 +28,10 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro.core.api import StaticProvider, TraceProvider, intensity_batch
 from repro.core.cluster import EdgeCluster
 from repro.core.policy import Placement, TemporalPolicy
+from repro.core.providers import (StaticProvider, TraceProvider,
+                                  intensity_batch, intensity_interval_batch)
 from repro.core.scheduler import Task, Weights, node_feasible
 
 
@@ -193,7 +194,7 @@ def plan_wake(provider, cluster: EdgeCluster, task, now_hour: float,
     cluster's incremental :class:`~repro.core.featcache.FeatureCache`
     columns (duck-typed cluster-likes without one fall back to the scalar
     feasibility filter) and the whole (S, N) slot grid is one batched
-    :func:`~repro.core.api.intensity_batch` read — no nodes x slots
+    :func:`~repro.core.providers.intensity_batch` read — no nodes x slots
     Python loop. Delegates to :func:`plan_wake_batch`.
     """
     return float(plan_wake_batch(provider, cluster, [task], now_hour,
@@ -276,7 +277,7 @@ def plan_wake_risk_batch(provider, cluster: EdgeCluster, tasks,
     :func:`plan_wake_batch` trusts the provider's point forecast; with a
     noisy forecast that gambles real carbon on a predicted dip. Here the
     grid is read as ``coverage``-level intervals
-    (:func:`repro.core.api.intensity_interval_batch`) and a task defers
+    (:func:`repro.core.providers.intensity_interval_batch`) and a task defers
     only when the deferral wins even under the interval's pessimistic
     view: the candidate future slot is the feasible (slot >= 1, node)
     cell minimising the interval UPPER bound (earliest slot, first node
@@ -289,8 +290,6 @@ def plan_wake_risk_batch(provider, cluster: EdgeCluster, tasks,
     improvement". Tasks without deadline slack, or with no feasible node,
     wake immediately.
     """
-    from repro.core.api import intensity_interval_batch
-
     T = len(tasks)
     wakes = np.full(T, now_hour, dtype=float)
     n_slots = np.array([_wake_slots(t, slot_hours) for t in tasks])

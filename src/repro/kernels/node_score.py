@@ -1,10 +1,18 @@
-"""Carbon-aware node scoring (paper Algorithm 1) — Pallas TPU kernel.
+"""Carbon-aware node scoring (paper Algorithm 1) — Pallas TPU kernels.
 
-The paper's NSA inner loop at fleet scale: for N nodes, fuse the five score
-components (Eq. 3) and the feasibility filter into one VMEM pass, emitting
-per-node total scores (invalid nodes get -inf). The host (or a tiny jnp
-argmax) picks the winner. At 10^5-10^6 nodes this is one HBM read of the
-(N, 8) feature matrix — the op is memory-bound and the fusion is the win.
+The paper's NSA inner loop at fleet scale: the five score components
+(Eq. 3) and the feasibility filter fused into one VMEM pass. Three kernels
+share that math (:func:`_eq3_total`):
+
+- :func:`select_best_columns` — the engine's select on the chip
+  (``VectorizedPolicy``): every (task, node) cell scored from node columns
+  and task profiles, reduced on the chip to each task's winner. Its trace
+  name is ``select_best_fused``.
+- :func:`select_best_joint` — the (task, cut, node) reduction of
+  ``PartitionPolicy`` over a (B, P, N, 8) feature tensor.
+- :func:`node_scores` / :func:`node_scores_batched` — per-cell totals of
+  an (N, 8) or (B, N, 8) feature tensor, for ``TemporalPolicy``'s slot
+  grid.
 
 Feature layout (N, 8) float32:
   0 cpu_free_frac, 1 mem_free_frac, 2 load, 3 avg_time_s,
@@ -12,9 +20,8 @@ Feature layout (N, 8) float32:
   6 valid (1/0 feasibility), 7 padding
 Weights: (8,) = [w_R, w_L, w_P, w_B, w_C, 0, 0, 0].
 
-:func:`select_best_columns` scores the same cells from the node columns
-and the task profiles instead, so no per-(task, node) tensor exists
-outside the chip:
+The column kernel's operands, with no per-(task, node) tensor outside the
+chip:
 
   node rows (7, N) float32: 0 free_cpu, 1 free_mem, 2 load, 3 avg_time_s,
     4 running_tasks, 5 intensity_x_e_est, 6 node_ok (1/0)
@@ -92,12 +99,6 @@ def node_scores(features, weights, *, bn: int = 1024, interpret: bool = False):
     return out[:n0, 0]
 
 
-def select_best(features, weights, *, interpret: bool = False) -> jnp.ndarray:
-    """Fused scoring + argmax; returns best node index (int32)."""
-    s = node_scores(features, weights, interpret=interpret)
-    return jnp.argmax(s).astype(jnp.int32)
-
-
 # ---------------------------------------------------------------------------
 # Batched variant: B pending tasks x N nodes in ONE kernel launch
 # ---------------------------------------------------------------------------
@@ -108,10 +109,11 @@ def node_scores_batched(features, weights, *, bn: int = 1024,
                         interpret: bool = False):
     """features: (B, N, 8) f32; weights: (8,) f32 -> (B, N) scores.
 
-    The CarbonEdgeEngine hot path: scoring is row-wise with shared weights,
-    so B tasks x N nodes flattens to one (B*N, 8) pass through the single
-    kernel above — still exactly one pallas_call (and one HBM read of the
-    feature tensor) per batch, with no duplicated Eq. 3 math.
+    ``TemporalPolicy``'s slot grid on the pallas backend: scoring is
+    row-wise with shared weights, so B rows x N nodes flattens to one
+    (B*N, 8) pass through the single kernel above — one pallas_call (and
+    one HBM read of the feature tensor) per grid, with no duplicated Eq. 3
+    math.
     """
     B, N, _ = features.shape
     flat = node_scores(features.reshape(B * N, 8), weights, bn=bn,
@@ -119,14 +121,8 @@ def node_scores_batched(features, weights, *, bn: int = 1024,
     return flat.reshape(B, N)
 
 
-def select_best_batched(features, weights, *, interpret: bool = False):
-    """Fused batched scoring + per-task argmax -> (B,) int32 node indices."""
-    idx, _ = select_best_fused(features, weights, interpret=interpret)
-    return idx
-
-
 # ---------------------------------------------------------------------------
-# Fused score + argmax: reduce to (best_index, best_score) on-chip
+# Score + argmax: reduce to (best_index, best_score) on-chip
 # ---------------------------------------------------------------------------
 
 
@@ -159,16 +155,6 @@ def _fold_tile_best(s, base, first, idx_ref, val_ref):
         idx_ref[...] = jnp.where(better, gidx, idx_ref[...])
 
 
-def _select_kernel(f_ref, w_ref, idx_ref, val_ref):
-    """One (1, bn, 8) node tile of one task row: score it, reduce to the
-    tile's (first) max, and fold into the running per-task best across the
-    sequential node-tile grid axis. Emits per-task winner index + score —
-    the (B, N) score matrix never leaves the chip."""
-    j = pl.program_id(1)
-    s = _eq3_tile_scores(f_ref[0], w_ref[...])[None, :]      # (1, bn)
-    _fold_tile_best(s, j * s.shape[1], j == 0, idx_ref.at[0], val_ref.at[0])
-
-
 # Per-task outputs are (B, 1, 1): a (1, 1, 1) block's last two dims equal
 # the array's, which the TPU tiling accepts (a (1, 1) block of a (B, 1)
 # array is refused unless B == 1).
@@ -177,44 +163,6 @@ def _winner_specs(index_map, B):
     shapes = [jax.ShapeDtypeStruct((B, 1, 1), jnp.int32),
               jax.ShapeDtypeStruct((B, 1, 1), jnp.float32)]
     return specs, shapes
-
-
-@functools.partial(jax.jit, static_argnames=("bn", "interpret"))
-def select_best_fused(features, weights, *, bn: int = 1024,
-                      interpret: bool = False):
-    """features: (B, N, 8) f32; weights: (8,) f32 ->
-    ((B,) int32 best index, (B,) f32 best score).
-
-    One pallas_call tiling the node axis: each tile reduces to its local
-    (max, first-argmax) and folds into the per-task running best across
-    the sequential tile axis, so only 2*B scalars ship to host instead of
-    a (B, N) score matrix. N is padded to a multiple of bn (padding rows
-    invalid -> NEG_INF, never selected while any real node is feasible).
-    Callers that want a bounded jit cache should pad (B, N) to shape
-    buckets first (VectorizedPolicy does). Its trace name is
-    ``select_best_tensor``, apart from the column kernel's.
-    """
-    B, n0, _ = features.shape
-    pad = (-n0) % bn
-    if pad:
-        features = jnp.pad(features, ((0, 0), (0, pad), (0, 0)))
-    N = features.shape[1]
-    out_specs, out_shape = _winner_specs(lambda i, j: (i, 0, 0), B)
-    idx, val = pl.pallas_call(
-        _select_kernel,
-        name="select_best_tensor",
-        grid=(B, N // bn),
-        in_specs=[
-            pl.BlockSpec((1, bn, 8), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, 8), lambda i, j: (0, 0)),
-        ],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(features, weights.reshape(1, 8))
-    return idx[:, 0, 0], val[:, 0, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -279,11 +227,12 @@ def select_best_columns(nodes, node_keys, tasks, task_keys, weights, *,
     (U, 4) i32 (layouts in the module docstring); weights (8,) f32 ->
     ((U,) int32 best index, (U,) f32 best score).
 
-    The answer of :func:`select_best_fused` on the (U, N, 8) tensor that
-    ``featurize_cached`` would build from the same columns, bit for bit —
-    lowest index on exact ties, ``NEG_INF`` when no node is feasible —
-    from O(U + N) inputs: one pallas_call over (task-row tiles x node
-    tiles), each tile computing every cell's Eq. 3 total in VMEM. Rows are
+    Each task's first-argmax over the float32 Eq. 3 totals of the cells
+    ``featurize_cached`` would build from the same columns — lowest index
+    on exact ties, ``NEG_INF`` when no node is feasible, feasibility
+    decided exactly in float64 — from O(U + N) inputs: one pallas_call
+    over (task-row tiles x node tiles), each tile computing every cell's
+    Eq. 3 total in VMEM. Rows are
     padded to a multiple of ``bu`` (and 8) and nodes to a multiple of
     ``bn`` when N exceeds it (padding nodes are not ok -> NEG_INF). Its
     trace name is ``select_best_fused``: it is the engine's select kernel.
@@ -381,52 +330,3 @@ def select_best_joint(features, weights, *, bn: int = 1024,
     # case the score is NEG_INF and callers discard the indices anyway.
     return ((flat // N).astype(jnp.int32), (flat % N).astype(jnp.int32),
             val[:, 0, 0])
-
-
-# ---------------------------------------------------------------------------
-# Sharded node axis: N >= 10^5 fleets across devices
-# ---------------------------------------------------------------------------
-
-
-@functools.lru_cache(maxsize=32)
-def _sharded_select_fn(mesh, axis: str, bn: int, interpret: bool):
-    """Build (and cache) the shard_map'd fused select for one mesh: each
-    device scores its node shard with the fused kernel, then a cross-shard
-    argmax combine picks the global winner (lowest global index on ties)."""
-
-    def local_select(f_local, w):
-        # f_local: (B, N/d, 8) on this device
-        idx, val = select_best_fused(f_local, w, bn=bn, interpret=interpret)
-        shard = jax.lax.axis_index(axis)
-        gidx = idx + (shard * f_local.shape[1]).astype(jnp.int32)
-        vals = jax.lax.all_gather(val, axis)                   # (d, B)
-        gidxs = jax.lax.all_gather(gidx, axis)                 # (d, B)
-        best_val = jnp.max(vals, axis=0)                       # (B,)
-        # among shards attaining the max, take the lowest global index
-        cand = jnp.where(vals == best_val[None, :], gidxs, jnp.iinfo(jnp.int32).max)
-        return jnp.min(cand, axis=0).astype(jnp.int32), best_val
-
-    from jax.sharding import PartitionSpec as P
-
-    return jax.jit(jax.shard_map(
-        local_select, mesh=mesh,
-        in_specs=(P(None, axis, None), P(None)),
-        out_specs=(P(None), P(None)),
-        check_vma=False))
-
-
-def select_best_sharded(features, weights, mesh=None, axis: str = "nodes",
-                        *, bn: int = 1024, interpret: bool = False):
-    """Fused select with the node axis sharded across devices.
-
-    features: (B, N, 8) f32 with N divisible by the mesh's ``axis`` size
-    (pad with invalid rows first); returns ((B,) int32, (B,) f32) exactly
-    like :func:`select_best_fused`. With ``mesh=None`` builds a 1-D mesh
-    over all local devices.
-    """
-    if mesh is None:
-        from jax.sharding import Mesh
-
-        devs = np.array(jax.devices())
-        mesh = Mesh(devs, (axis,))
-    return _sharded_select_fn(mesh, axis, bn, interpret)(features, weights)

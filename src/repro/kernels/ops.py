@@ -85,35 +85,17 @@ def mamba2_chunk(xdt, Bh, Ch, cum, state):
     return _mc.mamba2_chunk(xdt, Bh, Ch, cum, state, interpret=_interpret())
 
 
-def node_scores(features, weights):
-    return _ns.node_scores(features, weights, interpret=_interpret())
-
-
-def select_best_node(features, weights):
-    return _ns.select_best(features, weights, interpret=_interpret())
-
-
 def node_scores_batched(features, weights):
-    """(B, N, 8) x (8,) -> (B, N): the engine's one-launch batched scorer."""
+    """(B, N, 8) x (8,) -> (B, N): the one-launch batched scorer of
+    ``TemporalPolicy``'s slot grid."""
     return _ns.node_scores_batched(features, weights, interpret=_interpret())
-
-
-def select_best_node_batched(features, weights):
-    return _ns.select_best_batched(features, weights, interpret=_interpret())
-
-
-def select_best_node_fused(features, weights):
-    """(B, N, 8) x (8,) -> ((B,) int32 best index, (B,) f32 best score):
-    the fused score+argmax kernel — per-task winners reduced on-chip, no
-    (B, N) score matrix shipped to host."""
-    return _ns.select_best_fused(features, weights, interpret=_interpret())
 
 
 def select_best_node_columns(nodes, node_keys, tasks, task_keys, weights):
     """Node columns (7, N) + keys (4, N), task profiles (U, 2) + keys
     (U, 4), weights (8,) -> ((U,) int32 best index, (U,) f32 best score):
-    the fused select scored from columns on the chip, no (U, N, 8) tensor;
-    see node_score.select_best_columns."""
+    the engine's select, scored from columns on the chip with no (U, N, 8)
+    tensor; see node_score.select_best_columns."""
     return _ns.select_best_columns(nodes, node_keys, tasks, task_keys,
                                    weights, interpret=_interpret())
 
@@ -124,13 +106,6 @@ def select_best_node_joint(features, weights):
     per-task (cut, node) winners folded on-chip with lowest-(p, n) tie
     semantics; see node_score.select_best_joint."""
     return _ns.select_best_joint(features, weights, interpret=_interpret())
-
-
-def select_best_node_sharded(features, weights, mesh=None, axis="nodes"):
-    """Fused select with the node axis sharded across devices via
-    shard_map (cross-shard argmax combine); see node_score.select_best_sharded."""
-    return _ns.select_best_sharded(features, weights, mesh, axis,
-                                   interpret=_interpret())
 
 
 # Re-export oracles for tests/benchmarks.

@@ -28,11 +28,11 @@ from typing import List, Optional, Sequence
 
 import numpy as np
 
-from repro.core.api import CarbonIntensityProvider
 from repro.core.policy import (COL_CPU_FREE, COL_IXE, COL_LOAD, COL_MEM_FREE,
                                COL_RUNNING, COL_TIME_S, COL_VALID,
                                FEATURE_DIM, VectorizedPolicy, _SelectionMemo,
                                get_cache)
+from repro.core.providers import CarbonIntensityProvider
 from repro.core.scheduler import Task, Weights, node_feasible
 from repro.obs.profiler import span
 from repro.partition.profile import CutProfile

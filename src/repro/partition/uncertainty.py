@@ -16,7 +16,7 @@ guarantee, no distributional assumptions). Risk-bounded callers
 defer/reject only when the *pessimistic* end of the band still beats
 executing now.
 
-The provider-facing plumbing lives in ``core.api`` (the
+The provider-facing plumbing lives in ``core.providers`` (the
 ``intensity_interval_batch`` dispatch helper plus native zero-width
 intervals on the measured providers); this module owns the calibrators.
 """
@@ -26,8 +26,8 @@ from typing import Sequence
 
 import numpy as np
 
-from repro.core.api import (CarbonIntensityProvider, intensity_batch,
-                            intensity_interval_batch)
+from repro.core.providers import (CarbonIntensityProvider, intensity_batch,
+                                  intensity_interval_batch)
 
 __all__ = [
     "SplitConformal", "ConformalProvider", "calibrate_intensity",

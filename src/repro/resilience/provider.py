@@ -1,6 +1,6 @@
 """Blackout-tolerant carbon provider with staleness-widened intervals.
 
-:class:`ResilientProvider` wraps any :class:`~repro.core.api.
+:class:`ResilientProvider` wraps any :class:`~repro.core.providers.
 CarbonIntensityProvider` (typically the engine's *outermost* one, so a
 ``ForecastProvider``'s conformal band rides along). Healthy, every read
 delegates bit-identically — the DESIGN.md §10 zero-fault contract — while
@@ -19,6 +19,8 @@ from __future__ import annotations
 from typing import Dict, Sequence
 
 import numpy as np
+
+from repro.core.providers import intensity_batch, intensity_interval_batch
 
 
 class ResilientProvider:
@@ -93,8 +95,6 @@ class ResilientProvider:
         return float(self._stale_values([node])[0])
 
     def intensity_batch(self, names: Sequence[str], hours) -> np.ndarray:
-        from repro.core.api import intensity_batch
-
         h = np.asarray(hours, dtype=float)
         if not self.blackout:
             try:
@@ -117,8 +117,6 @@ class ResilientProvider:
 
     def intensity_interval_batch(self, names: Sequence[str], hours,
                                  coverage: float = 0.9):
-        from repro.core.api import intensity_interval_batch
-
         if not self.blackout:
             # healthy: the base's own band, bit-identical
             return intensity_interval_batch(self.base, names, hours,

@@ -52,6 +52,7 @@ from typing import (Callable, Dict, List, Optional, Protocol, Sequence,
 
 import numpy as np
 
+from repro.core.providers import intensity_batch
 from repro.obs.journey import PARK_DEFER, PARK_RETRY
 from repro.obs.profiler import span
 from repro.sim.arrivals import ArrivalProcess, ClosedLoopClientPool
@@ -167,7 +168,7 @@ class AsyncEngineDriver:
 
     ``task_factory(uid, hour)`` builds the submitted object (a ``Task``,
     ``DeferrableTask`` or serving ``Request``). When ``forecast`` is given
-    (any provider; a :class:`~repro.core.api.ForecastProvider` uses its
+    (any provider; a :class:`~repro.core.providers.ForecastProvider` uses its
     ``window``), tasks with ``deadline_hours > 0`` are deferred to the
     minimum-forecast-intensity slot within their deadline.
     """
@@ -721,8 +722,6 @@ class AsyncEngineDriver:
         provider = getattr(self.executor, "provider", None)
         mean_int = 0.0
         if cluster is not None and provider is not None:
-            from repro.core.api import intensity_batch
-
             names = list(cluster.nodes)
             try:
                 # fleet-scale: one batched provider read per tick, not N

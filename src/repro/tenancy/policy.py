@@ -49,8 +49,8 @@ from typing import Callable, List, Optional, Sequence
 
 import numpy as np
 
-from repro.core.api import intensity_interval_batch
 from repro.core.energy import carbon_g
+from repro.core.providers import intensity_interval_batch
 from repro.core.scheduler import MODES, Task, Weights, node_feasible
 from repro.tenancy.spec import (ESCALATION_BOUNDS, MODE_ORDER, TenantRegistry,
                                 TenantSpec)

@@ -2,12 +2,13 @@
 import numpy as np
 import pytest
 
-from repro.core.api import (CarbonEdgeEngine, ForecastProvider,
-                            StaticProvider, TraceProvider)
+from repro.core.api import CarbonEdgeEngine
 from repro.core.carbon import CarbonMonitor
 from repro.core.cluster import EdgeCluster, PAPER_NODES
 from repro.core.policy import (TemporalPolicy, VectorizedPolicy,
                                WeightedScoringPolicy)
+from repro.core.providers import (ForecastProvider, StaticProvider,
+                                  TraceProvider)
 from repro.core.scheduler import MODES, Task, run_workload
 from repro.core.temporal import synthetic_trace
 
@@ -212,7 +213,7 @@ def test_requeue_preserves_fifo_order_across_retries():
 def test_fallback_provider_edge_cases():
     """Satellite: FallbackProvider covers primary hits, fallback hits,
     double misses, and chained composition."""
-    from repro.core.api import FallbackProvider
+    from repro.core.providers import FallbackProvider
 
     tr = synthetic_trace("a", 100.0)
     p = FallbackProvider(TraceProvider({"a": tr}), StaticProvider({"b": 200.0}))
@@ -227,7 +228,7 @@ def test_fallback_provider_edge_cases():
 def test_forecast_window_edge_cases():
     """Satellite: empty window, zero smoothing samples, and partial trace
     coverage through the forecast wrapper."""
-    from repro.core.api import FallbackProvider
+    from repro.core.providers import FallbackProvider
 
     tr = synthetic_trace("n", 500.0)
     base = TraceProvider({"n": tr})

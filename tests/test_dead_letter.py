@@ -19,9 +19,9 @@ Satellite regressions for two pre-resilience engine traps:
 import numpy as np
 import pytest
 
-from repro.core.api import (CarbonEdgeEngine, NoFeasibleNodeError,
-                            StaticProvider)
+from repro.core.api import CarbonEdgeEngine, NoFeasibleNodeError
 from repro.core.cluster import EdgeCluster, NodeSpec, PAPER_NODES
+from repro.core.providers import StaticProvider
 from repro.core.scheduler import Task
 from repro.resilience import Resilience
 

@@ -19,11 +19,11 @@ import numpy as np
 import pytest
 
 from repro.core import energy
-from repro.core.api import (CarbonEdgeEngine, NoFeasibleNodeError,
-                            StaticProvider, TraceProvider)
+from repro.core.api import CarbonEdgeEngine, NoFeasibleNodeError
 from repro.core.carbon import CarbonMonitor
 from repro.core.cluster import EdgeCluster, NodeSpec, PAPER_NODES
 from repro.core.policy import VectorizedPolicy
+from repro.core.providers import StaticProvider, TraceProvider
 from repro.core.scheduler import MODES, Task
 from repro.core.temporal import synthetic_trace
 

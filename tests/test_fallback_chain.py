@@ -11,8 +11,8 @@ never invents a value).
 import numpy as np
 import pytest
 
-from repro.core.api import (FallbackProvider, StaticProvider,
-                            intensity_batch, intensity_interval_batch)
+from repro.core.providers import (FallbackProvider, StaticProvider,
+                                  intensity_batch, intensity_interval_batch)
 
 
 class OpaqueProvider:

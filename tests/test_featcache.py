@@ -6,12 +6,13 @@ bit-for-bit, including partial-coverage provider masking."""
 import numpy as np
 import pytest
 
-from repro.core.api import (CarbonEdgeEngine, FallbackProvider,
-                            StaticProvider, TraceProvider)
+from repro.core.api import CarbonEdgeEngine
 from repro.core.cluster import EdgeCluster, NodeSpec, PAPER_NODES
 from repro.core.policy import (VectorizedPolicy, WeightedScoringPolicy,
                                featurize, featurize_cached,
                                featurize_columns)
+from repro.core.providers import (FallbackProvider, StaticProvider,
+                                  TraceProvider)
 from repro.core.scheduler import MODES, Task
 from repro.core.temporal import synthetic_trace
 
@@ -179,7 +180,7 @@ def test_trace_provider_batch_respects_custom_at():
     tr = StepTrace(tuple(float(100 + 10 * i) for i in range(24)))
     provider = TraceProvider({"node-high": tr},
                              fallback=StaticProvider.from_cluster(c))
-    from repro.core.api import intensity_batch
+    from repro.core.providers import intensity_batch
     hours = np.array([0.25, 7.9, 13.5])
     grid = intensity_batch(provider, ["node-high", "node-green"], hours)
     for s, hr in enumerate(hours):
@@ -232,11 +233,11 @@ def test_select_batch_cached_vs_fresh_vs_oracle():
     c = random_cluster(rng, 64)
     tasks = [random_task(rng) for _ in range(16)]
     w = MODES["green"]
-    cached = VectorizedPolicy(backend="numpy", use_cache=True)
-    fresh = VectorizedPolicy(backend="numpy", use_cache=False)
+    cached = VectorizedPolicy(backend="numpy")
+    F, names = featurize(c, tasks)
     oracle = WeightedScoringPolicy()
     assert (cached.select_batch(c, tasks, w)
-            == fresh.select_batch(c, tasks, w)
+            == cached._select_from_features(F, names, w)
             == oracle.select_batch(c, tasks, w))
 
 

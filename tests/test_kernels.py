@@ -4,6 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels import node_score as ns
 from repro.kernels import ops
 
 KEY = jax.random.PRNGKey(0)
@@ -105,13 +106,18 @@ def test_mamba2_chunk_matches_model_scan():
     assert not bool(jnp.any(jnp.isnan(out_ref)))
 
 
+def _node_scores(f, w):
+    return ns.node_scores(jnp.asarray(f), jnp.asarray(w),
+                          interpret=not ops.use_pallas())
+
+
 @pytest.mark.parametrize("n", [1024, 4096])
 def test_node_scores(n):
     rng = np.random.default_rng(0)
     f = np.abs(rng.standard_normal((n, 8))).astype(np.float32)
     f[:, 6] = (f[:, 6] > 0.4).astype(np.float32)
     w = np.array([0.2, 0.2, 0.15, 0.15, 0.3, 0, 0, 0], np.float32)
-    out = ops.node_scores(jnp.asarray(f), jnp.asarray(w))
+    out = _node_scores(f, w)
     ref = ops.node_scores_ref(jnp.asarray(f), jnp.asarray(w))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-6, rtol=1e-6)
 
@@ -127,7 +133,7 @@ def test_node_scores_matches_scheduler():
     f8 = np.concatenate([f6, np.ones((256, 1), np.float32),
                          np.zeros((256, 1), np.float32)], axis=1)
     w8 = np.concatenate([w5, np.zeros(3)]).astype(np.float32)
-    out = ops.node_scores(jnp.asarray(f8), jnp.asarray(w8))
+    out = _node_scores(f8, w8)
     np.testing.assert_allclose(np.asarray(out), ref.astype(np.float32),
                                atol=1e-5, rtol=1e-5)
 
@@ -144,86 +150,134 @@ def test_node_scores_batched(B, n):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=1e-6, rtol=1e-6)
     for b in range(B):
-        row = ops.node_scores(jnp.asarray(f[b]), jnp.asarray(w))
+        row = _node_scores(f[b], w)
         np.testing.assert_allclose(np.asarray(out[b]), np.asarray(row),
                                    atol=1e-6, rtol=1e-6)
 
 
-def test_select_best_node_batched():
-    rng = np.random.default_rng(4)
-    f = np.abs(rng.standard_normal((5, 300, 8))).astype(np.float32)
-    f[:, :, 6] = 1.0
-    w = np.array([0.2, 0.2, 0.15, 0.15, 0.3, 0, 0, 0], np.float32)
-    best = np.asarray(ops.select_best_node_batched(jnp.asarray(f), jnp.asarray(w)))
-    ref = np.argmax(np.asarray(ops.node_scores_batched_ref(
-        jnp.asarray(f), jnp.asarray(w))), axis=1)
-    np.testing.assert_array_equal(best, ref)
+W8 = np.array([0.2, 0.2, 0.15, 0.15, 0.3, 0, 0, 0], np.float32)
+
+
+def _columns(free_cpu, free_mem, rest, need):
+    """The column kernel's operands (layouts in ``kernels/node_score.py``)
+    from float64 free cpu and memory, the (5, N) float32 rows load, avg
+    time, running, I x E_est and node_ok, and (U, 2) float64 needs."""
+    nodes = np.concatenate([np.stack([free_cpu, free_mem]), rest]
+                           ).astype(np.float32)
+    keys = np.concatenate([ns.f64_keys(free_cpu), ns.f64_keys(free_mem)])
+    tkeys = np.concatenate([ns.f64_keys(need[:, 0]),
+                            ns.f64_keys(need[:, 1])]).T
+    return tuple(map(jnp.asarray, (nodes, keys, need.astype(np.float32),
+                                   tkeys)))
+
+
+def _cell_tensor(free_cpu, free_mem, rest, need):
+    """The (U, N, 8) float32 feature tensor of the same cells, built as
+    ``featurize_cached`` builds it: fractions and feasibility in float64."""
+    U, N = need.shape[0], free_cpu.shape[0]
+    F = np.zeros((U, N, 8))
+    for col, free, k in ((0, free_cpu, 0), (1, free_mem, 1)):
+        frac = np.ones((U, N))
+        np.divide(free[None, :], need[:, k:k + 1], out=frac,
+                  where=need[:, k:k + 1] > 0)
+        F[:, :, col] = frac
+    F[:, :, 2:6] = rest[:4].T[None]
+    F[:, :, 6] = ((rest[4] > 0.5)[None, :]
+                  & (free_cpu[None, :] >= need[:, :1])
+                  & (free_mem[None, :] >= need[:, 1:]))
+    return F.astype(np.float32)
+
+
+def _random_columns(U, N, seed):
+    rng = np.random.default_rng(seed)
+    free_cpu = rng.uniform(0.0, 4.0, N)
+    free_mem = rng.uniform(0.0, 4096.0, N)
+    rest = np.stack([rng.uniform(0.0, 1.0, N), rng.uniform(0.0, 2.0, N),
+                     rng.integers(0, 5, N).astype(float),
+                     rng.uniform(0.0, 3.0, N),
+                     (rng.uniform(0.0, 1.0, N) > 0.3).astype(float)])
+    need = np.stack([rng.uniform(0.0, 2.0, U), rng.uniform(0.0, 2048.0, U)],
+                    axis=1)
+    return free_cpu, free_mem, rest, need
 
 
 @pytest.mark.parametrize("B,n", [(5, 300), (1, 7), (3, 1024), (2, 1500)])
 def test_select_best_fused_matches_scores_argmax(B, n):
-    """Fused score+argmax kernel == argmax over the score kernel, with the
-    winner's score returned (the host never sees the (B, N) matrix)."""
-    rng = np.random.default_rng(5)
-    f = np.abs(rng.standard_normal((B, n, 8))).astype(np.float32)
-    f[:, :, 6] = (f[:, :, 6] > 0.3).astype(np.float32)
-    w = np.array([0.2, 0.2, 0.15, 0.15, 0.3, 0, 0, 0], np.float32)
-    idx, val = ops.select_best_node_fused(jnp.asarray(f), jnp.asarray(w))
-    scores = np.asarray(ops.node_scores_batched(jnp.asarray(f), jnp.asarray(w)))
+    """The column select kernel (trace name ``select_best_fused``) ==
+    argmax over the score kernel on the same cells' feature tensor, with
+    the winner's score returned (the host never sees the (B, N) matrix)."""
+    case = _random_columns(B, n, seed=5 * n + B)
+    idx, val = ops.select_best_node_columns(*_columns(*case),
+                                            jnp.asarray(W8))
+    scores = np.asarray(ops.node_scores_batched(
+        jnp.asarray(_cell_tensor(*case)), jnp.asarray(W8)))
     ref = np.argmax(scores, axis=1)
     np.testing.assert_array_equal(np.asarray(idx), ref)
-    np.testing.assert_allclose(np.asarray(val), scores[np.arange(B), ref],
-                               rtol=1e-6)
+    np.testing.assert_array_equal(np.asarray(val), scores[np.arange(B), ref])
+    assert np.all(np.asarray(val) > -1e29)      # every row has a winner
+
+
+def _tie_case(N, pairs):
+    """N identical feasible nodes and one row per (a, b) pair, in which
+    nodes a and b are the best with exactly equal scores (a lower load)."""
+    free = np.full(N, 2.0)
+    rest = np.tile(np.array([[0.5], [0.1], [1.0], [0.2], [1.0]]), (1, N))
+    rows = []
+    for a, b in pairs:
+        r = rest.copy()
+        r[0, [a, b]] = 0.0
+        rows.append(r)
+    return free, free * 1000.0, rows, np.array([[1.0, 64.0]])
 
 
 def test_select_best_fused_tie_prefers_lowest_index():
     """Exact ties must resolve like np.argmax: the lowest node index wins,
     within a tile and across tiles."""
-    w = np.array([0.2, 0.2, 0.15, 0.15, 0.3, 0, 0, 0], np.float32)
-    f = np.zeros((1, 2048, 8), np.float32)
-    f[:, :, 6] = 1.0
-    for a, b in [(700, 1900), (3, 4), (1024, 1025)]:   # cross/in-tile ties
-        ft = f.copy()
-        ft[0, a] = ft[0, b] = [2, 2, 0, 0, 0, 0, 1, 0]
-        idx, _ = ops.select_best_node_fused(jnp.asarray(ft), jnp.asarray(w))
+    pairs = [(700, 1900), (3, 4), (1024, 1025)]        # cross/in-tile ties
+    free_cpu, free_mem, rows, need = _tie_case(2048, pairs)
+    for (a, b), rest in zip(pairs, rows):
+        idx, _ = ops.select_best_node_columns(
+            *_columns(free_cpu, free_mem, rest, need), jnp.asarray(W8))
         assert int(idx[0]) == a, (a, b, int(idx[0]))
 
 
+def test_select_best_columns_tie_across_node_tiles():
+    """With node tiles of 128, a tie between tile 1 and tile 3 resolves to
+    the lowest global index in every task row, over two row tiles."""
+    a, b = 130, 390
+    free_cpu, free_mem, (rest,), _ = _tie_case(512, [(a, b)])
+    need = np.tile([[1.0, 64.0]], (16, 1))
+    idx, val = ns.select_best_columns(
+        *_columns(free_cpu, free_mem, rest, need), jnp.asarray(W8), bu=8,
+        bn=128, interpret=not ops.use_pallas())
+    np.testing.assert_array_equal(np.asarray(idx), np.full(16, a))
+    assert np.all(np.asarray(val) > 0)
+
+
+@pytest.mark.parametrize("shift", ["equal", "one_ulp_under"])
+def test_select_best_columns_free_exactly_at_need(shift):
+    """Node 0, the better one, is feasible when its free cpu equals the
+    need exactly and not one float64 ulp under it, although the two free
+    values round to the same float32; node 1 (worse, roomy) wins then."""
+    free_cpu, free_mem = np.array([0.7, 3.0]), np.array([700.3, 4000.0])
+    rest = np.array([[0.0, 0.9], [0.1, 0.1], [0.0, 0.0], [0.0, 0.0],
+                     [1.0, 1.0]])
+    need = np.array([[0.7, 700.3]])
+    if shift == "one_ulp_under":
+        free_cpu[0] = np.nextafter(free_cpu[0], -np.inf)
+        assert np.float32(free_cpu[0]) == np.float32(need[0, 0])
+    idx, val = ops.select_best_node_columns(
+        *_columns(free_cpu, free_mem, rest, need), jnp.asarray(W8))
+    assert int(idx[0]) == (0 if shift == "equal" else 1)
+    assert float(val[0]) > 0
+
+
 def test_select_best_fused_all_invalid():
-    w = np.array([0.2, 0.2, 0.15, 0.15, 0.3, 0, 0, 0], np.float32)
-    f = np.abs(np.random.default_rng(6).standard_normal((2, 64, 8))
-               ).astype(np.float32)
-    f[:, :, 6] = 0.0
-    idx, val = ops.select_best_node_fused(jnp.asarray(f), jnp.asarray(w))
+    case = _random_columns(2, 64, seed=6)
+    case[2][4] = 0.0                                    # no node is ok
+    idx, val = ops.select_best_node_columns(*_columns(*case),
+                                            jnp.asarray(W8))
     assert np.all(np.asarray(val) < -1e29)     # NEG_INF sentinel: no winner
-
-
-def test_select_best_sharded_single_device():
-    """Degenerate 1-device mesh: the cross-shard combine must reduce to the
-    fused kernel's answer."""
-    from repro.kernels import node_score as ns
-
-    rng = np.random.default_rng(7)
-    f = np.abs(rng.standard_normal((3, 512, 8))).astype(np.float32)
-    f[:, :, 6] = (f[:, :, 6] > 0.3).astype(np.float32)
-    w = np.array([0.2, 0.2, 0.15, 0.15, 0.3, 0, 0, 0], np.float32)
-    si, sv = ns.select_best_sharded(jnp.asarray(f), jnp.asarray(w),
-                                    interpret=True)
-    ri, rv = ops.select_best_node_fused(jnp.asarray(f), jnp.asarray(w))
-    np.testing.assert_array_equal(np.asarray(si), np.asarray(ri))
-    np.testing.assert_allclose(np.asarray(sv), np.asarray(rv), rtol=1e-6)
-
-
-def test_select_best_node():
-    rng = np.random.default_rng(2)
-    f = np.abs(rng.standard_normal((1000, 8))).astype(np.float32)
-    f[:, 6] = 1.0
-    f[:, 6][::3] = 0.0  # invalidate a third
-    w = np.array([0.2, 0.2, 0.15, 0.15, 0.3, 0, 0, 0], np.float32)
-    best = int(ops.select_best_node(jnp.asarray(f), jnp.asarray(w)))
-    ref = int(np.argmax(np.asarray(ops.node_scores_ref(jnp.asarray(f), jnp.asarray(w)))))
-    assert best == ref
-    assert f[best, 6] == 1.0
 
 
 def _column_case(U, N, seed):
@@ -271,8 +325,9 @@ def _column_case(U, N, seed):
 @pytest.mark.parametrize("U,N", [(1, 3), (9, 3), (12, 300), (1, 1500),
                                  (40, 1500), (9, 1024)])
 def test_select_best_columns_matches_fused_tensor(U, N):
-    """The column kernel returns what ``select_best_fused`` returns on
-    ``featurize_cached``'s padded tensor, bit for bit: exact ties to the
+    """The column kernel picks the winners of the float64 reference
+    (``featurize_cached`` + argmax), and its scores are, bit for bit, the
+    score kernel's on the float32 cast of that tensor: exact ties to the
     lowest index, NEG_INF rows, zero needs, and feasibility one float64
     ulp either side of a node's free cpu and memory, which a float32
     compare decides wrongly."""
@@ -282,14 +337,19 @@ def test_select_best_columns_matches_fused_tensor(U, N):
     cache, tasks = _column_case(U, N, seed=U * N)
     w = np.array([0.15, 0.15, 0.1, 0.1, 0.5, 0, 0, 0], np.float32)
     F, _ = featurize_cached(cache, tasks)
-    want_i, want_v = ops.select_best_node_fused(
-        jnp.asarray(VectorizedPolicy._pad_to_buckets(F)), jnp.asarray(w))
+    totals = VectorizedPolicy._score_numpy(F, w[:5].astype(np.float64))
+    want_i = np.argmax(totals, axis=1)
+    some = np.isfinite(totals[np.arange(U), want_i])
     got_i, got_v = ops.select_best_node_columns(
         *map(jnp.asarray, featurize_columns(cache, tasks)), jnp.asarray(w))
-    np.testing.assert_array_equal(np.asarray(got_i),
-                                  np.asarray(want_i)[:U])
-    np.testing.assert_array_equal(np.asarray(got_v),
-                                  np.asarray(want_v)[:U])
+    got_i, got_v = np.asarray(got_i), np.asarray(got_v)
+    np.testing.assert_array_equal(got_i[some], want_i[some])
+    np.testing.assert_allclose(got_v[some], totals[some, want_i[some]],
+                               rtol=1e-6)
+    assert np.all(got_v[~some] < -1e29)
+    s32 = np.asarray(ops.node_scores_batched(jnp.asarray(F, jnp.float32),
+                                             jnp.asarray(w)))
+    np.testing.assert_array_equal(got_v, s32[np.arange(U), got_i])
     # the case is hard: the leading rows' exact feasibility on node 0
     # differs from a float32 compare's, and node 0 (the best) wins
     # exactly where it is feasible (index 0 with NEG_INF is no winner)

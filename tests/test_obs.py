@@ -11,10 +11,11 @@ import logging
 import numpy as np
 import pytest
 
-from repro.core.api import (CarbonEdgeEngine, ForecastProvider,
-                            StaticProvider, TraceProvider)
+from repro.core.api import CarbonEdgeEngine
 from repro.core.cluster import EdgeCluster, PAPER_NODES
 from repro.core.policy import VectorizedPolicy
+from repro.core.providers import (ForecastProvider, StaticProvider,
+                                  TraceProvider)
 from repro.core.scheduler import MODES, Task
 from repro.core.temporal import DeferrableTask, synthetic_trace
 from repro.obs import (MODE_LABELS, TRACE_PREFIX, VERDICT_LABELS,

@@ -423,8 +423,9 @@ def _chaos_run(obs, event_queue="calendar"):
     """The scripted chaos drill from examples/chaos_serving.py: two
     closed-loop tenants through a lagged-detection crash + feed blackout,
     obs wired to BOTH the engine and the driver."""
-    from repro.core.api import CarbonEdgeEngine, StaticProvider
+    from repro.core.api import CarbonEdgeEngine
     from repro.core.cluster import EdgeCluster, PAPER_NODES
+    from repro.core.providers import StaticProvider
     from repro.resilience import (Fault, FaultInjector, Resilience,
                                   ResilientProvider)
     from repro.sim import (AsyncEngineDriver, ClientPopulation,

@@ -16,11 +16,11 @@ window certainly loses.
 import numpy as np
 import pytest
 
-from repro.core.api import (CarbonEdgeEngine, ForecastProvider,
-                            StaticProvider, TraceProvider,
-                            intensity_interval_batch)
+from repro.core.api import CarbonEdgeEngine
 from repro.core.cluster import EdgeCluster, NodeSpec, PAPER_NODES
 from repro.core.policy import VectorizedPolicy, get_cache
+from repro.core.providers import (ForecastProvider, StaticProvider,
+                                  TraceProvider, intensity_interval_batch)
 from repro.core.scheduler import MODES, Task
 from repro.core.temporal import (DeferrableTask, plan_wake_batch,
                                  plan_wake_risk, plan_wake_risk_batch,

@@ -151,18 +151,14 @@ def test_select_batch_matches_select():
     assert batch == ORACLE.select_batch(c, tasks, w)
 
 
-@pytest.mark.parametrize("use_cache,kernel", [
-    (True, "select_best_columns"), (False, "select_best_fused")])
-def test_pallas_compile_count_bounded_across_fleet_sizes(use_cache, kernel):
-    """Regression (ISSUE 3 satellite): the Pallas scorer pads (B, N) to
-    power-of-two shape buckets, so a sweep over many distinct fleet/batch
-    sizes may only add as many jit entries as there are distinct buckets —
-    not one per (B, N) — on the cached column path and on the fresh
-    tensor path alike."""
+def test_pallas_compile_count_bounded_across_fleet_sizes():
+    """The Pallas column path pads (B, N) to power-of-two shape buckets,
+    so a sweep over many distinct fleet/batch sizes may only add as many
+    jit entries as there are distinct buckets — not one per (B, N)."""
     from repro.kernels import node_score as ns
 
-    fn = getattr(ns, kernel)
-    pol = VectorizedPolicy(backend="pallas", use_cache=use_cache)
+    fn = ns.select_best_columns
+    pol = VectorizedPolicy(backend="pallas")
     sweep = [(1, 3), (2, 5), (3, 9), (2, 17), (4, 33), (1, 40),
              (5, 65), (2, 100), (3, 129), (1, 200)]
     buckets = set()
@@ -209,38 +205,69 @@ def test_engine_pallas_places_as_numpy_at_fleet_scale():
     assert placed["pallas"] == placed["numpy"]
 
 
-@pytest.mark.parametrize("use_cache", [True, False])
-def test_select_columns_span_counts_one_per_step(use_cache):
+def test_select_columns_span_counts_one_per_step():
     """``select.columns`` counts the steps the column path scored: one per
-    engine step on the cached Pallas path, none on the fresh tensor path;
-    either way one kernel launch per step."""
+    engine step on the Pallas backend, with one kernel launch per step."""
     from repro.obs import Observability, StepProfiler
 
     prof = StepProfiler()
     eng = _engine("pallas", n=64, obs=Observability(profile=prof))
-    eng.policy.use_cache = use_cache
     for b in _continuous_batches(41):
         eng.submit_many(b).step()
-    assert prof.count("select.columns") == (3 if use_cache else 0)
+    assert prof.count("select.columns") == 3
     assert prof.count("select.launch") == 3
 
 
+def test_numpy_never_emits_select_columns():
+    """The numpy backend scores in ``featurize`` and ``score`` spans and
+    never enters the column path's ``select.*`` spans."""
+    from repro.obs import Observability, StepProfiler
+
+    prof = StepProfiler()
+    eng = _engine("numpy", n=64, obs=Observability(profile=prof))
+    for b in _continuous_batches(41):
+        eng.submit_many(b).step()
+    assert prof.count("score") == 3
+    assert prof.count("select.columns") == 0
+    assert prof.count("select.launch") == 0
+
+
+class _NoCache:
+    """A cluster-like without a FeatureCache: only the node states."""
+
+    def __init__(self, cluster):
+        self.nodes, self.host_power_w = cluster.nodes, cluster.host_power_w
+
+
+def test_uncached_cluster_like_places_on_pallas_as_numpy():
+    """Without a FeatureCache, the Pallas backend scores with the float64
+    reference and places exactly as numpy and the scalar oracle do."""
+    from repro.obs import StepProfiler
+
+    rng = np.random.default_rng(43)
+    c = random_cluster(rng, 40)
+    tasks = [random_task(rng) for _ in range(12)]
+    pol = VectorizedPolicy(backend="pallas")
+    pol.profiler = StepProfiler()
+    for mode in ("green", "balanced", "performance"):
+        w = MODES[mode]
+        got = pol.select_batch(_NoCache(c), tasks, w)
+        assert got == NUMPY.select_batch(_NoCache(c), tasks, w)
+        assert got == ORACLE.select_batch(c, tasks, w)
+    assert pol.profiler.count("select.launch") == 0
+
+
 def test_cached_column_path_matches_fresh_at_fleet_scale():
-    """The large-N column-scoring fast path (different summation order)
-    must agree with the fresh-featurize oracle modulo exact score ties."""
+    """At fleet scale the cached numpy path (``featurize_cached`` in
+    chunks) selects exactly what the fresh float64 reference
+    (``featurize`` + ``_select_from_features``) selects."""
     rng = np.random.default_rng(23)
-    n = 5000                                   # above COLUMN_PATH_MIN_N
+    n = 5000
     c = random_cluster(rng, n)
     tasks = [random_task(rng) for _ in range(6)]
-    fresh = VectorizedPolicy(backend="numpy", use_cache=False)
-    cached = VectorizedPolicy(backend="numpy", use_cache=True)
-    assert n >= cached.COLUMN_PATH_MIN_N
+    cached = VectorizedPolicy(backend="numpy")
     for mode in ("green", "performance"):
         w = MODES[mode]
-        a = fresh.select_batch(c, tasks, w)
-        b = cached.select_batch(c, tasks, w)
-        for task, x, y in zip(tasks, a, b):
-            if x != y:                         # only on an exact float tie
-                assert x is not None and y is not None
-                assert abs(oracle_score(c, task, w, x)
-                           - oracle_score(c, task, w, y)) < 1e-12
+        F, names = featurize(c, tasks)
+        assert (cached.select_batch(c, tasks, w)
+                == NUMPY._select_from_features(F, names, w))

@@ -16,9 +16,9 @@ fixed-fault-seed byte-identical repeats).
 import numpy as np
 import pytest
 
-from repro.core.api import (CarbonEdgeEngine, StaticProvider,
-                            intensity_interval_batch)
+from repro.core.api import CarbonEdgeEngine
 from repro.core.cluster import EdgeCluster, NodeSpec
+from repro.core.providers import StaticProvider, intensity_interval_batch
 from repro.core.scheduler import Task
 from repro.resilience import (Fault, FaultInjector, FleetHealth, Resilience,
                               ResilientProvider)

@@ -134,57 +134,3 @@ def test_multipod_decode_lowers():
     line = [ln for ln in proc.stdout.splitlines() if ln.startswith("RESULT::")][0]
     out = json.loads(line[len("RESULT::"):])
     assert all(out.values()) and len(out) == 3
-
-
-_SHARDED_SELECT_SCRIPT = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import json
-import jax
-import jax.numpy as jnp
-import numpy as np
-
-from repro.kernels import node_score as ns
-from repro.kernels import ops
-
-rng = np.random.default_rng(0)
-B, N = 4, 8 * 1024                       # node axis divides 8 devices
-f = np.abs(rng.standard_normal((B, N, 8))).astype(np.float32)
-f[:, :, 6] = (f[:, :, 6] > 0.3).astype(np.float32)
-# plant cross-shard exact ties: shard 2 and shard 6 share the best score
-f[0, 2 * 1024 + 5] = f[0, 6 * 1024 + 9] = [2, 2, 0, 0, 0, 0, 1, 0]
-w = np.array([0.2, 0.2, 0.15, 0.15, 0.3, 0, 0, 0], np.float32)
-
-si, sv = ns.select_best_sharded(jnp.asarray(f), jnp.asarray(w),
-                                interpret=True)
-ref_scores = np.asarray(ops.node_scores_batched(jnp.asarray(f),
-                                                jnp.asarray(w)))
-ref = np.argmax(ref_scores, axis=1)
-out = {
-    "n_devices": len(jax.devices()),
-    "match": bool((np.asarray(si) == ref).all()),
-    "tie_idx": int(si[0]),
-    "val_close": bool(np.allclose(np.asarray(sv),
-                                  ref_scores[np.arange(B), ref], rtol=1e-5)),
-}
-print("RESULT::" + json.dumps(out))
-"""
-
-
-@pytest.mark.slow
-def test_sharded_node_select_8_devices():
-    """shard_map'd fused select across a forced 8-CPU-device mesh: global
-    winners (and cross-shard tie-breaks: lowest global index) must match
-    the unsharded argmax."""
-    proc = subprocess.run(
-        [sys.executable, "-c", _SHARDED_SELECT_SCRIPT],
-        capture_output=True, text=True, timeout=900,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-             "HOME": "/root", "JAX_PLATFORMS": "cpu"},
-    )
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    line = [ln for ln in proc.stdout.splitlines() if ln.startswith("RESULT::")][0]
-    out = json.loads(line[len("RESULT::"):])
-    assert out["n_devices"] == 8, out
-    assert out["match"] and out["val_close"], out
-    assert out["tie_idx"] == 2 * 1024 + 5, out   # lowest global index wins

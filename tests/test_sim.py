@@ -2,9 +2,10 @@
 import numpy as np
 import pytest
 
-from repro.core.api import (CarbonEdgeEngine, ForecastProvider,
-                            StaticProvider, TraceProvider)
+from repro.core.api import CarbonEdgeEngine
 from repro.core.cluster import EdgeCluster, PAPER_NODES
+from repro.core.providers import (ForecastProvider, StaticProvider,
+                                  TraceProvider)
 from repro.core.scheduler import Task
 from repro.core.temporal import DeferrableTask, plan_wake, synthetic_trace
 from repro.sim import (AsyncEngineDriver, ConstantRateArrivals,
@@ -363,7 +364,7 @@ def test_plan_wake_duck_typed_cluster():
 def test_fallback_provider_batch_splits_by_coverage():
     """FallbackProvider with a partial-coverage primary resolves the batch
     with covers()-split batched calls — values must equal the scalar path."""
-    from repro.core.api import FallbackProvider, intensity_batch
+    from repro.core.providers import FallbackProvider, intensity_batch
 
     c = fresh_cluster()
     primary = TraceProvider({"node-green": duck_traces()["node-green"]})

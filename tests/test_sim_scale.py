@@ -3,8 +3,8 @@
 import numpy as np
 import pytest
 
-from repro.core.api import TraceProvider, load_intensity_csv
 from repro.core.cluster import EdgeCluster, PAPER_NODES
+from repro.core.providers import TraceProvider, load_intensity_csv
 from repro.core.scheduler import Task
 from repro.sim import (AsyncEngineDriver, ClientPopulation,
                        ClosedLoopClientPool, EventCalendar, EventHeap,
