@@ -189,8 +189,8 @@ def attention_vs_reference(cfg, batch, prompt_len, cache_len, pos) -> None:
     k = jax.random.normal(ks[1], (batch, K, prompt_len, hd), bf)
     v = jax.random.normal(ks[2], (batch, K, prompt_len, hd), bf)
     qd = jax.random.normal(ks[3], (batch, H, hd), bf)
-    kd = jax.random.normal(ks[4], (batch, K, cache_len, hd), bf)
-    vd = jax.random.normal(ks[5], (batch, K, cache_len, hd), bf)
+    kd = jax.random.normal(ks[4], (batch, cache_len, K, hd), bf)
+    vd = jax.random.normal(ks[5], (batch, cache_len, K, hd), bf)
     cases = {
         f"flash_attention q={q.shape} kv={k.shape}": (
             lambda: ops.flash_attention(q, k, v, causal=True, softcap=cap),

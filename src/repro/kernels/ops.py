@@ -36,10 +36,11 @@ def flash_attention(q, k, v, *, causal=True, window: Optional[int] = None,
                                softcap=softcap, interpret=_interpret())
 
 
-def decode_attention(q, k, v, pos, *, window: Optional[int] = None,
-                     softcap: float = 0.0):
-    return _dec.decode_attention(q, k, v, pos, window=window,
-                                 softcap=softcap, interpret=_interpret())
+def decode_attention(q, cache_k, cache_v, pos, layer=None, *,
+                     window: Optional[int] = None, softcap: float = 0.0):
+    return _dec.decode_attention(q, cache_k, cache_v, pos, layer,
+                                 window=window, softcap=softcap,
+                                 interpret=_interpret())
 
 
 def mla_decode_attention(q, cache, pos, layer=None, *, scale: float,

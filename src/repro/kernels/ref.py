@@ -35,13 +35,14 @@ def flash_attention_ref(q, k, v, *, causal: bool = True,
 
 def decode_attention_ref(q, k, v, pos, *, window: Optional[int] = None,
                          softcap: float = 0.0):
-    """q: (B,H,hd); k/v: (B,K,S,hd); pos scalar."""
+    """q: (B,H,hd); k/v: (B,S,K,hd), the decode cache's layout; pos
+    scalar."""
     B, H, hd = q.shape
-    K, S = k.shape[1], k.shape[2]
+    S, K = k.shape[1], k.shape[2]
     if K != H:
-        k = jnp.repeat(k, H // K, axis=1)
-        v = jnp.repeat(v, H // K, axis=1)
-    s = jnp.einsum("bhd,bhkd->bhk", q.astype(jnp.float32),
+        k = jnp.repeat(k, H // K, axis=2)
+        v = jnp.repeat(v, H // K, axis=2)
+    s = jnp.einsum("bhd,bkhd->bhk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * (hd ** -0.5)
     if softcap and softcap > 0:
         s = jnp.tanh(s / softcap) * softcap
@@ -51,7 +52,7 @@ def decode_attention_ref(q, k, v, pos, *, window: Optional[int] = None,
         mask &= ki > pos - window
     s = jnp.where(mask[None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhk,bhkd->bhd", p, v.astype(jnp.float32)).astype(q.dtype)
+    return jnp.einsum("bhk,bkhd->bhd", p, v.astype(jnp.float32)).astype(q.dtype)
 
 
 def mla_decode_attention_ref(q, cache, pos, *, scale: float, rank: int):

@@ -249,14 +249,32 @@ def attn_prefill(cfg: ModelConfig, p, x, cache_len: int, *, causal=True,
 # -- decode: one new token against the cache -------------------------------
 
 
+def decode_kernel_reads_stack(cfg: ModelConfig, smax: int) -> bool:
+    """Whether ``attn_decode`` takes ``kernels/decode_attention`` (reading
+    the stacked K/V cache at the layer's index): Pallas on one device, a
+    cache of whole kernel blocks, query heads a multiple of the KV heads,
+    no mrope. Otherwise it takes the jnp (or, under a mesh, the sharded)
+    path."""
+    from repro.kernels import decode_attention as _dec
+    from repro.kernels import ops as _ops
+    from repro.sharding.constraints import _current_mesh
+
+    return (_ops.use_pallas() and _current_mesh() is None
+            and smax % _dec.BLOCK_K == 0
+            and cfg.num_heads % cfg.num_kv_heads == 0
+            and not cfg.mrope_sections)
+
+
 def attn_decode(cfg: ModelConfig, p, x, cache: Tuple, pos, *, window=None,
                 mrope_pos=None, layer=None):
     """x: (B,1,D); cache (ck, cv): (B,Smax,K,hd), or with ``layer`` (a
     scalar int32) the (L,B,Smax,K,hd) stacks over layers, written at that
     layer in place and read there; pos: scalar int32.
 
-    Returns (y, new_cache). The attention over the cache is the jnp oracle
-    for kernels/decode_attention.
+    Returns (y, new_cache). Where ``decode_kernel_reads_stack``, the
+    attention over the cache is ``kernels/decode_attention``, given the
+    stacks and the layer index as they are; otherwise it is the jnp oracle
+    over the layer's (B,Smax,K,hd) slice.
     """
     ck, cv = cache
     B, Smax, K, hd = ck.shape[-4:]
@@ -282,6 +300,15 @@ def attn_decode(cfg: ModelConfig, p, x, cache: Tuple, pos, *, window=None,
         at = (layer, 0, pos, 0, 0)
         ck = jax.lax.dynamic_update_slice(ck, k[None].astype(ck.dtype), at)
         cv = jax.lax.dynamic_update_slice(cv, v[None].astype(cv.dtype), at)
+    if decode_kernel_reads_stack(cfg, Smax):
+        from repro.kernels import ops as _ops
+
+        out = _ops.decode_attention(
+            q[:, 0], ck, cv, pos, layer, window=window,
+            softcap=cfg.attn_logit_softcap)[:, None]
+        y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
+        return y, (ck, cv)
+
     # this layer's (B,Smax,K,hd) keys and values
     if layer is None:
         lk, lv = ck, cv
@@ -297,28 +324,6 @@ def attn_decode(cfg: ModelConfig, p, x, cache: Tuple, pos, *, window=None,
 
     from repro.sharding import rules as _rules
     from repro.sharding.constraints import _current_mesh
-
-    # Pallas decode-attention kernel (single-device serving path).
-    from repro.kernels import decode_attention as _dec
-    from repro.kernels import ops as _ops
-
-    if (_ops.use_pallas() and _current_mesh() is None
-            and Smax % _dec.BLOCK_K == 0
-            and H % K == 0 and not cfg.mrope_sections):
-        if layer is not None:
-            # The stack stays in its row-major layout: the kernel's
-            # (B,K,S,hd) operand is staged one layer at a time, where the
-            # compiler would otherwise relayout the whole stack on the way
-            # in and out of the step.
-            from jax.experimental.layout import Layout, with_layout_constraint
-
-            lk, lv = (with_layout_constraint(x, Layout(tuple(range(x.ndim))))
-                      for x in (lk, lv))
-        out = _ops.decode_attention(
-            q[:, 0], lk.transpose(0, 2, 1, 3), lv.transpose(0, 2, 1, 3), pos,
-            window=window, softcap=cfg.attn_logit_softcap)[:, None]
-        y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
-        return y, (ck, cv)
 
     ke, ve = lk, lv
     if K != H:

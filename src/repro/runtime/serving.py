@@ -20,7 +20,7 @@ from repro.configs.base import ModelConfig
 from repro.core import costmodel, energy
 from repro.core.router import GreenRouter
 from repro.kernels.decode_attention import BLOCK_K
-from repro.models import transformer
+from repro.models import attention, transformer
 from repro.obs.profiler import span
 from repro.runtime import steps
 
@@ -71,7 +71,10 @@ class ServingEngine:
     (``transformer.cache_in_place``). A caller of ``_decode`` that keeps the
     cache it passed in must copy it first. With ``obs`` metrics on, the
     gauge ``serve.decode_cache_in_place`` says which decode path the jits
-    take: 1 in place, 0 the mesh path."""
+    take: 1 in place, 0 the mesh path; and ``serve.decode_attention_stacked``
+    says how decode's GQA attention layers attend: 1 through
+    ``decode_attention`` over the stacked K/V cache by layer index, 0
+    through the jnp or mesh path (or where the model has no such layer)."""
 
     def __init__(self, cfg: ModelConfig, params, router: GreenRouter,
                  max_len: int = 256, batch_size: int = 4, obs=None):
@@ -91,6 +94,13 @@ class ServingEngine:
                 "serve.decode_cache_in_place",
                 "1 if decode updates the attention caches in place").set(
                     float(transformer.cache_in_place()))
+            self.obs.metrics.gauge(
+                "serve.decode_attention_stacked",
+                "1 if decode attention reads the stacked K/V cache in the "
+                "kernel").set(float(
+                    any(ld.kind == "attn" for ld in cfg.layer_defs)
+                    and attention.decode_kernel_reads_stack(cfg,
+                                                            self.max_len)))
         self.queue: List[Request] = []
         self.completions: List[Completion] = []
 

@@ -6,6 +6,8 @@ scans each layer's cache in and out whole. Both compute the same values,
 so on the CPU the carried path must return the same logits and caches bit
 for bit.
 """
+import contextlib
+import dataclasses
 import functools
 
 import jax
@@ -139,3 +141,65 @@ def test_engine_donates_the_cache_and_reports_the_path():
     with jax.sharding.use_abstract_mesh(MESH):
         _engine(cfg, obs)
     assert obs.metrics.get("serve.decode_cache_in_place").get() == 0.0
+
+
+@pytest.mark.parametrize("arch,kernel,mesh,want", [
+    ("qwen3-1.7b", True, False, 1.0),
+    ("qwen3-1.7b", False, False, 0.0),
+    ("qwen3-1.7b", True, True, 0.0),
+    ("deepseek-v2-lite", True, False, 0.0),
+])
+def test_engine_reports_whether_decode_attention_reads_the_stack(
+        monkeypatch, arch, kernel, mesh, want):
+    """``serve.decode_attention_stacked`` follows the helper that steers
+    ``attn_decode`` (forced here: the CPU takes the jnp path), reads 0
+    under a mesh, and 0 for a model whose attention is all latent (MLA)."""
+    from repro.kernels import ops
+    from repro.models import attention
+
+    monkeypatch.setattr(ops, "use_pallas", lambda: kernel)
+    cfg = reduced_config(arch)
+    obs = Observability(metrics=True)
+    with (jax.sharding.use_abstract_mesh(MESH) if mesh
+          else contextlib.nullcontext()):
+        eng = serving.ServingEngine(cfg, None, None, max_len=32,
+                                    batch_size=2, obs=obs)
+        assert attention.decode_kernel_reads_stack(cfg, eng.max_len) is (
+            kernel and not mesh)
+    assert obs.metrics.get("serve.decode_attention_stacked").get() == want
+
+
+# a dense GQA model, sliding windows with a softcap, a mamba2 hybrid's
+# attention layers (H = K), cross-attention
+@pytest.mark.parametrize("arch", ["qwen3-1.7b", "gemma3-27b", "zamba2-2.7b",
+                                  "whisper-base"])
+def test_decode_kernel_over_the_stack_equals_the_jnp_path(monkeypatch, arch):
+    """With the Pallas path forced (the kernel in interpret mode on the
+    CPU), each attention layer of the in-place decode step reads the
+    stacked K/V cache by layer index in ``decode_attention``; its logits
+    and caches match the jnp path's. Decode runs past gemma3's window of
+    64, so the window masks part of the cache; each pattern position stacks
+    two layers, so the layer index selects."""
+    from repro.kernels import ops
+
+    cfg = reduced_config(arch)
+    cfg = dataclasses.replace(
+        cfg, repeats=2 * cfg.repeats, num_layers=cfg.num_layers
+        + len(cfg.pattern) * cfg.repeats)
+    params = transformer.init_params(cfg, jax.random.PRNGKey(0))
+    key = jax.random.PRNGKey(1)
+    batch = make_batch(cfg, key, with_labels=False)
+    tokens = jax.random.randint(key, (2, 100), 0, cfg.vocab_size)
+    S = tokens.shape[1] - N_DEC
+    cache, _ = transformer.prefill(cfg, params, dict(batch, tokens=tokens[:, :S]),
+                                   256)
+    runs = []
+    for kernel in (False, True):
+        monkeypatch.setattr(ops, "use_pallas", lambda: kernel)
+        runs.append(_decode(cfg, params, cache, tokens[:, S:],
+                            S + cfg.vision_tokens, True))
+    (want_logits, want), (got_logits, got) = runs
+    for a, b in zip(got_logits, want_logits):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)

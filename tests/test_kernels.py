@@ -57,8 +57,8 @@ def test_flash_attention_variants(kwargs):
 @pytest.mark.parametrize("pos", [0, 100, 255])
 def test_decode_attention(B, H, K, S, hd, pos):
     q = rand(jax.random.fold_in(KEY, 7), (B, H, hd), jnp.float32)
-    k = rand(jax.random.fold_in(KEY, 8), (B, K, S, hd), jnp.float32)
-    v = rand(jax.random.fold_in(KEY, 9), (B, K, S, hd), jnp.float32)
+    k = rand(jax.random.fold_in(KEY, 8), (B, S, K, hd), jnp.float32)
+    v = rand(jax.random.fold_in(KEY, 9), (B, S, K, hd), jnp.float32)
     out = ops.decode_attention(q, k, v, jnp.int32(pos))
     ref = ops.decode_attention_ref(q, k, v, jnp.int32(pos))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
@@ -67,11 +67,42 @@ def test_decode_attention(B, H, K, S, hd, pos):
 def test_decode_attention_window():
     B, H, K, S, hd = 2, 4, 4, 512, 64
     q = rand(jax.random.fold_in(KEY, 10), (B, H, hd), jnp.float32)
-    k = rand(jax.random.fold_in(KEY, 11), (B, K, S, hd), jnp.float32)
-    v = rand(jax.random.fold_in(KEY, 12), (B, K, S, hd), jnp.float32)
+    k = rand(jax.random.fold_in(KEY, 11), (B, S, K, hd), jnp.float32)
+    v = rand(jax.random.fold_in(KEY, 12), (B, S, K, hd), jnp.float32)
     out = ops.decode_attention(q, k, v, jnp.int32(300), window=64)
     ref = ops.decode_attention_ref(q, k, v, jnp.int32(300), window=64)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
+
+
+BK = 256  # decode_attention.BLOCK_K
+
+
+# g = H / K of 1, 2 and 8; pos at the first position, either side of a block
+# edge and the cache's last; a window whose start lies mid-block; softcap
+@pytest.mark.parametrize("H,K", [(4, 4), (8, 4), (8, 1)])
+@pytest.mark.parametrize("pos,kwargs", [
+    (0, {}),
+    (BK - 1, {}),
+    (BK, {}),
+    (3 * BK - 1, {}),
+    (2 * BK + 40, dict(window=100)),
+    (3 * BK - 1, dict(softcap=5.0)),
+])
+def test_decode_attention_reads_the_stack_at_its_layer(H, K, pos, kwargs):
+    """The (L, B, S, K, hd) stack read at layer l equals the oracle over
+    that layer's (B, S, K, hd) cache, in bfloat16 as the decode step keeps
+    it."""
+    L, B, S, hd, layer = 3, 2, 3 * BK, 128, 1
+    q = rand(jax.random.fold_in(KEY, 30), (B, H, hd), jnp.bfloat16)
+    k = rand(jax.random.fold_in(KEY, 31), (L, B, S, K, hd), jnp.bfloat16)
+    v = rand(jax.random.fold_in(KEY, 32), (L, B, S, K, hd), jnp.bfloat16)
+    out = ops.decode_attention(q, k, v, jnp.int32(pos), jnp.int32(layer),
+                               **kwargs)
+    ref = ops.decode_attention_ref(q, k[layer], v[layer], jnp.int32(pos),
+                                   **kwargs)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32), atol=1e-2,
+                               rtol=1e-2)
 
 
 @pytest.mark.parametrize("B,H,L,N,P", [

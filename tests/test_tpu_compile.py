@@ -103,9 +103,30 @@ def test_attention_kernels_compile_at_qwen3_widths(one_chip, kernel):
     else:
         S = 2048
         text = _compiled_text(dec.decode_attention, sds(B, H, hd),
-                              sds(B, K, S, hd), sds(B, K, S, hd),
+                              sds(B, S, K, hd), sds(B, S, K, hd),
                               sds(dtype=jnp.int32))
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("window", [None, 512])
+def test_decode_attention_compiles_on_the_stacked_cache(one_chip, window):
+    """Qwen3-1.7B's decode step: the (28, 8, 1280, 8, 128) K and V stacks
+    read at a layer index, with and without a sliding window; nothing but
+    the kernel touches the stacks (their (rows, bk * K, hd) view is a
+    bitcast)."""
+    L, B, S, H, K, hd = 28, 8, 1280, 16, 8, 128
+
+    def sds(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fn = functools.partial(dec.decode_attention, window=window)
+    text = _compiled_text(fn, sds(B, H, hd), sds(L, B, S, K, hd),
+                          sds(L, B, S, K, hd), sds(dtype=jnp.int32),
+                          sds(dtype=jnp.int32))
+    assert "tpu_custom_call" in text
+    assert not [i for i in _instructions(text)
+                if i[1] in ((L, B, S, K, hd), (L, B, S * K, hd))
+                and i[3] not in ("parameter", "bitcast")]
 
 
 # DeepSeek-V2-Lite widths: 16 heads of 192 (q, k) and 128 (v) in prefill;
@@ -230,10 +251,9 @@ def test_serving_decode_updates_the_cache_in_place(one_chip, monkeypatch,
     """``ServingEngine``'s own decode jit (the cache donated): the whole
     cache is aliased to the output, the program needs less scratch than one
     layer's cache, and nothing makes an array of the stacked cache's or one
-    layer's shape in HBM but an in-place ``dynamic-update-slice``. Allowed:
-    ``decode_attention``'s staging of one layer's K/V into VMEM (memory
-    space S(1)) as the (B, K, S, hd) it reads; its removal belongs to the
-    kernel."""
+    layer's shape (or of a K/V stack's or layer's (S * K, hd) view), in HBM
+    or in VMEM, but an in-place ``dynamic-update-slice``:
+    ``decode_attention`` reads the K/V stacks where they lie."""
     eng, cfg, B = _serving_engine(monkeypatch, arch)
     _, compiled, cache = _lowered(one_chip, eng, cfg, B)
     mem = compiled.memory_analysis()
@@ -243,14 +263,15 @@ def test_serving_decode_updates_the_cache_in_place(one_chip, monkeypatch,
     assert mem.temp_size_in_bytes < sum(nbytes(x) // x.shape[0]
                                         for x in one_layer)
     stacks = {x.shape for x in jax.tree.leaves(cache) if x.ndim >= 4}
-    shapes = stacks | {s[1:] for s in stacks} | {(1,) + s[1:] for s in stacks}
-    staging = {v for _, b, t, k, d in (s for s in stacks if len(s) == 5)
-               for v in ((b, t, k, d), (b, k, t, d))}
+    stacks |= {(n, b, t * k, d) for n, b, t, k, d in
+               (s for s in stacks if len(s) == 5)}
+    layer = {s[1:] for s in stacks}
+    shapes = stacks | layer | {(1,) + s for s in layer} | {
+        (b, k, t, d) for b, t, k, d in (s for s in layer if len(s) == 4)}
     bad = [(name, shape, space, op)
            for name, shape, space, op, root in _instructions(compiled.as_text())
            if shape in shapes and op in ("copy", "fusion")
-           and not (space is None and root == "dynamic-update-slice")
-           and not (space == "S(1)" and shape in staging)]
+           and not (space is None and root == "dynamic-update-slice")]
     assert not bad, bad
 
 
